@@ -12,17 +12,20 @@ Dispatch order mirrors the reference `code2vec.py.__main__`: train if
 
 import sys
 
+from code2vec_tpu import device
 from code2vec_tpu.config import Config
 from code2vec_tpu.parallel.distributed import maybe_initialize
 from code2vec_tpu.vocab.vocabularies import VocabType
 
 
-def main() -> int:
+def main(argv=None) -> int:
     try:
-        config = Config.load_from_args()
+        config = Config.load_from_args(argv)
     except ValueError as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
+    device.select_backend(config.BACKEND)
+    cache_dir = device.enable_compile_cache()
     # Deterministic fault injection (ISSUE 10): arm the registry BEFORE
     # anything builds — sites fetch their handles at setup time, and
     # dist/init below is itself a site.
@@ -37,6 +40,13 @@ def main() -> int:
     # first backend touch; single-host runs detect nothing and continue.
     maybe_initialize(config.DIST_COORDINATOR, config.DIST_NUM_PROCESSES,
                      config.DIST_PROCESS_ID, log=config.log)
+    # --backend is a demand, not a hint: a run that asked for a TPU and
+    # found none stops here, before any model is built.
+    try:
+        devices = device.require_backend(config.BACKEND)
+    except device.BackendUnavailable as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 2
     # Preemption recovery: with --auto_resume, an existing checkpoint in
     # --save turns this run into a resume of itself — the SAME command
     # line continues after a pod restart instead of training from
@@ -96,7 +106,10 @@ def main() -> int:
     else:
         from code2vec_tpu.models.jax_model import Code2VecModel
         model = Code2VecModel(config)
-    config.log(f"model loaded: framework=jax backend={config.BACKEND}")
+    config.log(f"model loaded: framework=jax platform="
+               f"{devices[0].platform} device_kind="
+               f"{devices[0].device_kind!r} devices={len(devices)} "
+               f"compile_cache={cache_dir}")
 
     if config.release:
         model.release()

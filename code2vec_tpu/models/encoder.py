@@ -147,13 +147,15 @@ def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,
            dropout_rng: Optional[jax.Array] = None,
            dropout_keep_rate: float = 1.0,
            compute_dtype=jnp.float32,
-           use_pallas: bool = False) -> Tuple[jax.Array, jax.Array]:
+           use_pallas: bool = False,
+           mesh=None) -> Tuple[jax.Array, jax.Array]:
     """Forward to the code vector.
 
     Args: [B, C] int32 ids for source token / path / target token, [B, C]
     f32 mask. Returns (code_vectors [B, D] in compute dtype,
     attention [B, C] f32). use_pallas selects the fused Pallas pooling
-    kernel (ops/pallas_attention.py).
+    kernel (ops/pallas_attention.py); inside a step partitioned over
+    `mesh` each device runs it on its own batch rows.
     """
     src = take_rows(params, "token_emb", source_ids)
     pth = take_rows(params, "path_emb", path_ids)
@@ -167,8 +169,13 @@ def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,
 
     if use_pallas:
         from code2vec_tpu.ops.pallas_attention import attention_pool_fused
-        code, attn = attention_pool_fused(
-            contexts, params["transform"], params["attention"], mask)
+        pool = attention_pool_fused
+        if mesh is not None:
+            from code2vec_tpu.parallel.sharding import shard_map_over_batch
+            pool = shard_map_over_batch(pool, mesh,
+                                        (True, False, False, True))
+        code, attn = pool(contexts, params["transform"],
+                          params["attention"], mask)
         return code.astype(compute_dtype), attn
     return attention_pool(contexts, params["transform"],
                           params["attention"], mask)
@@ -177,16 +184,17 @@ def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,
 def get_encode_fn(dims: ModelDims, mesh=None):
     """The encode callable for dims.encoder_type (same signature as
     `encode`); the jitted steps in training/steps.py close over it.
-    `mesh` is only consumed by the transformer's ring-attention path
-    (dims.ring_attention with a ctx axis > 1)."""
-    if dims.encoder_type == "transformer":
-        import functools
+    `mesh` places the Pallas kernels per device (and feeds the
+    transformer's ring-attention path: dims.ring_attention with a ctx
+    axis > 1)."""
+    import functools
 
+    if dims.encoder_type == "transformer":
         from code2vec_tpu.models.transformer_encoder import (
             encode_transformer)
         return functools.partial(encode_transformer, dims=dims,
                                  mesh=mesh)
-    return encode
+    return functools.partial(encode, mesh=mesh)
 
 
 def logits_vs_table(table: jax.Array, code_vectors: jax.Array,

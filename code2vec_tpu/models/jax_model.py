@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from code2vec_tpu import device
 from code2vec_tpu.common import (EvaluationResults, MethodPredictionResults,
                                  SpecialVocabWords)
 from code2vec_tpu.config import Config
@@ -88,10 +89,14 @@ class Code2VecModel(Code2VecModelBase):
         cfg = config
         self.log = cfg.log
         self.compute_dtype = jnp.bfloat16 if cfg.USE_BF16 else jnp.float32
-        # Pallas kernels are TPU-only; fall back to the XLA pool
-        # elsewhere (tests run on the virtual CPU mesh).
-        self.use_pallas = (cfg.USE_PALLAS
-                           and jax.default_backend() == "tpu")
+        # The fused pool is a Mosaic kernel, so it exists on a TPU
+        # only. code2vec.py has already held the run to --backend, so
+        # the platform read here is the one the user named.
+        platform = device.platform()
+        self.use_pallas = cfg.USE_PALLAS and platform == "tpu"
+        self.log(f"attention pool: "
+                 f"{'Pallas kernel' if self.use_pallas else 'XLA'} "
+                 f"(platform {platform}, USE_PALLAS={cfg.USE_PALLAS})")
 
         # ---- mesh (SURVEY.md §3.3): data axis for DP, model axis for
         # sharded vocab tables; single-device runs use no mesh. ----
@@ -433,7 +438,15 @@ class Code2VecModel(Code2VecModelBase):
                 self.mesh.shape.get(DCN_AXIS, 1)
                 * self.mesh.shape.get(DATA_AXIS, 1)))
         procs = jax.process_count()
-        if cfg.SPARSE_EMBEDDING_UPDATES and model_shards == 1:
+        # the floors divide bytes by this chip's published HBM peak;
+        # a device_kind the table does not list gets no floor gauge
+        peak_gbps = device.hbm_peak_gbps()
+        if peak_gbps is None:
+            self.log("no analytic floor gauges: device_kind "
+                     f"{jax.local_devices()[0].device_kind!r} has no "
+                     "entry in code2vec_tpu.device.HBM_PEAK_GBPS")
+        if (cfg.SPARSE_EMBEDDING_UPDATES and model_shards == 1
+                and peak_gbps is not None):
             # live optimizer-efficiency plane (round 13): publish the
             # [U, E]-aware analytic step floor once; the health
             # engine's opt_efficiency monitor divides it by the
@@ -460,7 +473,7 @@ class Code2VecModel(Code2VecModelBase):
             upd_bytes = sparse_update_phase_bytes(
                 self.params, cfg.TRAIN_BATCH_SIZE, cfg.MAX_CONTEXTS,
                 num_sampled=ns, processes=procs)
-            ceiling = cfg.HBM_CEILING_GBPS * 1e9
+            ceiling = peak_gbps * 1e9
             telemetry.gauge("train/step_floor_ms",
                             step_bytes / ceiling * 1e3, emit=False,
                             static=True)
@@ -478,7 +491,7 @@ class Code2VecModel(Code2VecModelBase):
         from code2vec_tpu.obs.phases import PhaseProfiler
         phase_kw = {}
         if cfg.PHASE_PROFILE == "on" and telemetry.enabled \
-                and model_shards == 1:
+                and model_shards == 1 and peak_gbps is not None:
             # the analytic per-phase comparator (model-sharded tables
             # are not described by it — same rule as the floor gauges
             # above: no gauge beats a false one)
@@ -488,7 +501,7 @@ class Code2VecModel(Code2VecModelBase):
                 self.params, cfg.TRAIN_BATCH_SIZE, cfg.MAX_CONTEXTS,
                 num_sampled=ns, sparse=cfg.SPARSE_EMBEDDING_UPDATES,
                 data_shards=data_shards, processes=procs)
-            phase_kw["ceiling_gbps"] = cfg.HBM_CEILING_GBPS
+            phase_kw["ceiling_gbps"] = peak_gbps
 
         def _phase_probes():
             from code2vec_tpu.training.phase_probes import \
@@ -772,14 +785,17 @@ class Code2VecModel(Code2VecModelBase):
         return buckets
 
     def predict_compile_count(self) -> int:
-        """Number of compiled predict-step variants (-1 when the
-        backend's jit cache is not introspectable). Serving asserts this
-        stays flat after `warmup_predict` — the zero-new-compilations
-        acceptance check."""
-        try:
-            return int(self._predict_step._cache_size())
-        except Exception:
-            return -1
+        """Number of compiled predict-step variants. Serving asserts
+        this stays flat after `warmup_predict` — the
+        zero-new-compilations acceptance check, which a sentinel for
+        "cannot tell" would pass vacuously, so a jit without the
+        counter is an error."""
+        cache_size = getattr(self._predict_step, "_cache_size", None)
+        if cache_size is None:
+            raise RuntimeError(
+                "the jitted predict step exposes no _cache_size(): "
+                "compilations under load cannot be counted on this JAX")
+        return int(cache_size())
 
     def predict_device(self, prepared: PreparedRows):
         """Device phase of `predict`: pad the rows to their

@@ -67,7 +67,8 @@ def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
 
 def _mha(x: jax.Array, qkv: jax.Array, out: jax.Array,
          log_mask: jax.Array, heads: int,
-         ring_mesh=None, use_pallas: bool = False) -> jax.Array:
+         ring_mesh=None, use_pallas: bool = False,
+         mesh=None) -> jax.Array:
     B, C, D = x.shape
     hd = D // heads
     proj = x @ qkv.astype(x.dtype)                     # [B, C, 3D]
@@ -84,7 +85,11 @@ def _mha(x: jax.Array, qkv: jax.Array, out: jax.Array,
         # fused fwd+bwd kernels: no [B, H, C, C] tensor in HBM either
         # direction (ops/xf_attention.py)
         from code2vec_tpu.ops.xf_attention import fused_mha
-        ctx = fused_mha(q, k, v, log_mask)
+        mha = fused_mha
+        if mesh is not None:
+            from code2vec_tpu.parallel.sharding import shard_map_over_batch
+            mha = shard_map_over_batch(mha, mesh, (True,) * 4)
+        ctx = mha(q, k, v, log_mask)
     else:
         logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
         # hd is the Python-int head dim: trace-time scale math, no
@@ -142,7 +147,7 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
         h = _rms_norm(x, layer["ln1_scale"])
         x = x + _mha(h, layer["qkv"], layer["out"], log_mask,
                      dims.xf_heads, ring_mesh=ring_mesh,
-                     use_pallas=use_pallas)
+                     use_pallas=use_pallas, mesh=mesh)
         h = _rms_norm(x, layer["ln2_scale"])
         h = jax.nn.gelu(h @ layer["mlp_up"].astype(compute_dtype))
         return x + h @ layer["mlp_down"].astype(compute_dtype)

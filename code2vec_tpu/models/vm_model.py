@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from code2vec_tpu import device
 from code2vec_tpu.config import Config
 from code2vec_tpu.data.vm_reader import (VMTextReader, build_vm_vocabs)
 from code2vec_tpu.models.encoder import ModelDims
@@ -46,14 +47,22 @@ class VarMisuseModel:
         self.telemetry = Telemetry.disabled()  # train() swaps it in
         self.tracer = Tracer.disabled()        # ditto (--trace)
         self.compute_dtype = jnp.bfloat16 if cfg.USE_BF16 else jnp.float32
-        # Pallas kernels are TPU-only; fall back to the XLA pool
-        # elsewhere (tests run on the virtual CPU mesh).
-        self.use_pallas = (cfg.USE_PALLAS
-                           and jax.default_backend() == "tpu")
-
         from code2vec_tpu.models.setup import build_mesh, build_optimizer
         # no context axis: the vm head is bag-encoder-only (Config.verify)
         self.mesh = build_mesh(cfg, with_context_axis=False)
+        # The fused pool is a Mosaic kernel, so it exists on a TPU only
+        # (code2vec.py has already held the run to --backend: the
+        # platform read here is the one the user named). Under a mesh
+        # it would have to sit in a shard_map (encoder.encode's
+        # `mesh`), which vm_scores does not thread: partitioned vm
+        # steps pool with XLA.
+        platform = device.platform()
+        self.use_pallas = (cfg.USE_PALLAS and platform == "tpu"
+                           and self.mesh is None)
+        self.log(f"attention pool: "
+                 f"{'Pallas kernel' if self.use_pallas else 'XLA'} "
+                 f"(platform {platform}, USE_PALLAS={cfg.USE_PALLAS}, "
+                 f"mesh={self.mesh})")
         model_axis = max(1, cfg.MESH_MODEL_AXIS)
 
         if cfg.is_loading:

@@ -314,7 +314,9 @@ class OptEfficiency(Monitor):
     """Analytic-floor attainment of the train step: a STATIC floor
     gauge (`train/step_floor_ms` — published once by the sparse-update
     train loop from the [U, E]-aware traffic model in
-    training/sparse_update.py, over the HBM_CEILING_GBPS constant)
+    training/sparse_update.py, over the chip's published HBM peak,
+    code2vec_tpu/device.HBM_PEAK_GBPS; a device_kind that table does
+    not list publishes none and this monitor stays unknown)
     divided by the observed p50 step time. Semantics mirror bench.py's
     `optimizer_efficiency` (throughput over the optimizer-free floor):
     near 1 means the step runs at its roofline, and ANY step-time

@@ -250,11 +250,9 @@ class TrainStepRecorder:
         self.last_step_context = root
 
     def _device_memory_gauges(self) -> None:
-        try:
-            import jax
-            stats = jax.local_devices()[0].memory_stats() or {}
-        except Exception:  # backend without memory_stats (CPU)
-            return
+        import jax
+        # None on the CPU backend, which keeps no allocator statistics
+        stats = jax.local_devices()[0].memory_stats() or {}
         for key in ("bytes_in_use", "peak_bytes_in_use"):
             if key in stats:
                 self._tele.gauge(f"device/{key}", int(stats[key]))

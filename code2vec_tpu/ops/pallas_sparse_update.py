@@ -10,8 +10,8 @@ table, moments and (int8) scales stay in HBM (`memory_space=ANY`) and
 are ALIASED input->output, so the kernel's HBM traffic is proportional
 to the number of unique rows U, not the vocab V — the whole point: the
 dense path's optimizer/requantize walk moved table-sized traffic per
-step (BENCH_r05: optimizer efficiency 0.786 at 15.7% HBM utilization),
-this moves [U, E].
+step (BENCH_r05, git history at a4bf2f7: optimizer efficiency 0.786 at
+15.7% HBM utilization), this moves [U, E].
 
 Contract with the facade (training/sparse_update.py):
   - `uids` is PRE-PADDED to a whole number of `block_rows` blocks with
@@ -21,14 +21,32 @@ Contract with the facade (training/sparse_update.py):
   - unique ids never repeat, so grid programs write disjoint rows and
     the sequential-grid in-place aliasing is race-free.
   - the row math IS the facade's `row_adam_math` / `requant_row_math`
-    (imported, not copied), so fused-vs-reference parity cannot drift:
-    bit-exact on float/bf16 tables, q-exact on int8 under a shared
-    salt.
+    (imported, not copied), so the two paths cannot drift in meaning.
+    They are not promised bit-equal: one expression is compiled twice
+    (by Mosaic or the interpreter here, by XLA in the reference) and
+    each compiler may contract and associate it its own way. The
+    parity contract is FLOAT ULP at the scale of the operands —
+    max(|value before|, |update|) — and rows no id names stay
+    bit-identical:
+      compiled on a TPU v5e, f32 rows, V=1,301,136, 409,600 ids
+        (chip_smoke.py, PR 21): p, m and v came out bit-equal; the
+        smoke holds p to 2 ulp and the moments to bit equality;
+      interpret mode against XLA:CPU (tests/test_sparse_update.py):
+        p, m and v within 8 ulp (measured: 4 — XLA:CPU fuses the
+        multiply-adds the interpreter runs one by one);
+      int8, interpret mode: q exact under a shared salt, s within 2
+        ulp, m and v bit-equal. The compiled int8 kernel has no chip
+        verdict — Mosaic refuses it, see below.
 
-Follows the ops/pallas_requant.py pattern: TPU-compiled on a TPU
-backend, interpret mode elsewhere (the CPU tier-1 tests run the
-identical kernel), auto-selected by the facade, governed by
-Config.SPARSE_UPDATE_PALLAS. Sentinel rows clamp their gather to row 0
+Where it runs: Mosaic compiles the kernel for float32 rows only — it
+refuses the single-row DMA of a packed bf16 or int8 row
+(`sparse_update._resolve_fused` has the message) — so on a TPU the
+facade's auto-select takes this kernel for f32 tables and the XLA
+reference for the rest; forcing it raises the compiler's message.
+Off-TPU it runs in interpret mode (the CPU
+tier-1 tests run the identical kernel). The bias-corrected step size
+arrives as an f32 scalar computed outside
+(`sparse_adam.adam_step_size`: Mosaic lowers no scalar power). Sentinel rows clamp their gather to row 0
 (a wasted but harmless read) and `pl.when` skips their scatter. The
 per-row DMAs are issued serially within a block — block size (the
 `block_rows` knob, tools/sparse_update_sweep.py) trades grid overhead
@@ -46,7 +64,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from code2vec_tpu.ops.quant import QuantTable
-from code2vec_tpu.training.sparse_adam import RowAdamState
+from code2vec_tpu.training.sparse_adam import (RowAdamState,
+                                               adam_step_size)
 from code2vec_tpu.training.sparse_update import (requant_row_math,
                                                  row_adam_math)
 
@@ -63,10 +82,10 @@ def _scatter_row(src_vmem, dst_any, slot, rid, sem):
     cp.wait()
 
 
-def _row_adam_kernel(ids_ref, seg_ref, count_ref, tbl_any, m_any, v_any,
+def _row_adam_kernel(ids_ref, seg_ref, lr_t_ref, tbl_any, m_any, v_any,
                      tbl_out, m_out, v_out, p_vmem, m_vmem, v_vmem, sem,
-                     *, block_rows: int, vocab: int, lr: float,
-                     b1: float, b2: float, eps: float):
+                     *, block_rows: int, vocab: int, b1: float,
+                     b2: float, eps: float):
     # tbl_out/m_out/v_out alias tbl_any/m_any/v_any: gather from the
     # OUTPUT refs so re-reads inside one pallas_call (there are none —
     # ids are unique) and the aliasing contract stay coherent.
@@ -81,7 +100,7 @@ def _row_adam_kernel(ids_ref, seg_ref, count_ref, tbl_any, m_any, v_any,
 
     p_new, m_new, v_new = row_adam_math(
         p_vmem[:].astype(jnp.float32), m_vmem[:], v_vmem[:],
-        seg_ref[:], count_ref[0, 0], lr, b1, b2, eps)
+        seg_ref[:], lr_t_ref[0, 0], b1, b2, eps)
     p_vmem[:] = p_new.astype(p_vmem.dtype)
     m_vmem[:] = m_new
     v_vmem[:] = v_new
@@ -106,7 +125,7 @@ def _row_adam_impl(table, m, v, uids, seg, count, block_rows, interpret,
     V, E = table.shape
     S = uids.shape[0]
     kernel = functools.partial(_row_adam_kernel, block_rows=block_rows,
-                               vocab=V, lr=lr, b1=b1, b2=b2, eps=eps)
+                               vocab=V, b1=b1, b2=b2, eps=eps)
     return pl.pallas_call(
         kernel,
         grid=(S // block_rows,),
@@ -114,13 +133,13 @@ def _row_adam_impl(table, m, v, uids, seg, count, block_rows, interpret,
             pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, E), lambda i: (i, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         out_shape=(jax.ShapeDtypeStruct((V, E), table.dtype),
                    jax.ShapeDtypeStruct((V, E), jnp.float32),
                    jax.ShapeDtypeStruct((V, E), jnp.float32)),
@@ -130,8 +149,8 @@ def _row_adam_impl(table, m, v, uids, seg, count, block_rows, interpret,
                         pltpu.SemaphoreType.DMA],
         input_output_aliases={3: 0, 4: 1, 5: 2},
         interpret=interpret,
-    )(uids.reshape(S, 1), seg, count.reshape(1, 1).astype(jnp.float32),
-      table, m, v)
+    )(uids.reshape(S, 1), seg,
+      adam_step_size(count, lr, b1, b2).reshape(1, 1), table, m, v)
 
 
 def sparse_row_adam_fused(table: jax.Array, state: RowAdamState,
@@ -154,11 +173,11 @@ def sparse_row_adam_fused(table: jax.Array, state: RowAdamState,
     return new_t, RowAdamState(m=new_m, v=new_v)
 
 
-def _requant_adam_kernel(ids_ref, seg_ref, count_ref, salt_ref, q_any,
+def _requant_adam_kernel(ids_ref, seg_ref, lr_t_ref, salt_ref, q_any,
                          s_any, m_any, v_any, q_out, s_out, m_out,
                          v_out, q_vmem, s_vmem, m_vmem, v_vmem, sem, *,
-                         block_rows: int, vocab: int, lr: float,
-                         b1: float, b2: float, eps: float):
+                         block_rows: int, vocab: int, b1: float,
+                         b2: float, eps: float):
     def gather(i, _):
         rid = ids_ref[i, 0]
         rid = jnp.where(rid < vocab, rid, 0)
@@ -171,8 +190,7 @@ def _requant_adam_kernel(ids_ref, seg_ref, count_ref, salt_ref, q_any,
 
     q_new, s_new, m_new, v_new = requant_row_math(
         q_vmem[:], s_vmem[:], m_vmem[:], v_vmem[:], seg_ref[:],
-        ids_ref[:, 0], salt_ref[0, 0], count_ref[0, 0], lr, b1, b2,
-        eps)
+        ids_ref[:, 0], salt_ref[0, 0], lr_t_ref[0, 0], b1, b2, eps)
     q_vmem[:] = q_new
     s_vmem[:] = s_new
     m_vmem[:] = m_new
@@ -199,8 +217,8 @@ def _requant_adam_impl(q, s, m, v, uids, seg, salt, count, block_rows,
     V, E = q.shape
     S = uids.shape[0]
     kernel = functools.partial(_requant_adam_kernel,
-                               block_rows=block_rows, vocab=V, lr=lr,
-                               b1=b1, b2=b2, eps=eps)
+                               block_rows=block_rows, vocab=V, b1=b1,
+                               b2=b2, eps=eps)
     return pl.pallas_call(
         kernel,
         grid=(S // block_rows,),
@@ -209,15 +227,15 @@ def _requant_adam_impl(q, s, m, v, uids, seg, salt, count, block_rows,
             pl.BlockSpec((block_rows, E), lambda i: (i, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         out_shape=(jax.ShapeDtypeStruct((V, E), jnp.int8),
                    jax.ShapeDtypeStruct((V, 1), jnp.float32),
                    jax.ShapeDtypeStruct((V, E), jnp.float32),
@@ -229,7 +247,8 @@ def _requant_adam_impl(q, s, m, v, uids, seg, salt, count, block_rows,
                         pltpu.SemaphoreType.DMA],
         input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3},
         interpret=interpret,
-    )(uids.reshape(S, 1), seg, count.reshape(1, 1).astype(jnp.float32),
+    )(uids.reshape(S, 1), seg,
+      adam_step_size(count, lr, b1, b2).reshape(1, 1),
       salt.reshape(1, 1), q, s, m, v)
 
 

@@ -158,8 +158,10 @@ def dither_from_index(idx: jax.Array, salt: jax.Array) -> jax.Array:
     # top 24 bits -> f32: exact in a 24-bit mantissa, so the result
     # stays in [-0.5, 0.5) — a full-32-bit convert would round values
     # near 2^32 up and emit dither of exactly +0.5
-    return ((h >> 8).astype(jnp.float32) * jnp.float32(1.0 / 16777216.0)
-            - 0.5)
+    # via int32: Mosaic has no uint32 -> f32 convert, and the 24-bit
+    # value is the same number either way
+    return ((h >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / 16777216.0) - 0.5)
 
 
 def _dither(rng: jax.Array, shape) -> jax.Array:

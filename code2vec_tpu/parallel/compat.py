@@ -1,32 +1,20 @@
-"""Version-portable wrappers for the JAX APIs the parallel layer leans
-on — the seams where the installed JAX's surface has moved between the
-versions this repo meets in the wild (the 0.4.x CPU wheels in CI
-containers, the newer TPU builds on the driver).
+"""Seams between the parallel layer and the one JAX this repo is
+written for (jax 0.9.0 / jaxlib 0.9.0, on the TPU and on the CPU
+harness alike), plus the bring-up guards the multi-process CPU
+harnesses share.
 
-Three seams, one module:
-
-- `shard_map`: promoted from `jax.experimental.shard_map` (where the
-  replication-check kwarg is `check_rep`) to top-level `jax.shard_map`
-  (where it is `check_vma`). Every caller here wants the check OFF —
-  the parallel bodies use collectives (`ppermute`, `all_gather`) whose
-  replication typing the older checker rejects — so the wrapper owns
-  the spelling.
-- CPU device provisioning: `jax.config.update("jax_num_cpu_devices",
-  n)` exists only on newer JAX; the env flag
-  `XLA_FLAGS=--xla_force_host_platform_device_count=N`, read at
-  backend init, is the one knob every supported version honors — so
-  `cpu_worker_env` pins it (plus `JAX_PLATFORMS=cpu`) in the spawn
-  environment BEFORE a worker's jax import, and every multi-process
-  spawner (tests/mp_worker.py, tools/multichip_bench.py) provisions
-  that way.
-- CPU cross-process collectives: the 0.4.x CPU client refuses
-  multi-process computations unless the Gloo collectives
-  implementation is selected via `jax_cpu_collectives_implementation`
-  BEFORE `jax.distributed.initialize`; newer JAX defaults to Gloo and
-  drops the knob. `enable_cpu_collectives` sets it when present and is
-  a no-op otherwise. parallel/distributed.maybe_initialize calls it,
-  so every entry point (code2vec.py, tests/mp_worker.py,
-  tools/multichip_bench.py) inherits the fix.
+- `shard_map`: `jax.shard_map` with the varying-manual-axes check off.
+  Every caller wants it off — the parallel bodies use collectives
+  (`ppermute`, `all_gather`) on values the checker would make them
+  annotate — so one wrapper owns the argument.
+- `distributed_initialize`: `jax.distributed.initialize`, with a wider
+  heartbeat timeout on the CPU harness.
+- `cpu_worker_env`: the spawn environment of a CPU worker
+  (`JAX_PLATFORMS=cpu` plus the virtual device count), pinned before
+  the worker's jax import; every multi-process spawner
+  (tests/mp_worker.py, tools/multichip_bench.py, tools/chaos.py)
+  provisions that way. CPU cross-process collectives need no knob:
+  Gloo is this JAX's default.
 """
 
 from __future__ import annotations
@@ -36,105 +24,33 @@ from typing import Any
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` / `jax.experimental.shard_map.shard_map` with the
-    replication/varying-manual-axes check disabled under either
-    spelling. The kwarg is probed, not version-guessed: the
-    promote-to-top-level and the `check_rep`->`check_vma` rename were
-    separate JAX releases, so a top-level `jax.shard_map` may still
-    spell the kwarg `check_rep`."""
+    """`jax.shard_map` with `check_vma=False`."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
-def disable_cpu_async_dispatch() -> None:
-    """Turn off the CPU client's async dispatch. With it on, two
-    in-flight programs can interleave differently-sized collectives on
-    the same Gloo TCP pair, which dies with
-    `gloo::EnforceNotMet: op.preamble.length <= op.nbytes` —
-    intermittently, under load (observed on the 2-process tier-1
-    harness). Single-process training never calls this, so the
-    steady-state CPU fast path keeps async dispatch; multi-process
-    BENCHMARKS must apply this same knob to their single-process
-    baseline leg so the timing comparison stays like-for-like
-    (tools/multichip_bench.py does — via this standalone entry, since
-    selecting Gloo itself without a distributed client would fail the
-    backend build)."""
-    import jax
-
-    try:
-        jax.config.update("jax_cpu_enable_async_dispatch", False)
-    except (AttributeError, ValueError):
-        pass  # newer JAX may drop/rename the knob; the race is 0.4.x-era
-
-
-def enable_cpu_collectives() -> bool:
-    """Select the Gloo CPU collectives implementation where the knob
-    exists (it must be set before `jax.distributed.initialize`; without
-    it the 0.4.x CPU client fails multi-process computations with
-    "Multiprocess computations aren't implemented on the CPU backend").
-    Returns True when the option was set (or JAX is new enough to
-    default to Gloo). Also applies `disable_cpu_async_dispatch` (see
-    there). Only call on a process that WILL join a distributed
-    runtime: the Gloo client factory requires the distributed client,
-    so a single-process backend build would fail with it selected."""
-    import jax
-
-    disable_cpu_async_dispatch()
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        return True
-    except (AttributeError, ValueError):
-        # newer JAX: the option is gone because Gloo IS the default
-        return not hasattr(jax.config, "jax_cpu_collectives_implementation")
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def distributed_initialize(coordinator_address=None, num_processes=None,
                            process_id=None) -> None:
-    """`jax.distributed.initialize`, with the coordination-service
-    heartbeat tolerance widened on CPU backends. The public API drops
-    the heartbeat knobs on 0.4.x, but the CPU Gloo harnesses this repo
-    runs (2 OS processes x 4 virtual devices on a 2-core CI box) can
-    starve a worker's heartbeat thread past the default 100 s tolerance
-    during the first big XLA compile — the coordinator then EVICTS the
-    healthy-but-descheduled worker and the peer dies mid-collective
-    with `gloo ... Connection reset by peer` (observed on the multichip
-    bench). TPU/GPU runs keep stock tolerances: there the default is
-    the right failure detector, and eviction latency matters."""
+    """`jax.distributed.initialize`, with the coordination service's
+    heartbeat timeout widened from 100 s to 600 s when the platform is
+    the CPU. The Gloo harnesses this repo runs (2 OS processes x 4
+    virtual devices on a 2-core box) can starve a worker's heartbeat
+    thread past the default during the first big XLA compile; the
+    coordinator then evicts the healthy-but-descheduled worker and the
+    peer dies mid-collective with `gloo ... Connection reset by peer`.
+    TPU runs keep the stock timeout: there it is the right failure
+    detector, and eviction latency matters."""
     import jax
 
-    relax = os.environ.get("JAX_PLATFORMS", "") == "cpu"
     kwargs = {}
     if coordinator_address is not None:
         kwargs = dict(coordinator_address=coordinator_address,
                       num_processes=num_processes,
                       process_id=process_id)
-    if relax:
-        try:
-            from jax._src import xla_bridge
-            from jax._src.distributed import global_state
-            if xla_bridge.backends_are_initialized():
-                raise RuntimeError(
-                    "distributed_initialize must run before any JAX "
-                    "computation (the public-API precondition)")
-            global_state.initialize(
-                service_heartbeat_interval_seconds=10,
-                service_max_missing_heartbeats=60,
-                client_heartbeat_interval_seconds=10,
-                client_max_missing_heartbeats=60,
-                **kwargs)
-            return
-        except (ImportError, TypeError):
-            pass  # private surface moved: fall back to the public API
+    if jax.config.jax_platforms == "cpu":
+        kwargs["heartbeat_timeout_seconds"] = 600
     jax.distributed.initialize(**kwargs)
 
 
@@ -152,7 +68,7 @@ def first_collective_barrier(timeout_s: float = 90.0, *,
     loopback-Gloo rendezvous can wedge EVERY cohort member during
     bring-up — inside `jax.distributed.initialize` itself (it blocks
     until every peer connects) or at the FIRST collective right after
-    it returns (the compat-docstring transport-race family). Each
+    it returns (a Gloo transport race). Each
     worker then blocks forever, the spawner burns its full
     communicate() wall, and one wedge eats a whole test module's
     budget.
@@ -305,9 +221,8 @@ def free_port() -> int:
 def cpu_worker_env(n_devices: int, extra: dict[str, Any] | None = None
                    ) -> dict:
     """Environment for a spawned CPU worker process: CPU platform +
-    n virtual devices pinned BEFORE its jax import (the portable way —
-    no config API races). Used by the multi-process test/bench
-    spawners."""
+    n virtual devices pinned BEFORE its jax import. Used by the
+    multi-process test/bench spawners."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (

@@ -88,14 +88,8 @@ def maybe_initialize(coordinator_address: Optional[str] = None,
 
     import jax
 
-    # CPU backends need the Gloo collectives implementation selected
-    # BEFORE initialize() or multi-process computations fail outright;
-    # harmless elsewhere (parallel/compat.py owns the version seam —
-    # and its distributed_initialize widens the heartbeat tolerance on
-    # oversubscribed CPU harnesses).
-    from code2vec_tpu.parallel.compat import (distributed_initialize,
-                                              enable_cpu_collectives)
-    enable_cpu_collectives()
+    # (widens the heartbeat timeout on oversubscribed CPU harnesses)
+    from code2vec_tpu.parallel.compat import distributed_initialize
 
     kwargs = {}
     if explicit:

@@ -47,6 +47,20 @@ def batch_pspec() -> P:
     return P((DCN_AXIS, DATA_AXIS))
 
 
+def shard_map_over_batch(fn, mesh: Mesh, batched):
+    """`fn` run once per device on that device's rows: arguments whose
+    `batched` flag is set (and every output) split their leading dim
+    over ('dcn', 'data'), the rest arrive whole. This is how a Pallas
+    kernel sits inside a partitioned step — GSPMD will not split one
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map")."""
+    from code2vec_tpu.parallel.compat import shard_map
+    rows = batch_pspec()
+    return shard_map(fn, mesh=mesh,
+                     in_specs=tuple(rows if b else P() for b in batched),
+                     out_specs=rows)
+
+
 def context_batch_pspec() -> P:
     """[B, C] tensors with the context dim sharded over 'ctx' — the
     sequence/context-parallel layout for the transformer encoder."""

@@ -204,8 +204,7 @@ class ReplicaPool:
             model.params = params
         server.start(warmup=warmup)
         rep.server = server
-        c = model.predict_compile_count()
-        rep.compile_baseline = c if c >= 0 else 0
+        rep.compile_baseline = model.predict_compile_count()
         with self._lock:
             rep.state = "ready"
             self._replicas.append(rep)
@@ -416,8 +415,8 @@ class ReplicaPool:
 
     def compile_delta(self) -> int:
         """Jit compilations the SERVING path triggered after warmup,
-        summed over live replicas (models that cannot introspect report
-        -1 and are skipped) — the chaos leg's zero-compile gate."""
+        summed over live replicas — the chaos leg's zero-compile
+        gate."""
         with self._lock:
             reps = list(self._replicas)
         total = 0
@@ -425,8 +424,7 @@ class ReplicaPool:
             if rep.server is None:
                 continue
             c = rep.server.model.predict_compile_count()
-            if c >= 0:
-                total += max(0, c - rep.compile_baseline)
+            total += max(0, c - rep.compile_baseline)
         return total
 
     def wait_ready(self, n: int, timeout_s: float = 60.0) -> bool:

@@ -1,8 +1,9 @@
 """Sparse-row (lazy) Adam state + the dense-carrier oracle update.
 
 SURVEY.md §8.4 item 2: table traffic dominates the java-large step, and
-a batch touches far fewer than V unique rows — BENCH_r05 puts the
-shipped dense path at 6.66M pc/s/chip against an 8.48M fwd/bwd floor
+a batch touches far fewer than V unique rows — BENCH_r05 (git history
+at a4bf2f7) puts the shipped dense path at 6.66M pc/s/chip against an
+8.48M fwd/bwd floor
 (optimizer efficiency 0.786, HBM at 15.7% of the 637 GB/s ceiling), so
 moments and parameters are updated for TOUCHED ROWS ONLY. (The "45 ms
 dense / ~9 GB moment traffic" figures previously quoted here were
@@ -30,6 +31,7 @@ Config.SPARSE_EMBEDDING_UPDATES=False for strict dense-Adam semantics.
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -50,6 +52,20 @@ def init_row_adam(table) -> RowAdamState:
     shape = table["q"].shape if isinstance(table, dict) else table.shape
     return RowAdamState(m=jnp.zeros(shape, jnp.float32),
                         v=jnp.zeros(shape, jnp.float32))
+
+
+def adam_step_size(count, lr: float, b1: float, b2: float):
+    """The bias-corrected Adam step size lr * sqrt(1 - b2^c) /
+    (1 - b1^c), an f32 scalar; `count` is the (already incremented)
+    global step shared with the dense-parameter optimizer so bias
+    correction matches. 1 - b^c is taken as -expm1(c * log b): written
+    as `1 - b ** c` it cancels (0.999^3 = 0.997), one ulp of the power
+    moves the step size by 1e-5, and two compilations of that one line
+    — XLA's and the one feeding the Mosaic kernel — then disagree by
+    that much (seen on the chip, PR 21)."""
+    c = count.astype(jnp.float32)
+    return (lr * jnp.sqrt(-jnp.expm1(c * math.log(b2)))
+            / -jnp.expm1(c * math.log(b1)))
 
 
 def row_adam_update(table: jax.Array, state: RowAdamState,
@@ -81,8 +97,7 @@ def row_adam_update(table: jax.Array, state: RowAdamState,
 
     m_new = b1 * m_rows + (1.0 - b1) * g
     v_new = b2 * v_rows + (1.0 - b2) * jnp.square(g)
-    c = count.astype(jnp.float32)
-    lr_t = lr * jnp.sqrt(1.0 - b2 ** c) / (1.0 - b1 ** c)
+    lr_t = adam_step_size(count, lr, b1, b2)
     p_new = p_rows - lr_t * m_new / (jnp.sqrt(v_new) + eps)
 
     table = table.at[ids].set(p_new)

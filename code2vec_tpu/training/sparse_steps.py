@@ -13,8 +13,8 @@ machinery. Dense params (TRANSFORM / ATTENTION — and TARGET_WORDS_VOCAB
 when running full softmax, whose logits touch every row anyway) keep
 ordinary optax Adam.
 
-Why: BENCH_r05 measures the shipped dense-path step at 6.66M pc/s/chip
-against an 8.48M fwd/bwd floor (optimizer efficiency 0.786, HBM at
+Why: BENCH_r05 (git history at a4bf2f7) measures the shipped
+dense-path step at 6.66M pc/s/chip against an 8.48M fwd/bwd floor (optimizer efficiency 0.786, HBM at
 15.7% of the 637 GB/s ceiling) — the gap IS the dense backward scatter
 plus the table-proportional optimizer walk this module avoids. The
 round-6 lesson (the fused requantize row-pass turned the int8 +26%

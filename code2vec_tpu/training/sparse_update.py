@@ -1,9 +1,10 @@
 """Fused sparse table-update facade: dedup + segment-sum + live-row
 optimizer update (ROADMAP item 1, round 13).
 
-BENCH_r05 pins the per-chip step at 6.66M pc/s against an 8.48M fwd/bwd
-floor (optimizer efficiency 0.786) with HBM at 15.7% of the 637 GB/s
-ceiling: the step is backward-scatter-bound. A batch touches far fewer
+BENCH_r05 (git history at a4bf2f7) pins the per-chip step at 6.66M
+pc/s against an 8.48M fwd/bwd floor (optimizer efficiency 0.786) with
+HBM at 15.7% of the 637 GB/s ceiling: the step is
+backward-scatter-bound. A batch touches far fewer
 than V unique token/path ids, yet the dense-path gradients flow through
 a dense [V, E] carrier (the VJP of a gather) and the optimizer/requant
 apply walks far more rows than it needs. This module removes the dense
@@ -24,18 +25,18 @@ carrier from the sparse path entirely:
      primitive, so a live-row pass and a full-table pass draw identical
      dither for the same element index and salt).
 
-Dispatch follows the ops/quant.requantize pattern: the fused Pallas
-kernel (ops/pallas_sparse_update.py — one pass over the live rows,
-per-row DMA gather/scatter, no [V, E] materialization) on a
-single-device TPU backend, the XLA gather/scatter reference on CPU;
+Dispatch (`_resolve_fused`): the fused Pallas kernel
+(ops/pallas_sparse_update.py — one pass over the live rows, per-row
+DMA gather/scatter, no [V, E] materialization) where it compiles — a
+TPU and float32 table rows — and the XLA gather/scatter reference
+everywhere else, bf16 and int8 tables on a TPU included;
 `Config.SPARSE_UPDATE_PALLAS` ("auto" | "fused" | "reference") maps
 onto the `fused` argument via `resolve_sparse_update_mode`. Under a
 MESH (round 14) `mesh_sparse_apply` runs the SAME compact path per
 device inside `shard_map` — the GSPMD partitioner never sees the
 dedup composition it miscompiles, and the flag is honored everywhere.
 The reference and the kernel share the row-math helpers below (single
-source of truth), so fused-vs-reference parity is bit-exact on
-float/bf16 tables and q-exact on int8 under a shared salt.
+source of truth); the parity contract is in the kernel's docstring.
 
 Consumed by training/sparse_steps.py (code2vec head: cotangents arrive
 at gathered-row granularity, no dense carrier anywhere) and
@@ -54,7 +55,8 @@ import jax.numpy as jnp
 
 from code2vec_tpu.ops.quant import (_SCALE_FLOOR, QuantTable,
                                     dither_from_index, is_quantized)
-from code2vec_tpu.training.sparse_adam import RowAdamState
+from code2vec_tpu.training.sparse_adam import (RowAdamState,
+                                               adam_step_size)
 
 # Unique-row slots per kernel program. 512 rows x E=128 keeps the
 # per-block VMEM working set (p/m/v or q/s/m/v row blocks + f32 temps)
@@ -102,21 +104,19 @@ def dedup_segment_sum(ids: jax.Array, grads: jax.Array, num_rows: int,
 # VMEM blocks — one definition, so fused-vs-reference parity cannot
 # drift) ----
 
-def row_adam_math(p, m, v, g, count, lr: float, b1: float, b2: float,
-                  eps: float):
-    """One Adam step for a block of rows, all f32. `count` is the
-    (already incremented) global step shared with the dense-parameter
-    optimizer so bias correction matches."""
+def row_adam_math(p, m, v, g, lr_t, b1: float, b2: float, eps: float):
+    """One Adam step for a block of rows, all f32, at the
+    bias-corrected step size `lr_t` — sparse_adam.adam_step_size,
+    computed OUTSIDE the kernel: Mosaic lowers no scalar power
+    ("failed to legalize operation 'math.powf'")."""
     m_new = b1 * m + (1.0 - b1) * g
     v_new = b2 * v + (1.0 - b2) * jnp.square(g)
-    c = count.astype(jnp.float32)
-    lr_t = lr * jnp.sqrt(1.0 - b2 ** c) / (1.0 - b1 ** c)
     p_new = p - lr_t * m_new / (jnp.sqrt(v_new) + eps)
     return p_new, m_new, v_new
 
 
-def requant_row_math(q, s, m, v, g, row_ids, salt, count, lr: float,
-                     b1: float, b2: float, eps: float):
+def requant_row_math(q, s, m, v, g, row_ids, salt, lr_t, b1: float,
+                     b2: float, eps: float):
     """Row-Adam + requantize for a block of int8 rows: dequantize,
     Adam in f32, per-row absmax rescale, counter-hash dither over the
     ABSOLUTE [V, E] element index (row id * E + col — the same stream a
@@ -124,8 +124,7 @@ def requant_row_math(q, s, m, v, g, row_ids, salt, count, lr: float,
     `row_ids` are the rows' table indices (int32 [R]); padded sentinel
     rows produce garbage that the caller discards."""
     f = q.astype(jnp.float32) * s
-    p_new, m_new, v_new = row_adam_math(f, m, v, g, count, lr, b1, b2,
-                                        eps)
+    p_new, m_new, v_new = row_adam_math(f, m, v, g, lr_t, b1, b2, eps)
     absmax = jnp.max(jnp.abs(p_new), axis=1, keepdims=True)
     s_new = jnp.maximum(absmax, _SCALE_FLOOR) / 127.0
     x = p_new / s_new
@@ -146,8 +145,8 @@ def _apply_rows_reference(table, state: RowAdamState, uids, seg, count,
     p = jnp.take(table, uids, axis=0, mode="clip").astype(jnp.float32)
     m = jnp.take(state.m, uids, axis=0, mode="clip")
     v = jnp.take(state.v, uids, axis=0, mode="clip")
-    p_new, m_new, v_new = row_adam_math(p, m, v, seg, count, lr, b1,
-                                        b2, eps)
+    p_new, m_new, v_new = row_adam_math(
+        p, m, v, seg, adam_step_size(count, lr, b1, b2), b1, b2, eps)
     table = table.at[uids].set(p_new.astype(table.dtype), mode="drop")
     m = state.m.at[uids].set(m_new, mode="drop")
     v = state.v.at[uids].set(v_new, mode="drop")
@@ -168,7 +167,7 @@ def _apply_quant_rows_reference(qt: QuantTable, state: RowAdamState,
     v = jnp.take(state.v, uids, axis=0, mode="clip")
     q_new, s_new, m_new, v_new = requant_row_math(
         q, s, m, v, seg, uids if dither_ids is None else dither_ids,
-        salt, count, lr, b1, b2, eps)
+        salt, adam_step_size(count, lr, b1, b2), b1, b2, eps)
     new_q = qt["q"].at[uids].set(q_new, mode="drop")
     new_s = qt["s"].at[uids].set(s_new, mode="drop")
     new_m = state.m.at[uids].set(m_new, mode="drop")
@@ -178,9 +177,20 @@ def _apply_quant_rows_reference(qt: QuantTable, state: RowAdamState,
 
 # ---- dispatch ----
 
-def _resolve_fused(fused) -> bool:
+def _resolve_fused(fused, table) -> bool:
+    """`fused=None` (auto) selects the Pallas live-row kernel where it
+    compiles: a TPU and 32-bit table rows. The kernel gathers rows by
+    single-row DMA, and Mosaic (libtpu 0.0.34) refuses that for packed
+    dtypes — a bf16/int8 row shares its 32-bit words with its
+    neighbours: "Slice shape along dimension 0 must be aligned to
+    tiling (8), but is 1" on `tpu.memref_slice` of
+    memref<Vx128xbf16, #tpu.tiled<(8,128)(2,1)>, hbm>. Those tables
+    take the XLA reference; `fused=True` on them raises that message
+    on a TPU."""
     if fused is None:
-        return jax.default_backend() == "tpu"
+        return (jax.default_backend() == "tpu"
+                and not is_quantized(table)
+                and table.dtype == jnp.float32)
     return bool(fused)
 
 
@@ -193,14 +203,14 @@ def sparse_row_adam(table: jax.Array, state: RowAdamState,
 
     `ids` [N] (any shape, flattened) with per-occurrence cotangents
     `grads` [N, E]; only the unique rows are read or written — no dense
-    [V, E] carrier. `fused=None` auto-selects the Pallas kernel on a
-    TPU backend. Single-device entry: mesh steps route through
+    [V, E] carrier. `fused=None` auto-selects (`_resolve_fused`).
+    Single-device entry: mesh steps route through
     `mesh_sparse_apply`, which runs the same dedup + apply per device
     inside shard_map. Returns (new_table, new_state)."""
     block_rows = block_rows or _BLOCK_ROWS
     uids, seg = dedup_segment_sum(ids, grads, table.shape[0],
                                   block_rows=block_rows)
-    if _resolve_fused(fused):
+    if _resolve_fused(fused, table):
         from code2vec_tpu.ops.pallas_sparse_update import \
             sparse_row_adam_fused
         return sparse_row_adam_fused(table, state, uids, seg,
@@ -225,7 +235,7 @@ def sparse_requant_adam(qt: QuantTable, state: RowAdamState,
     salt = jax.random.bits(rng, dtype=jnp.uint32)
     uids, seg = dedup_segment_sum(ids, grads, qt["q"].shape[0],
                                   block_rows=block_rows)
-    if _resolve_fused(fused):
+    if _resolve_fused(fused, qt):
         from code2vec_tpu.ops.pallas_sparse_update import \
             sparse_requant_adam_fused
         return sparse_requant_adam_fused(qt, state, uids, seg, salt,
@@ -344,7 +354,7 @@ def mesh_sparse_apply(mesh, table, state: RowAdamState, parts, *,
             luids = uids
         st = RowAdamState(m=m, v=v)
         if quant:
-            if model_shards > 1 or not _resolve_fused(fused):
+            if model_shards > 1 or not _resolve_fused(fused, tbl):
                 # the fused kernel derives dither from its gather ids;
                 # a model-sharded block needs the GLOBAL ids for that
                 # stream, which only the reference threads through
@@ -357,7 +367,7 @@ def mesh_sparse_apply(mesh, table, state: RowAdamState, parts, *,
                 new_t, new_st = sparse_requant_adam_fused(
                     tbl, st, luids, seg, salt_, count=count_, lr=lr,
                     b1=b1, b2=b2, eps=eps, block_rows=block_rows)
-        elif _resolve_fused(fused):
+        elif _resolve_fused(fused, tbl):
             from code2vec_tpu.ops.pallas_sparse_update import \
                 sparse_row_adam_fused
             new_t, new_st = sparse_row_adam_fused(
@@ -396,7 +406,7 @@ def rows_from_dense(table, state: RowAdamState, dense_grad: jax.Array,
     uids = jnp.unique(ids, size=slots, fill_value=num_rows)
     seg = jnp.take(dense_grad, uids, axis=0,
                    mode="clip").astype(jnp.float32)
-    if _resolve_fused(fused):
+    if _resolve_fused(fused, table):
         from code2vec_tpu.ops.pallas_sparse_update import \
             sparse_row_adam_fused
         return sparse_row_adam_fused(table, state, uids, seg,

@@ -449,7 +449,10 @@ def build_cli_spawn(child_cmd: Sequence[str], *, num_procs: int = 1,
     from the surviving process set; a cohort re-formed at ONE process
     gets no flags at all and runs plain single-process);
     `cpu_devices` pins the CPU harness's virtual device count via
-    `parallel/compat.cpu_worker_env`, BEFORE the child's jax import.
+    `parallel/compat.cpu_worker_env`, BEFORE the child's jax import,
+    and names the platform to the child (`--backend cpu`); without it
+    the children inherit this environment and may need the chip, so
+    the supervisor itself never touches JAX.
     `metrics_ports` gives member i a fixed `--metrics_port` (the fleet
     collector's scrape set must be knowable BEFORE launch, so members
     can't pick ephemeral ports). Child output streams to
@@ -470,6 +473,8 @@ def build_cli_spawn(child_cmd: Sequence[str], *, num_procs: int = 1,
         if cpu_devices is not None:
             from code2vec_tpu.parallel.compat import cpu_worker_env
             env = cpu_worker_env(cpu_devices)
+            if "--backend" not in cmd:
+                cmd += ["--backend", "cpu"]
         else:
             env = dict(os.environ)
         stdout = None

@@ -13,11 +13,9 @@ compare against a single-process oracle.
 import os
 import sys
 
-# 4 local CPU devices, pinned BEFORE the jax import: the env flag is the
-# only provisioning knob every supported JAX reads (the
-# `jax_num_cpu_devices` config key is newer-JAX-only —
-# parallel/compat.cpu_worker_env documents the seam). The parent
-# test strips XLA_FLAGS from the spawn env, so this append is authoritative.
+# 4 local CPU devices, pinned BEFORE the jax import (the same
+# provisioning as parallel/compat.cpu_worker_env). The parent test
+# strips XLA_FLAGS from the spawn env, so this append is authoritative.
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=4"

@@ -139,11 +139,35 @@ def test_default_metrics_include_sparse_gate():
     assert "sparse_pc_per_sec" in DEFAULT_METRICS
 
 
-def test_repo_trajectory_is_loadable():
-    """The real BENCH_r*.json history stays parseable by the gate (the
-    driver runs it against exactly these files)."""
-    rounds = load_rounds(REPO)
-    assert len(rounds) >= 2
+def _write_driver_rounds(dest) -> None:
+    """Five rounds in the shape the driver wraps a bench.py line in
+    ({n, cmd, rc, tail, parsed}), with the key set growing the way
+    bench.py's did: headline only, then the floor, then the int8 and
+    transformer cells."""
+    parsed = {"metric": "path-contexts/sec/chip"}
+    grown = [
+        {"value": 4700000.0},
+        {"value": 6000000.0, "fwd_bwd_floor_pc_per_sec": 8400000.0},
+        {"value": 6600000.0, "int8_pc_per_sec": 5300000.0,
+         "transformer_pc_per_sec": 2300000.0},
+        {"value": 6600000.0},
+        {"value": 6700000.0, "fwd_bwd_floor_pc_per_sec": 8500000.0,
+         "int8_pc_per_sec": 5400000.0},
+    ]
+    for n, new in enumerate(grown, start=1):
+        parsed = dict(parsed, **new)
+        with open(os.path.join(dest, f"BENCH_r0{n}.json"), "w") as f:
+            json.dump({"n": n, "cmd": "python bench.py", "rc": 0,
+                       "tail": json.dumps(parsed) + "\n",
+                       "parsed": parsed}, f)
+
+
+def test_repo_trajectory_is_loadable(tmp_path):
+    """A BENCH_r*.json history in the driver's wrapper shape stays
+    parseable by the gate."""
+    _write_driver_rounds(str(tmp_path))
+    rounds = load_rounds(str(tmp_path))
+    assert [r for r, _res in rounds] == [1, 2, 3, 4, 5]
     assert all("value" in res for _r, res in rounds)
 
 
@@ -297,21 +321,14 @@ def test_multichip_repo_trajectory_accepted():
     assert all(r["status"] != "REGRESSION" for r in rows)
 
 
-def test_bench_r06_with_phase_breakdown_passes_real_trajectory(
-        tmp_path):
+def test_bench_r06_with_phase_breakdown_passes_real_trajectory(tmp_path):
     """ISSUE 15 satellite (the round-13 TODO that keeps the trajectory
     gate alive): a BENCH_r06 carrying the new phase_* breakdown must
-    pass the DEFAULT gate against the repo's real BENCH_r01–r05 —
-    the new keys have no history yet (skip, by the mixed-schema rule)
-    and the headline metrics gate on-trajectory values. The driver's
-    post-round bench capture is exactly this shape (bench.py now emits
-    phase_* every round)."""
-    import shutil
-
+    pass the DEFAULT gate against five driver-shaped rounds without
+    them — the new keys have no history yet (skip, by the mixed-schema
+    rule) and the headline metrics gate on-trajectory values."""
     from tools.bench_regression import DEFAULT_METRICS
-    for n in range(1, 6):
-        shutil.copy(os.path.join(REPO, f"BENCH_r0{n}.json"),
-                    tmp_path / f"BENCH_r0{n}.json")
+    _write_driver_rounds(str(tmp_path))
     r06 = {"metric": "path-contexts/sec/chip", "value": 6700000.0,
            "fwd_bwd_floor_pc_per_sec": 8500000.0,
            "int8_pc_per_sec": 5400000.0,

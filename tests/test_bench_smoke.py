@@ -104,19 +104,6 @@ def test_step_hbm_bytes_counts_quantized_carrier():
     assert bench._step_hbm_bytes(params, opt_state) > naive
 
 
-def test_graft_entry_forward_compiles():
-    """entry() is the driver's single-chip compile check — keep it
-    importable and jittable."""
-    import jax
-
-    import __graft_entry__ as g
-    fn, args = g.entry()
-    # one-shot compile IS the test  # graftlint: disable=retrace-hazard
-    out = jax.jit(fn)(*args)
-    jax.block_until_ready(out)
-    assert out.shape[0] == 256
-
-
 def test_aggregate_projection_collective_model():
     """The v4-32 projection (tools/aggregate_projection.py) must model
     DP efficiency from explicit collective traffic, not imply 1.0

@@ -606,8 +606,8 @@ def test_live_cohort_straggler_ticket_and_merged_trace(tmp_path):
         "infeed/produce": {"action": "sleep", "delay_ms": 150,
                            "times": -1, "process": 1}})
     members_dir = str(tmp_path / "members")
-    # sync checkpointing: the loopback-Gloo transport race (the
-    # parallel/compat docstring family) reproduces deterministically
+    # sync checkpointing: the loopback-Gloo transport race
+    # reproduces deterministically
     # when the async writer thread's device work interleaves with a
     # cohort this skewed — verified pre-existing with the fault alone,
     # no fleet plane attached

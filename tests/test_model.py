@@ -251,11 +251,15 @@ def test_auto_resume_via_cli_dispatch(dataset, tmp_path, monkeypatch):
     import code2vec as cli
     ckpt = str(tmp_path / "ckpt")
 
+    # the CLI would otherwise turn this session's compile cache on
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+
     def run(epochs):
         monkeypatch.setattr(sys, "argv", [
             "code2vec.py", "--data", dataset, "--save", ckpt,
             "--epochs", str(epochs), "--batch_size", "32",
-            "--max_contexts", "16", "--auto_resume"])
+            "--max_contexts", "16", "--auto_resume",
+            "--backend", "cpu"])
         assert cli.main() == 0
         from code2vec_tpu.training.checkpoint import latest_step
         return latest_step(ckpt)

@@ -44,3 +44,45 @@ def test_pallas_train_step_matches_xla_train_step():
     for k in p1:
         np.testing.assert_allclose(np.asarray(p1[k]), np.asarray(p2[k]),
                                    atol=1e-4, err_msg=k)
+
+
+def _mesh_step_matches_single_device(dims, n_data=4):
+    """A partitioned step with the Pallas kernels in it (GSPMD refuses
+    to split a Mosaic call, so they sit in shard_map over the batch
+    axes — parallel/sharding.shard_map_over_batch) must take the same
+    step as one device: same loss, same params, replicated weights'
+    gradients summed over the shards."""
+    from code2vec_tpu.parallel.mesh import make_mesh
+    from code2vec_tpu.parallel.sharding import (shard_batch,
+                                                shard_opt_state,
+                                                shard_params)
+    params = init_params(jax.random.PRNGKey(0), dims)
+    opt = optax.adam(0.01)
+    batch = _batch()
+    rng = jax.random.PRNGKey(1)
+    step_1 = make_train_step(dims, opt, use_pallas=True)
+    p1, _, loss1 = step_1(jax.tree_util.tree_map(jnp.copy, params),
+                          opt.init(params), batch, rng)
+    mesh = make_mesh(n_data, 1, 1, devices=jax.devices()[:n_data])
+    step_m = make_train_step(dims, opt, use_pallas=True, mesh=mesh)
+    sp = shard_params(mesh, jax.tree_util.tree_map(jnp.copy, params))
+    p2, _, loss2 = step_m(
+        sp, shard_opt_state(mesh, opt.init(params), sp),
+        shard_batch(mesh, tuple(np.asarray(a) for a in batch)), rng)
+    np.testing.assert_allclose(float(loss1), float(loss2), rtol=1e-5)
+    for (k1, a), (_k2, b) in zip(
+            jax.tree_util.tree_flatten_with_path(p1)[0],
+            jax.tree_util.tree_flatten_with_path(p2)[0]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5,
+                                   err_msg=jax.tree_util.keystr(k1))
+
+
+def test_pallas_pool_inside_a_partitioned_step():
+    _mesh_step_matches_single_device(DIMS)
+
+
+def test_fused_mha_inside_a_partitioned_step():
+    import dataclasses
+    _mesh_step_matches_single_device(dataclasses.replace(
+        DIMS, encoder_type="transformer", xf_layers=1, xf_heads=2))

@@ -302,6 +302,16 @@ def test_phase_roofline_monitor_and_prometheus_render():
 
 # ---- acceptance: A/B trajectory parity + mid-train scrape ----
 
+@pytest.fixture
+def cpu_has_a_peak(monkeypatch):
+    """The roofline gauges divide by the published HBM peak of the
+    device_kind (code2vec_tpu/device.py) and are not published for a
+    kind the table does not list — 'cpu' among them. These runs test
+    the gauges' plumbing, so they list it."""
+    from code2vec_tpu import device
+    monkeypatch.setitem(device.HBM_PEAK_GBPS, "cpu", 100.0)
+
+
 @pytest.fixture(scope="module")
 def tiny_prefix(tmp_path_factory):
     from tests.helpers import build_tiny_dataset
@@ -310,7 +320,8 @@ def tiny_prefix(tmp_path_factory):
                               max_contexts=16)
 
 
-def test_train_ab_trajectory_bit_identical(tiny_prefix, tmp_path):
+def test_train_ab_trajectory_bit_identical(tiny_prefix, tmp_path,
+                                           cpu_has_a_peak):
     """--phase_profile off vs on (sampling every 2 steps): the final
     params are bit-identical — the off hot path is untouched AND the
     sampled steps' state updates are the fused dispatches. The on-run
@@ -343,7 +354,8 @@ def test_train_ab_trajectory_bit_identical(tiny_prefix, tmp_path):
 
 
 def test_metrics_scrape_has_health_phase_mid_train(tiny_prefix,
-                                                  tmp_path):
+                                                  tmp_path,
+                                                  cpu_has_a_peak):
     """Acceptance: a /metrics scrape DURING a --phase_profile run
     carries the health_phase_* roofline gauges and train_phase_*
     summaries. The run is held open by a gate after several sampled

@@ -4,8 +4,9 @@ ops/pallas_sparse_update.py, round 13).
 Covers: the dedup + segment-sum + scatter-back property against the
 dense-carrier oracle (bit-for-bit in f32, including heavy-duplicate /
 all-same / all-unique extremes), interpret-mode fused-vs-reference
-parity (bit-exact on f32/bf16 tables; q-exact on int8 under the shared
-dither salt), the dispatch + config resolution, the mesh path's
+parity (float ulp on f32/bf16 tables — the contract in
+ops/pallas_sparse_update.py; q-exact on int8 under the shared dither
+salt), the dispatch + config resolution, the mesh path's
 (mesh_sparse_apply, round 14) bit-exact agreement with BOTH the
 single-device compact apply and the dense-carrier reference on the
 8-device virtual mesh, a fused-path train smoke through
@@ -35,6 +36,23 @@ from code2vec_tpu.training.sparse_adam import (RowAdamState,
 from code2vec_tpu.training.sparse_steps import (init_sparse_opt_state,
                                                 make_sparse_train_step)
 from code2vec_tpu.training.steps import make_train_step
+
+
+def _ulps(ref, got, old) -> float:
+    """Largest |got - ref| in f32 units in the last place AT THE SCALE
+    OF THE OPERANDS: the larger of the value before the update and the
+    update itself. (Near a cancellation the result is far smaller than
+    its operands, and an ulp of the result would measure nothing.)"""
+    ref, got, old = (np.asarray(a, np.float32) for a in (ref, got, old))
+    scale = np.maximum(np.abs(old), np.abs(old - ref))
+    return float(np.max(np.abs(got - ref)
+                        / np.spacing(np.maximum(scale, 1e-30))))
+
+
+# The kernel's parity contract (ops/pallas_sparse_update.py): float ulp,
+# not bits. The interpreter sits a few ulp from XLA:CPU (measured max
+# 4); the compiled kernel on a v5e sits closer still (chip_smoke.py).
+_MAX_ULP = 8
 
 
 def _ids_cases(V, N, seed=0):
@@ -112,7 +130,8 @@ def test_scatter_back_equals_dense_carrier_path_f32():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fused_matches_reference(V, E, N, dtype):
     """The kernel IS the reference restructured around per-row DMA:
-    same shared row math -> bit-exact tables AND moments."""
+    same shared row math, compiled twice -> tables AND moments agree
+    to float ulp, and rows no id names stay bit-identical."""
     r = np.random.default_rng(V + N)
     table = jnp.asarray(r.normal(size=(V, E)) * 0.3).astype(dtype)
     state = RowAdamState(
@@ -130,12 +149,15 @@ def test_fused_matches_reference(V, E, N, dtype):
             table, state, ids, g, count=count)
 
     (t_ref, s_ref), (t_fus, s_fus) = run(False), run(True)
-    np.testing.assert_array_equal(
-        np.asarray(t_ref, np.float32), np.asarray(t_fus, np.float32))
-    np.testing.assert_array_equal(np.asarray(s_ref.m),
-                                  np.asarray(s_fus.m))
-    np.testing.assert_array_equal(np.asarray(s_ref.v),
-                                  np.asarray(s_fus.v))
+    assert _ulps(t_ref, t_fus, table) <= _MAX_ULP
+    assert _ulps(s_ref.m, s_fus.m, state.m) <= _MAX_ULP
+    assert _ulps(s_ref.v, s_fus.v, state.v) <= _MAX_ULP
+    untouched = np.setdiff1d(np.arange(V), np.asarray(ids))
+    for new, old in ((t_fus, table), (s_fus.m, state.m),
+                     (s_fus.v, state.v)):
+        np.testing.assert_array_equal(
+            np.asarray(new, np.float32)[untouched],
+            np.asarray(old, np.float32)[untouched])
 
 
 @pytest.mark.parametrize("V,E,N", [(64, 8, 100), (40, 16, 37),
@@ -321,9 +343,11 @@ def test_mesh_sparse_apply_bitexact_vs_carrier_f32():
 
 
 def test_fused_step_reproduces_reference_step_exactly():
-    """--sparse_update_pallas fused vs reference: identical training
-    trajectory (the flag-level A/B), through make_train_step's sparse
-    dispatch — the exact entry point jax_model uses."""
+    """--sparse_update_pallas fused vs reference: the same training
+    trajectory to float rounding (the flag-level A/B; the kernel's
+    contract is ulp-level, so four steps stay within 1e-5), through
+    make_train_step's sparse dispatch — the exact entry point
+    jax_model uses."""
     params = init_params(jax.random.PRNGKey(0), DIMS)
 
     def build(fused):
@@ -343,10 +367,11 @@ def test_fused_step_reproduces_reference_step_exactly():
         rng, k = jax.random.split(rng)
         p1, o1, l1 = ref_step(p1, o1, batch, k)
         p2, o2, l2 = fus_step(p2, o2, batch, k)
-    assert float(l1) == float(l2)
+    assert float(l1) == pytest.approx(float(l2), rel=1e-5)
     for key in p1:
-        np.testing.assert_array_equal(np.asarray(p1[key]),
-                                      np.asarray(p2[key]), err_msg=key)
+        np.testing.assert_allclose(np.asarray(p1[key]),
+                                   np.asarray(p2[key]), rtol=1e-5,
+                                   atol=1e-6, err_msg=key)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -137,6 +137,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=40)
     args = ap.parse_args()
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
 

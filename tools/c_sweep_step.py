@@ -37,6 +37,8 @@ def main() -> None:
                     choices=["bfloat16", "int8"])
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
     from tools._bench_common import load_bench_module
     bench = load_bench_module()
 

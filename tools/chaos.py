@@ -1,6 +1,9 @@
 #!/usr/bin/env python3
 """Chaos scenario runner (ISSUE 10): exercise the detect -> decide ->
-recover loop end to end, deterministically, on the CPU harness.
+recover loop end to end, deterministically, on the CPU harness —
+every child it spawns is forced onto virtual CPU devices
+(`compat.cpu_worker_env`, `--backend cpu`); nothing here reaches a
+chip.
 
 Each scenario builds a tiny synthetic dataset, runs REAL
 `code2vec.py` training processes under the REAL supervisor
@@ -126,7 +129,8 @@ def train_cmd(prefix: str, save_dir: str, *, epochs: int,
             "--data", prefix, "--save", save_dir,
             "--epochs", str(epochs), "--batch_size", str(batch),
             "--max_contexts", str(max_contexts),
-            "--lr_schedule", "constant", "--seed", "11"]
+            "--lr_schedule", "constant", "--seed", "11",
+            "--backend", "cpu"]
 
 
 def _run_plain(cmd: list, *, cpu_devices: int, timeout_s: float) -> None:
@@ -158,7 +162,7 @@ def _latest_state(ckpt_dir: str):
         os.path.join(ckpt_dir, f"step_{step}", "state"))
     sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
     with ocp.StandardCheckpointer() as c:
-        meta = c.metadata(path)
+        meta = c.metadata(path).item_metadata.tree
         def leaf_template(m):
             if m.shape:
                 return jax.ShapeDtypeStruct(m.shape, m.dtype,

@@ -177,6 +177,8 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
                          "async}.json Chrome traces to the cwd")
     ap.add_argument("--out", default=None, help="also append JSONL here")
     a = ap.parse_args(argv)
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
 
     result: Dict[str, Any] = {}
     with tempfile.TemporaryDirectory(prefix="epoch_overhead_") as wd:

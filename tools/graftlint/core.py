@@ -301,6 +301,7 @@ GENERIC_ATTRS = frozenset({
     "get", "put", "items", "keys", "values", "append", "add", "update",
     "pop", "close", "open", "read", "write", "run", "start", "stop",
     "join", "split", "copy", "clear", "count", "index", "sort", "submit",
+    "encode", "decode",  # str/bytes, not models/encoder.encode
 })
 
 

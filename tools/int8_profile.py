@@ -96,6 +96,8 @@ def main() -> None:
     ap.add_argument("--out", default=None)
     ap.add_argument("--dtypes", default="bfloat16,int8")
     args = ap.parse_args()
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
     from tools._bench_common import load_bench_module
     bench = load_bench_module()
 

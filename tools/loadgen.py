@@ -371,6 +371,8 @@ def main(argv=None) -> int:
                     help="JSON alert-rule file (see README)")
     ap.add_argument("--out", default=None, help="also write JSON here")
     args = ap.parse_args(argv)
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
     if args.load and args.synthetic:
         ap.error("--load and --synthetic are mutually exclusive")
     if (args.trace or args.watchdog_stall_s > 0
@@ -428,15 +430,9 @@ def main(argv=None) -> int:
                        modulation_period_s=args.modulation_period_s,
                        hot_key_frac=args.hot_key_frac,
                        hot_keys=args.hot_keys, seed=args.seed)
-        if compiled_after_warmup >= 0:
-            rep["compiled_variants_after_warmup"] = compiled_after_warmup
-            rep["new_compilations_under_load"] = (
-                model.predict_compile_count() - compiled_after_warmup)
-        else:
-            # -1 sentinel: the jit cache is not introspectable here —
-            # report unknown, never a false "0 compilations" pass
-            rep["compiled_variants_after_warmup"] = None
-            rep["new_compilations_under_load"] = None
+        rep["compiled_variants_after_warmup"] = compiled_after_warmup
+        rep["new_compilations_under_load"] = (
+            model.predict_compile_count() - compiled_after_warmup)
         server.close()
         reports.append(rep)
 

@@ -1,11 +1,13 @@
 #!/usr/bin/env python3
-"""Multi-process scaling-efficiency bench: the MULTICHIP_r*.json
-producer (ROADMAP item 2).
+"""Multi-process distribution-cost harness, ON THE CPU: the
+MULTICHIP_r*.json producer. Every worker it spawns is forced onto
+virtual CPU devices (`compat.cpu_worker_env`); it never touches a
+chip, and "MULTICHIP" names the topology it emulates, not where it
+ran — its numbers are counts and CPU ratios, not device metrics.
 
-BENCH_r*.json answers "how fast is one chip"; this driver answers "what
-fraction of that speed survives the REAL process boundary". It runs the
-same synthetic train harness twice over the SAME global device count
-and global batch:
+It answers "what fraction of single-process speed survives the REAL
+process boundary" by running the same synthetic train harness twice
+over the SAME global device count and global batch:
 
   baseline  1 process  x (procs * devices_per_proc) local CPU devices
   multi     `--procs` OS processes x `--devices_per_proc` devices each,
@@ -16,13 +18,11 @@ and global batch:
 `scaling_efficiency` = multi global pc/s / baseline global pc/s: with
 equal chips and equal math, anything below 1.0 is pure
 distribution cost (Gloo gradient allreduce, per-process infeed,
-coordination). Both legs run with the CPU collective knobs applied
-(`parallel/compat.enable_cpu_collectives` — async dispatch off), and
-the multi leg's workers are CPU-pinned to disjoint equal core groups
-(`taskset`) so each emulated host owns its cores the way a pod host
-owns its chips — without pinning every worker's XLA threadpool claims
-ALL cores and the ratio measures N× scheduler oversubscription, not
-distribution cost. See `_core_groups` / the compat docstring.
+coordination). The multi leg's workers are CPU-pinned to disjoint
+equal core groups (`taskset`) so each emulated host owns its cores the
+way a pod host owns its chips — without pinning every worker's XLA
+threadpool claims ALL cores and the ratio measures N× scheduler
+oversubscription, not distribution cost. See `_core_groups`.
 
 Usage (repo root):
 
@@ -41,11 +41,8 @@ re-formed cohort resumed from) and `recovery_seconds` (kill to first
 post-resize training step). `bench_regression --kind multichip` gates
 both as lower-is-better.
 
-Writes `MULTICHIP_r<next>.json` into `--out` (default: repo root; the
-seed rounds r01-r05 are the driver's failed-dryrun records — their
-shape carries no metrics and `tools/bench_regression.py --kind
-multichip` skips them) and prints the result JSON to stdout, bench.py
-style. `--no_write` suppresses the file for ad-hoc runs.
+Writes `MULTICHIP_r<next>.json` into `--out` (default: repo root)
+and prints the result JSON to stdout, bench.py style. `--no_write` suppresses the file for ad-hoc runs.
 
 The worker half of this file re-executes itself with `--worker`; the
 parent owns spawn, timeout and orphan cleanup (no worker survives a
@@ -112,19 +109,12 @@ def _worker(args) -> None:
     device count is pinned at backend build."""
     sys.path.insert(0, _REPO)
 
-    from code2vec_tpu.parallel.compat import disable_cpu_async_dispatch
     from code2vec_tpu.parallel.distributed import maybe_initialize
 
     if args.num_procs > 1:
-        # maybe_initialize applies the collective knobs itself
         maybe_initialize(
             coordinator_address=f"127.0.0.1:{args.port}",
             num_processes=args.num_procs, process_id=args.proc_id)
-    else:
-        # baseline leg: same timing knob (async dispatch off) without
-        # the distributed runtime, so the legs differ ONLY in topology
-        # (Gloo itself can't be selected without a distributed client)
-        disable_cpu_async_dispatch()
 
     import jax
     import jax.numpy as jnp

@@ -148,6 +148,8 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="append JSON lines here too")
     args = ap.parse_args()
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
 
     results = []
     for name in args.variants.split(","):

@@ -73,6 +73,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=239)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
 
     import jax.numpy as jnp
 

@@ -97,6 +97,8 @@ def main(argv=None) -> int:
                          "(default: parsed from --out)")
     ap.add_argument("--out", default="SERVING_r01.json")
     args = ap.parse_args(argv)
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
 
     from code2vec_tpu.config import Config
     from code2vec_tpu.data import preprocess as preprocess_mod

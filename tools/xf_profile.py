@@ -56,6 +56,8 @@ def main() -> None:
     # (hd=128 lane-aligned; --heads 4 reproduces the round-4
     # before/after comparison)
     args = ap.parse_args()
+    from code2vec_tpu.device import enable_compile_cache
+    enable_compile_cache()
     L, H = args.layers, args.heads
 
     import jax

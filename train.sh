@@ -1,7 +1,9 @@
 #!/usr/bin/env bash
 # Train driver — reference-compatible (SURVEY.md §3 "Train driver"):
-# set the dataset name/paths, invoke code2vec.py. Runs unchanged on the
-# TPU backend.
+# set the dataset name/paths, invoke code2vec.py. --backend is a demand:
+# the default `tpu` exits when JAX finds no TPU; `backend=cpu ./train.sh`
+# (with JAX_PLATFORMS=cpu) runs on the CPU on purpose. Compiled programs
+# are cached in $JAX_COMPILATION_CACHE_DIR, or <checkout>/.jax_cache.
 set -euo pipefail
 
 type=${type:-java-small}
