@@ -21,16 +21,103 @@ Multi-host note: each process prefetches its OWN reader shard in
 deterministic reader order, and `make_array_from_process_local_data`
 is per-process local work, so threading it does not reorder anything
 across hosts.
+
+The production record (ISSUE 26). Every batch is timed where the work
+happens, through the process-wide in-memory recorder
+(`obs.trace.memory_tracer()`; always on, spans also stand in a running
+profiler's trace on the device's clock):
+
+  infeed/read      producer   `next()` on the reader's iterator
+                              (`seq`, `rows`, `epoch_first`: the first
+                              batch of a pass holds the permutation)
+  infeed/transfer  producer   `put_fn(batch)`: host arrays, then the
+                              device_put (`seq`, `bytes`)
+  infeed/blocked   producer   the bounded put into the queue: the
+                              producer's slack (`seq`)
+  infeed/pop_wait  consumer   `q.get()` (`seq` of the batch it popped;
+                              of a chunk, its first)
+
+Batches, rows and bytes are counted by those attributes: one `seq` a
+batch, with its `rows` and `bytes`. The `BatchRecord` (sequence
+number, rows, bytes, where its read started and its transfer ended)
+rides the queue item, so the consumer's pop names the batch that
+caused it. Cost a batch: three spans on the producer, one on the
+consumer, tens of microseconds.
 """
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
-from typing import Callable, Iterable, Iterator, Tuple
+from typing import Callable, Iterable, Iterator, Optional, Tuple
+
+from code2vec_tpu.obs.trace import memory_tracer
 
 _SENTINEL = object()
 _EPOCH_END = object()
+# process-wide sequence number of a produced batch: the attribute that
+# ties a batch's producer spans to the consumer's pop of it
+_BATCH_SEQ = itertools.count()
+
+
+class BatchRecord:
+    """One produced batch: its sequence number, rows and bytes, and on
+    the recorder's clock where its read started and its transfer
+    ended. Rides the queue item the producer builds; `on_produced`
+    (the `--trace` hook) gets it after the transfer."""
+
+    __slots__ = ("seq", "rows", "bytes", "read_start", "transfer_end")
+
+    def __init__(self, seq: int, rows, read_start: float):
+        self.seq = seq
+        self.rows = rows
+        self.bytes = 0
+        self.read_start = read_start
+        self.transfer_end = None
+
+
+def _nbytes(arrays) -> int:
+    """Bytes of one batch's arrays, host or device (a device array is
+    as many bytes as the host array it was put from; a multi-process
+    global array reads its global size)."""
+    if not isinstance(arrays, (tuple, list)):
+        arrays = (arrays,)
+    return sum(int(getattr(a, "nbytes", 0)) for a in arrays)
+
+
+def _read_batches(batches: Iterable, recorder
+                  ) -> Iterator[Tuple[object, BatchRecord]]:
+    """One pass over the reader with `infeed/read` around each
+    `next()`. The `next()` that finds the pass exhausted is a span too
+    (`exhausted`, no `seq`): it is time on the same thread."""
+    it = iter(batches)
+    first = True
+    while True:
+        with recorder.start_span("infeed/read") as span:
+            try:
+                b = next(it)
+            except StopIteration:
+                span.attrs["exhausted"] = True
+                return
+            seq = next(_BATCH_SEQ)
+            rows = getattr(b, "num_valid_examples", None)
+            span.attrs.update(seq=seq, rows=rows, epoch_first=first)
+        yield b, BatchRecord(seq, rows, span.interval[0])
+        first = False
+
+
+def _transfer(fn: Callable, b, record: BatchRecord, recorder,
+              on_produced: Optional[Callable]):
+    """`fn(b)` under `infeed/transfer`; the bytes are those of what it
+    returns."""
+    with recorder.start_span("infeed/transfer", seq=record.seq) as span:
+        out = fn(b)
+        record.bytes = span.attrs["bytes"] = _nbytes(out)
+    record.transfer_end = span.interval[1]
+    if on_produced is not None:
+        on_produced(record)
+    return out
 
 
 class _ThreadedInfeed:
@@ -54,12 +141,35 @@ class _ThreadedInfeed:
         # producer wedged in parse/transfer" is distinguishable from
         # "nothing left to produce"
         self._heartbeat = None
+        # where the production record goes (injectable: a MemoryTracer
+        # with a fake clock), and the `--trace` hook called on the
+        # producer thread with each batch's record after its transfer
+        self._recorder = memory_tracer()
+        self._on_produced = None
 
     def _produce(self, put: Callable) -> None:
         raise NotImplementedError
 
     def _emit(self, item) -> Iterator[Tuple]:
         raise NotImplementedError
+
+    def _put_recorded(self, put: Callable, item,
+                      record: BatchRecord) -> bool:
+        """`put(item)` under `infeed/blocked`, named by `record`."""
+        with self._recorder.start_span("infeed/blocked", seq=record.seq):
+            return put(item)
+
+    def _pop(self, q: "queue.Queue"):
+        """`q.get()` under `infeed/pop_wait`, which names the batch it
+        popped (a chunk's first; none for an end marker)."""
+        with self._recorder.start_span("infeed/pop_wait") as span:
+            item = q.get()
+            record = item[-1]
+            if isinstance(record, list):  # a chunk's
+                record = record[0]
+            if isinstance(record, BatchRecord):
+                span.attrs["seq"] = record.seq
+        return item
 
     def __iter__(self) -> Iterator[Tuple]:
         q: queue.Queue = queue.Queue(maxsize=self._depth)
@@ -95,7 +205,7 @@ class _ThreadedInfeed:
         thread.start()
         try:
             while True:
-                item = q.get()
+                item = self._pop(q)
                 if item[0] is _SENTINEL:
                     thread.join()
                     if item[1] is not None:
@@ -130,12 +240,15 @@ class DevicePrefetcher(_ThreadedInfeed):
         self._put_fn = put_fn
 
     def _produce(self, put: Callable) -> None:
-        for b in self._batches:
-            if not put((self._put_fn(b), b)):
+        recorder, on_produced = self._recorder, self._on_produced
+        for b, record in _read_batches(self._batches, recorder):
+            dev = _transfer(self._put_fn, b, record, recorder,
+                            on_produced)
+            if not self._put_recorded(put, (dev, b, record), record):
                 return
 
     def _emit(self, item) -> Iterator[Tuple]:
-        yield item
+        yield item[:2]
 
 
 class ChunkedDevicePrefetcher(_ThreadedInfeed):
@@ -178,25 +291,37 @@ class ChunkedDevicePrefetcher(_ThreadedInfeed):
             import jax.numpy as jnp
             transfer = jnp.asarray
 
-        def ship(hosts, rows) -> bool:
-            stacked = tuple(
-                transfer(np.stack([r[f] for r in rows]))
-                for f in range(len(rows[0])))
-            return put((stacked, hosts))
+        recorder, on_produced = self._recorder, self._on_produced
 
-        hosts, rows = [], []
-        for b in self._batches:
+        def ship(hosts, rows, records) -> bool:
+            # the chunk's one stacked transfer and its one put are
+            # charged to its last batch (a second `infeed/transfer`
+            # under that seq, `stacked` = batches in it, no bytes: each
+            # batch's own span counted them)
+            last = records[-1]
+            with recorder.start_span("infeed/transfer", seq=last.seq,
+                                     stacked=len(rows)):
+                stacked = tuple(
+                    transfer(np.stack([r[f] for r in rows]))
+                    for f in range(len(rows[0])))
+            return self._put_recorded(put, (stacked, hosts, records),
+                                      last)
+
+        hosts, rows, records = [], [], []
+        for b, record in _read_batches(self._batches, recorder):
             hosts.append(b)
-            rows.append(self._to_arrays(b))
+            records.append(record)
+            rows.append(_transfer(self._to_arrays, b, record, recorder,
+                                  on_produced))
             if len(rows) == self._chunk:
-                if not ship(hosts, rows):
+                if not ship(hosts, rows, records):
                     return
-                hosts, rows = [], []
+                hosts, rows, records = [], [], []
         if rows:  # partial tail chunk
-            ship(hosts, rows)
+            ship(hosts, rows, records)
 
     def _emit(self, item) -> Iterator[Tuple]:
-        stacked, hosts = item
+        stacked, hosts, _records = item
         for i, host in enumerate(hosts):
             yield tuple(a[i] for a in stacked), host
 
@@ -209,10 +334,16 @@ class _SyncInfeed:
     def __init__(self, batches: Iterable, put_fn: Callable):
         self._batches = batches
         self._put_fn = put_fn
+        self._recorder = memory_tracer()
+        self._on_produced = None
 
     def __iter__(self) -> Iterator[Tuple]:
-        for b in self._batches:
-            yield self._put_fn(b), b
+        # read and transfer on the caller's thread; no queue, so no
+        # blocked time and no pop
+        recorder, on_produced = self._recorder, self._on_produced
+        for b, record in _read_batches(self._batches, recorder):
+            yield _transfer(self._put_fn, b, record, recorder,
+                            on_produced), b
 
 
 def prefetch_to_device(batches: Iterable, put_fn: Callable,
@@ -298,7 +429,7 @@ def persistent_epochs(infeed, num_epochs: int, first_epoch: int = 1
         if finished.is_set():
             return
         while True:
-            item = q.get()
+            item = infeed._pop(q)
             if item[0] is _EPOCH_END:
                 return
             if item[0] is _SENTINEL:
@@ -332,12 +463,14 @@ def build_train_infeed(reader: Iterable, *, chunk: int, depth: int,
     else depth-prefetched; logs instead of silently ignoring the chunk
     request when a mesh forces the fallback.
 
-    `instrument` (ISSUE 6 tracing) wraps the per-batch producer-side
-    function — it runs on the PRODUCER thread once per batch, so the
-    model can emit an `infeed/produce` span and send its context down
-    a SpanChannel without changing the queue's item shape. `heartbeat`
-    is the producer's obs.watchdog Heartbeat (beaten on every queue
-    put attempt). Both default to off and cost nothing when unset.
+    `instrument` (ISSUE 6 tracing; `obs.infeed_produce_instrument`)
+    is called on the PRODUCER thread with each batch's `BatchRecord`
+    once its transfer is done and before it is queued, so the model
+    can build its `infeed/produce` span from the record's own clock
+    reads and send the context down a SpanChannel in step with the
+    queue. `heartbeat` is the producer's obs.watchdog Heartbeat
+    (beaten on every queue put attempt). Both default to off and cost
+    nothing when unset.
 
     The `infeed/produce` failpoint (ISSUE 10, armed via --faults)
     wraps the same seam: an injected raise happens ON the producer
@@ -360,18 +493,16 @@ def build_train_infeed(reader: Iterable, *, chunk: int, depth: int,
             host_arrays_fn = _faulted(host_arrays_fn)
         else:
             device_batch_fn = _faulted(device_batch_fn)
-    if instrument is not None:
-        host_arrays_fn = instrument(host_arrays_fn)
-        device_batch_fn = instrument(device_batch_fn)
     if use_chunked:
         infeed = ChunkedDevicePrefetcher(reader, host_arrays_fn, chunk,
                                          depth=max(1, depth))
-        infeed._heartbeat = heartbeat
-        return infeed
-    if chunk > 1:
-        log("--infeed_chunk ignored: chunked infeed is single-device "
-            "only (mesh active); using depth prefetch")
-    infeed = prefetch_to_device(reader, device_batch_fn, depth)
+    else:
+        if chunk > 1:
+            log("--infeed_chunk ignored: chunked infeed is "
+                "single-device only (mesh active); using depth "
+                "prefetch")
+        infeed = prefetch_to_device(reader, device_batch_fn, depth)
+    infeed._on_produced = instrument
     if isinstance(infeed, _ThreadedInfeed):
         infeed._heartbeat = heartbeat
     return infeed

@@ -157,28 +157,37 @@ def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,
     kernel (ops/pallas_attention.py); inside a step partitioned over
     `mesh` each device runs it on its own batch rows.
     """
-    src = take_rows(params, "token_emb", source_ids)
-    pth = take_rows(params, "path_emb", path_ids)
-    dst = take_rows(params, "token_emb", target_ids)
-    contexts = jnp.concatenate([src, pth, dst], axis=-1).astype(compute_dtype)
+    # the step's phases by name (`c2v/...`): an op's metadata carries
+    # the scope path, the backward's as `transpose(jvp(c2v/...))`, so
+    # a profile tells the phases apart without reading shapes
+    with jax.named_scope("c2v/embed_gather"):
+        src = take_rows(params, "token_emb", source_ids)
+        pth = take_rows(params, "path_emb", path_ids)
+        dst = take_rows(params, "token_emb", target_ids)
 
-    if dropout_rng is not None and dropout_keep_rate < 1.0:
-        keep = jax.random.bernoulli(dropout_rng, dropout_keep_rate,
-                                    contexts.shape)
-        contexts = jnp.where(keep, contexts / dropout_keep_rate, 0.0)
+    with jax.named_scope("c2v/encode"):
+        contexts = jnp.concatenate([src, pth, dst],
+                                   axis=-1).astype(compute_dtype)
+        if dropout_rng is not None and dropout_keep_rate < 1.0:
+            keep = jax.random.bernoulli(dropout_rng, dropout_keep_rate,
+                                        contexts.shape)
+            contexts = jnp.where(keep, contexts / dropout_keep_rate, 0.0)
 
-    if use_pallas:
-        from code2vec_tpu.ops.pallas_attention import attention_pool_fused
-        pool = attention_pool_fused
-        if mesh is not None:
-            from code2vec_tpu.parallel.sharding import shard_map_over_batch
-            pool = shard_map_over_batch(pool, mesh,
-                                        (True, False, False, True))
-        code, attn = pool(contexts, params["transform"],
-                          params["attention"], mask)
-        return code.astype(compute_dtype), attn
-    return attention_pool(contexts, params["transform"],
-                          params["attention"], mask)
+    with jax.named_scope("c2v/pool"):
+        if use_pallas:
+            from code2vec_tpu.ops.pallas_attention import \
+                attention_pool_fused
+            pool = attention_pool_fused
+            if mesh is not None:
+                from code2vec_tpu.parallel.sharding import \
+                    shard_map_over_batch
+                pool = shard_map_over_batch(pool, mesh,
+                                            (True, False, False, True))
+            code, attn = pool(contexts, params["transform"],
+                              params["attention"], mask)
+            return code.astype(compute_dtype), attn
+        return attention_pool(contexts, params["transform"],
+                              params["attention"], mask)
 
 
 def get_encode_fn(dims: ModelDims, mesh=None):

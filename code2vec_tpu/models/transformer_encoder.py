@@ -127,21 +127,26 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
     if ring_mesh is not None:
         use_pallas = False
     xf = params["xf"]
-    emb = jnp.concatenate([
-        jnp.take(params["token_emb"], source_ids, axis=0),
-        jnp.take(params["path_emb"], path_ids, axis=0),
-        jnp.take(params["token_emb"], target_ids, axis=0),
-    ], axis=-1).astype(compute_dtype)                  # [B, C, D]
+    # phase scopes as in encoder.encode, `c2v/xf_layer_<i>` inside
+    # `c2v/encode`
+    with jax.named_scope("c2v/embed_gather"):
+        rows = [jnp.take(params["token_emb"], source_ids, axis=0),
+                jnp.take(params["path_emb"], path_ids, axis=0),
+                jnp.take(params["token_emb"], target_ids, axis=0)]
 
-    if dropout_rng is not None and dropout_keep_rate < 1.0:
-        keep = jax.random.bernoulli(dropout_rng, dropout_keep_rate,
-                                    emb.shape)
-        emb = jnp.where(keep, emb / dropout_keep_rate, 0.0)
+    with jax.named_scope("c2v/encode"):
+        emb = jnp.concatenate(rows, axis=-1).astype(
+            compute_dtype)                             # [B, C, D]
+        if dropout_rng is not None and dropout_keep_rate < 1.0:
+            keep = jax.random.bernoulli(dropout_rng, dropout_keep_rate,
+                                        emb.shape)
+            emb = jnp.where(keep, emb / dropout_keep_rate, 0.0)
 
-    # all-pad rows: keep one live key so softmax stays finite
-    safe_mask = jnp.where(jnp.sum(mask, axis=-1, keepdims=True) > 0,
-                          mask, jnp.ones_like(mask))
-    log_mask = jnp.log(jnp.maximum(safe_mask, 1e-30)).astype(jnp.float32)
+        # all-pad rows: keep one live key so softmax stays finite
+        safe_mask = jnp.where(jnp.sum(mask, axis=-1, keepdims=True) > 0,
+                              mask, jnp.ones_like(mask))
+        log_mask = jnp.log(jnp.maximum(safe_mask, 1e-30)).astype(
+            jnp.float32)
 
     def layer_fn(x, layer):
         h = _rms_norm(x, layer["ln1_scale"])
@@ -156,15 +161,18 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
         # O(1)-in-depth activation memory for CodeBERT-scale encoders
         layer_fn = jax.checkpoint(layer_fn)
 
-    x = emb @ xf["in_proj"].astype(compute_dtype)
-    for layer in xf["layers"]:
-        x = layer_fn(x, layer)
+    with jax.named_scope("c2v/encode"):
+        x = emb @ xf["in_proj"].astype(compute_dtype)
+        for i, layer in enumerate(xf["layers"]):
+            with jax.named_scope(f"c2v/xf_layer_{i}"):
+                x = layer_fn(x, layer)
 
-    x = _rms_norm(x, xf["ln_f_scale"])
-    # learned-query pool (the reference's attention pool, over the
-    # transformed representations)
-    pool_logits = (x.astype(jnp.float32)
-                   @ xf["pool_query"].astype(jnp.float32)) + log_mask
-    attn = jax.nn.softmax(pool_logits, axis=-1)        # [B, C]
-    code = jnp.einsum("bc,bcd->bd", attn.astype(compute_dtype), x)
+    with jax.named_scope("c2v/pool"):
+        x = _rms_norm(x, xf["ln_f_scale"])
+        # learned-query pool (the reference's attention pool, over the
+        # transformed representations)
+        pool_logits = (x.astype(jnp.float32)
+                       @ xf["pool_query"].astype(jnp.float32)) + log_mask
+        attn = jax.nn.softmax(pool_logits, axis=-1)    # [B, C]
+        code = jnp.einsum("bc,bcd->bd", attn.astype(compute_dtype), x)
     return code, attn

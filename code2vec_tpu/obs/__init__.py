@@ -27,7 +27,8 @@ from code2vec_tpu.obs.telemetry import (SUMMARY_PERCENTILES,  # noqa: F401
                                         Telemetry, TimerStat,
                                         device_sync,
                                         format_latency_line)
-from code2vec_tpu.obs.trace import (SpanChannel, SpanContext,  # noqa: F401
-                                    Tracer, TraceSpan)
+from code2vec_tpu.obs.trace import (MemoryTracer,  # noqa: F401
+                                    SpanChannel, SpanContext, Tracer,
+                                    TraceSpan, memory_tracer)
 from code2vec_tpu.obs.watchdog import (Heartbeat, StallError,  # noqa: F401
                                        Watchdog)

@@ -47,27 +47,26 @@ from code2vec_tpu.obs.trace import SpanChannel, SpanContext, Tracer
 
 def infeed_produce_instrument(tracer: Tracer,
                               channel: Optional[SpanChannel]):
-    """Producer-side tracing hook for `build_train_infeed`: wraps the
-    per-batch parse/transfer function so each batch gets an
-    `infeed/produce` span ON the producer thread, whose context is
-    handed to the consuming step through `channel` (FIFO-aligned with
-    the infeed queue — the recorder links it from the step span).
-    Returns None when tracing is off, so the infeed path stays
-    byte-identical to the untraced one. ONE definition shared by both
-    train loops: the FIFO handoff contract must not drift between
-    them."""
+    """Producer-side tracing hook for `build_train_infeed`: called ON
+    the producer thread with each batch's production record
+    (data/prefetch.py `BatchRecord`), it gives the batch an
+    `infeed/produce` span from the start of the reader's `next()` to
+    the end of the transfer — built from the record's clock reads, the
+    in-memory recorder's (`time.monotonic`, this tracer's default
+    too), never from a second pair — whose context is handed to the
+    consuming step through `channel` (FIFO-aligned with the infeed
+    queue — the recorder links it from the step span). Returns None
+    when tracing is off, so the infeed path stays the untraced one.
+    ONE definition shared by both train loops: the FIFO handoff
+    contract must not drift between them."""
     if not tracer.enabled:
         return None
 
-    def instrument(fn):
-        def produce(batch):
-            t0 = tracer.clock()
-            out = fn(batch)
-            channel.send(tracer.record_span(
-                "infeed/produce", t0, tracer.clock()))
-            return out
-        return produce
-    return instrument
+    def on_produced(record) -> None:
+        channel.send(tracer.record_span(
+            "infeed/produce", record.read_start, record.transfer_end,
+            seq=record.seq, rows=record.rows, bytes=record.bytes))
+    return on_produced
 
 
 class TrainStepRecorder:

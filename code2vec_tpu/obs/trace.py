@@ -39,6 +39,17 @@ shared by every span in a tracer, so retroactively recorded spans
 batcher reuses `PredictRequest.enqueued_at` (also `time.monotonic`)
 as the queue-wait span's start.
 
+In-memory recorder (ISSUE 26): `memory_tracer()` is the process-wide,
+sink-less `MemoryTracer` — the same span machinery with `_emit`
+appending to a bounded deque instead of the JSONL sink, always on, and
+readable after the objects that fed it are gone. A span opened through
+it as a context manager also enters a `jax.profiler.TraceAnnotation` of
+the same name, so whenever a profiler session runs the span stands in
+the `.xplane.pb` on the device trace's own clock; with no session the
+annotation is TraceMe's no-op. The input pipeline's producer thread
+records through it (data/prefetch.py: `infeed/read`, `infeed/transfer`,
+`infeed/blocked`, and the consumer's `infeed/pop_wait`).
+
 Disabled path (the PR 2 discipline): `Tracer.disabled()` is a shared
 singleton whose `enabled` is False and whose methods return the one
 shared `_NullTraceSpan` — hot paths guard on the ONE boolean and
@@ -51,11 +62,29 @@ from __future__ import annotations
 
 import collections
 import itertools
+import sys
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Union
 
-__all__ = ["SpanContext", "SpanChannel", "TraceSpan", "Tracer"]
+__all__ = ["MemoryTracer", "SpanContext", "SpanChannel", "TraceSpan",
+           "Tracer", "memory_tracer"]
+
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is there
+
+
+def _profiler_annotation(name: str):
+    """A `jax.profiler.TraceAnnotation(name)`, or None in a process
+    that has not imported jax (no profiler session can run there, and
+    obs/ never imports jax itself: tests/test_obs_guard.py)."""
+    global _TRACE_ANNOTATION
+    cls = _TRACE_ANNOTATION
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation as cls
+        _TRACE_ANNOTATION = cls
+    return cls(name)
 
 
 class SpanContext(NamedTuple):
@@ -98,7 +127,8 @@ class TraceSpan:
     thread-local current span (implicit within-thread parentage)."""
 
     __slots__ = ("_tracer", "name", "trace_id", "span_id", "parent_id",
-                 "_t0", "_tid", "_tname", "links", "attrs", "_prev")
+                 "_t0", "_t1", "_tid", "_tname", "links", "attrs",
+                 "_prev", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: Optional[str],
@@ -114,10 +144,19 @@ class TraceSpan:
         self._tid = t.ident or 0
         self._tname = t.name
         self._prev = None
+        self._annotation = None
+        self._t1 = None
         self._t0 = tracer.clock()
 
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id)
+
+    @property
+    def interval(self) -> tuple:
+        """(start, end) on the tracer's clock; end is None while the
+        span is open. What `record_span` takes, so a second span can be
+        built from these reads instead of its own."""
+        return self._t0, self._t1
 
     def end(self, **extra) -> float:
         """Close the span and emit its record; returns the duration in
@@ -127,7 +166,7 @@ class TraceSpan:
         tracer, self._tracer = self._tracer, None
         if tracer is None:
             return 0.0
-        t1 = tracer.clock()
+        t1 = self._t1 = tracer.clock()
         if extra:
             self.attrs.update(extra)
         tracer._finish(self, t1)
@@ -136,12 +175,20 @@ class TraceSpan:
     # context-manager form: current-span bookkeeping for implicit
     # within-thread parentage
     def __enter__(self) -> "TraceSpan":
-        tls = self._tracer._tls
+        tracer = self._tracer
+        tls = tracer._tls
         self._prev = getattr(tls, "current", None)
         tls.current = self
+        if tracer.annotate:
+            ann = self._annotation = _profiler_annotation(self.name)
+            if ann is not None:
+                ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        ann, self._annotation = self._annotation, None
+        if ann is not None:
+            ann.__exit__(exc_type, exc, tb)
         tracer = self._tracer
         if tracer is not None:  # not already end()ed early
             tracer._tls.current = self._prev
@@ -188,6 +235,10 @@ class Tracer:
     `events.jsonl` the rest of the run writes and `--trace` needs no
     second output path. The live-span table (unfinished spans) feeds
     the watchdog's stall dump."""
+
+    # context-manager spans also enter a profiler annotation
+    # (MemoryTracer turns it on)
+    annotate = False
 
     def __init__(self, telemetry, clock=time.monotonic):
         self.enabled = True
@@ -337,3 +388,42 @@ class _NullTracer(Tracer):
 
 
 _NULL_TRACER = _NullTracer()
+
+
+class MemoryTracer(Tracer):
+    """The sink-less recorder: finished spans go to a bounded deque as
+    plain dicts (`name`, `t0`, `t1` unrounded on `clock`, `tname` the
+    thread's name, `attrs`), oldest dropped first. Always on, so it
+    records where `--trace` is unset and under the benchmark, whose
+    readers take `records()` after the model is freed. `maxlen` counts
+    spans: the infeed emits four a batch, so the default holds the last
+    4,096 batches (over an hour of java-large steps)."""
+
+    annotate = True
+
+    def __init__(self, maxlen: int = 4 * 4096, clock=time.monotonic):
+        super().__init__(None, clock=clock)
+        self._records: "collections.deque" = collections.deque(
+            maxlen=maxlen)
+
+    def _emit(self, name, trace_id, span_id, parent_id, links, tid,
+              tname, t0, t1, attrs) -> None:
+        rec = {"name": name, "t0": t0, "t1": t1, "tname": tname,
+               "attrs": attrs}
+        with self._lock:  # a snapshot must not meet a moving deque
+            self._records.append(rec)
+
+    def records(self, prefix: str = "") -> List[Dict[str, Any]]:
+        """Snapshot of the kept spans whose name starts with `prefix`,
+        in the order they ended."""
+        with self._lock:
+            kept = list(self._records)
+        return [r for r in kept if r["name"].startswith(prefix)]
+
+
+_MEMORY_TRACER = MemoryTracer()
+
+
+def memory_tracer() -> MemoryTracer:
+    """The process-wide in-memory recorder."""
+    return _MEMORY_TRACER

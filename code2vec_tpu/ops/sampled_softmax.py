@@ -148,13 +148,16 @@ def sampled_softmax_loss(
         vocab_size = target_table.shape[0]
     # S > V degenerates to the exhaustive candidate set (full softmax)
     num_sampled = min(num_sampled, vocab_size)
-    sampled = log_uniform_sample(rng, num_sampled, vocab_size)  # [S]
-    loss = sampled_softmax_from_gathered(
-        code_vectors,
-        true_w=target_table[labels],
-        samp_w=target_table[sampled],
-        true_corr=_log_expected_count(labels, num_sampled, vocab_size),
-        samp_corr=_log_expected_count(sampled, num_sampled, vocab_size),
-        accidental=sampled[None, :] == labels[:, None],
-        example_weights=example_weights)
+    with jax.named_scope("c2v/loss"):  # the step's loss phase
+        sampled = log_uniform_sample(rng, num_sampled, vocab_size)  # [S]
+        loss = sampled_softmax_from_gathered(
+            code_vectors,
+            true_w=target_table[labels],
+            samp_w=target_table[sampled],
+            true_corr=_log_expected_count(labels, num_sampled,
+                                          vocab_size),
+            samp_corr=_log_expected_count(sampled, num_sampled,
+                                          vocab_size),
+            accidental=sampled[None, :] == labels[:, None],
+            example_weights=example_weights)
     return loss, sampled

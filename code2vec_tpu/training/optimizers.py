@@ -172,6 +172,38 @@ def resolve_checkpoint_warmup(schedule: str, requested: int,
     return ckpt_warmup if ckpt_warmup > 0 else requested
 
 
+def _scoped(tx: optax.GradientTransformation, scope: str
+            ) -> optax.GradientTransformation:
+    """`tx` with its update traced under `jax.named_scope(scope)`, so
+    a profile names the optimizer's walk over its leaves as a phase of
+    the step (`c2v/table_apply`, `c2v/dense_apply`; `apply_updates`
+    puts the parameter add under the same names). Metadata only: the
+    state's structure and the numbers are `tx`'s."""
+    tx = optax.with_extra_args_support(tx)
+
+    def update_fn(updates, state, params=None, **extra_args):
+        with jax.named_scope(scope):
+            return tx.update(updates, state, params, **extra_args)
+
+    return optax.GradientTransformationExtraArgs(tx.init, update_fn)
+
+
+def apply_updates(params, updates, skip=()):
+    """`optax.apply_updates` key by key, each under its phase's scope:
+    `c2v/table_apply` for the vocab tables, `c2v/dense_apply` for the
+    rest, in every train step (bag, int8, sparse, varmisuse). Keys in
+    `skip` are left out."""
+    out = {}
+    for k in params:
+        if k in skip:
+            continue
+        scope = "c2v/table_apply" if k in TABLE_PARAMS \
+            else "c2v/dense_apply"
+        with jax.named_scope(scope):
+            out[k] = optax.apply_updates(params[k], updates[k])
+    return out
+
+
 def make_optimizer(learning_rate,
                    embedding_optimizer: str = "adafactor",
                    trust_ratio: bool = False,
@@ -205,13 +237,13 @@ def make_optimizer(learning_rate,
                 "--trust_ratio_scope dense requires the adafactor "
                 "embedding optimizer (adam runs one transform over "
                 "all params, so there is no table/dense split).")
-        if not trust_ratio:
-            return optax.chain(
-                scale_by_adam_f32_moments(),
-                optax.scale_by_learning_rate(learning_rate))
-        return optax.chain(scale_by_adam_f32_moments(),
-                           optax.scale_by_trust_ratio(),
-                           optax.scale_by_learning_rate(learning_rate))
+        # one transform over every leaf, tables and dense alike, so
+        # the walk has a name of its own: neither phase's
+        trust = (optax.scale_by_trust_ratio(),) if trust_ratio else ()
+        return _scoped(optax.chain(
+            scale_by_adam_f32_moments(), *trust,
+            optax.scale_by_learning_rate(learning_rate)),
+            "c2v/apply")
     if embedding_optimizer == "adafactor":
         # label by key so extra head params (e.g. vm_pointer) route to
         # adam automatically
@@ -249,8 +281,9 @@ def make_optimizer(learning_rate,
                 optax.scale_by_adam(),
                 optax.scale_by_trust_ratio(),
                 optax.scale_by_learning_rate(learning_rate))
-        return optax.multi_transform({"table": table_tx,
-                                      "small": small_tx}, labels)
+        return optax.multi_transform(
+            {"table": _scoped(table_tx, "c2v/table_apply"),
+             "small": _scoped(small_tx, "c2v/dense_apply")}, labels)
     raise ValueError(
         f"unknown embedding_optimizer {embedding_optimizer!r} "
         "(expected 'adam' or 'adafactor')")

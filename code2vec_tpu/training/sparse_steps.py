@@ -42,6 +42,7 @@ from code2vec_tpu.ops.attention import attention_pool
 from code2vec_tpu.ops.quant import is_quantized
 from code2vec_tpu.ops.sampled_softmax import (
     _log_expected_count, log_uniform_sample)
+from code2vec_tpu.training.optimizers import apply_updates
 from code2vec_tpu.training.sparse_adam import init_row_adam
 from code2vec_tpu.training.sparse_update import (mesh_sparse_apply,
                                                  sparse_requant_adam,
@@ -102,14 +103,19 @@ def prepare_step_inputs(params, batch, rng, *, use_sampled_softmax:
         ctx["samp_corr"] = _log_expected_count(sampled, S, V)     # [S]
         ctx["accidental"] = sampled[None, :] == labels[:, None]   # [B,S]
 
-    # ---- gathers OUTSIDE the differentiated function ----
-    gathered = {"src_e": _gather_rows(params["token_emb"], src),
-                "pth_e": _gather_rows(params["path_emb"], pth),
-                "dst_e": _gather_rows(params["token_emb"], dst)}
+    # ---- gathers OUTSIDE the differentiated function, under the
+    # phase names models/encoder.py and ops/sampled_softmax.py give
+    # the dense step's ----
+    with jax.named_scope("c2v/embed_gather"):
+        gathered = {"src_e": _gather_rows(params["token_emb"], src),
+                    "pth_e": _gather_rows(params["path_emb"], pth),
+                    "dst_e": _gather_rows(params["token_emb"], dst)}
     if use_sampled_softmax:
-        gathered["true_w"] = _gather_rows(params["target_emb"], labels)
-        gathered["samp_w"] = _gather_rows(params["target_emb"],
-                                          ctx["sampled"])
+        with jax.named_scope("c2v/loss"):
+            gathered["true_w"] = _gather_rows(params["target_emb"],
+                                              labels)
+            gathered["samp_w"] = _gather_rows(params["target_emb"],
+                                              ctx["sampled"])
 
     dense_keys = ["transform", "attention"]
     if not use_sampled_softmax:
@@ -127,18 +133,24 @@ def make_gathered_loss(dims: ModelDims, ctx, *, use_sampled_softmax:
     mask, weights = ctx["mask"], ctx["weights"]
 
     def loss_fn(dense, gathered):
-        contexts = jnp.concatenate(
-            [gathered["src_e"], gathered["pth_e"], gathered["dst_e"]],
-            axis=-1).astype(compute_dtype)
-        if dims.dropout_keep_rate < 1.0:
-            keep = jax.random.bernoulli(
-                ctx["drop_rng"], dims.dropout_keep_rate,
-                contexts.shape)
-            contexts = jnp.where(keep,
-                                 contexts / dims.dropout_keep_rate,
-                                 0.0)
-        code, _ = attention_pool(contexts, dense["transform"],
-                                 dense["attention"], mask)
+        with jax.named_scope("c2v/encode"):
+            contexts = jnp.concatenate(
+                [gathered["src_e"], gathered["pth_e"],
+                 gathered["dst_e"]], axis=-1).astype(compute_dtype)
+            if dims.dropout_keep_rate < 1.0:
+                keep = jax.random.bernoulli(
+                    ctx["drop_rng"], dims.dropout_keep_rate,
+                    contexts.shape)
+                contexts = jnp.where(keep,
+                                     contexts / dims.dropout_keep_rate,
+                                     0.0)
+        with jax.named_scope("c2v/pool"):
+            code, _ = attention_pool(contexts, dense["transform"],
+                                     dense["attention"], mask)
+        with jax.named_scope("c2v/loss"):
+            return _gathered_loss(code, dense, gathered)
+
+    def _gathered_loss(code, dense, gathered):
         if use_sampled_softmax:
             true_w = gathered["true_w"].astype(code.dtype)
             samp_w = gathered["samp_w"].astype(code.dtype)
@@ -223,9 +235,10 @@ def make_sparse_train_step(dims: ModelDims, *, learning_rate: float,
         count = opt_state["count"] + 1
 
         # ---- dense params: ordinary Adam ----
-        updates, dense_state = dense_opt.update(
-            g_dense, opt_state["dense"], dense)
-        dense = optax.apply_updates(dense, updates)
+        with jax.named_scope("c2v/dense_apply"):
+            updates, dense_state = dense_opt.update(
+                g_dense, opt_state["dense"], dense)
+        dense = apply_updates(dense, updates)
 
         # ---- tables: dedup + segment-sum + live-rows-only update
         # (training/sparse_update.py — no dense [V, E] carrier) ----
@@ -236,21 +249,22 @@ def make_sparse_train_step(dims: ModelDims, *, learning_rate: float,
             the single-device path concatenates them — mesh_sparse_apply
             all-gathers + concatenates in this order, which is what
             makes mesh-vs-single-device parity bit-exact."""
-            table, state = params[key], opt_state["rows"][key]
-            kw = dict(count=count, lr=learning_rate, b1=b1, b2=b2,
-                      eps=eps, fused=sparse_update_fused,
-                      block_rows=sparse_block_rows)
-            if mesh is not None:
-                return mesh_sparse_apply(mesh, table, state, parts,
-                                         rng=qrngs.get(key), **kw)
-            ids = jnp.concatenate([i.reshape(-1) for i, _g, _s in parts])
-            grads = jnp.concatenate(
-                [g.reshape(i.reshape(-1).shape[0], -1)
-                 for i, g, _s in parts])
-            if is_quantized(table):
-                return sparse_requant_adam(table, state, ids, grads,
-                                           qrngs[key], **kw)
-            return sparse_row_adam(table, state, ids, grads, **kw)
+            with jax.named_scope("c2v/table_apply"):
+                table, state = params[key], opt_state["rows"][key]
+                kw = dict(count=count, lr=learning_rate, b1=b1, b2=b2,
+                          eps=eps, fused=sparse_update_fused,
+                          block_rows=sparse_block_rows)
+                if mesh is not None:
+                    return mesh_sparse_apply(mesh, table, state, parts,
+                                             rng=qrngs.get(key), **kw)
+                ids = jnp.concatenate([i.reshape(-1) for i, _g, _s in parts])
+                grads = jnp.concatenate(
+                    [g.reshape(i.reshape(-1).shape[0], -1)
+                     for i, g, _s in parts])
+                if is_quantized(table):
+                    return sparse_requant_adam(table, state, ids, grads,
+                                               qrngs[key], **kw)
+                return sparse_row_adam(table, state, ids, grads, **kw)
 
         new_tok, tok_state = apply_rows(
             "token_emb", [(src, g_rows["src_e"].reshape(-1, E), True),

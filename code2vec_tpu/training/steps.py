@@ -26,6 +26,7 @@ import optax
 from code2vec_tpu.models.encoder import (ModelDims, full_logits,
                                          get_encode_fn)
 from code2vec_tpu.ops.sampled_softmax import sampled_softmax_loss
+from code2vec_tpu.training.optimizers import apply_updates
 
 
 def _weighted_mean(values: jax.Array, weights: jax.Array) -> jax.Array:
@@ -59,10 +60,12 @@ def make_train_loss_fn(dims: ModelDims, *,
                 num_sampled, example_weights=weights,
                 vocab_size=dims.target_vocab_size)
         else:
-            logits = full_logits(params, code, dims.target_vocab_size)
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits, labels)
-            loss = _weighted_mean(ce, weights)
+            with jax.named_scope("c2v/loss"):
+                logits = full_logits(params, code,
+                                     dims.target_vocab_size)
+                ce = optax.softmax_cross_entropy_with_integer_labels(
+                    logits, labels)
+                loss = _weighted_mean(ce, weights)
         return loss
 
     return loss_fn
@@ -134,7 +137,7 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
             batch = augment_fn(batch, aug_rng)
         loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params = apply_updates(params, updates)
         return params, opt_state, loss
 
     return step
@@ -199,11 +202,10 @@ def _make_quantized_train_step(optimizer, loss_fn, augment_fn,
                                               flat_params)
         new_params = {}
         for k, qrng in zip(qkeys, qrngs):
-            new_params[k] = requantize(params[k], updates[k], qrng,
-                                       fused=requant_fused)
-        for k in params:
-            if k not in new_params:
-                new_params[k] = optax.apply_updates(params[k], updates[k])
+            with jax.named_scope("c2v/table_apply"):
+                new_params[k] = requantize(params[k], updates[k], qrng,
+                                           fused=requant_fused)
+        new_params.update(apply_updates(params, updates, skip=qkeys))
         return new_params, opt_state, loss
 
     return step

@@ -15,6 +15,7 @@ import optax
 
 from code2vec_tpu.models.encoder import ModelDims
 from code2vec_tpu.models.varmisuse import vm_loss, vm_scores
+from code2vec_tpu.training.optimizers import apply_updates
 
 
 _VM_TABLE_KEYS = ("token_emb", "path_emb")
@@ -91,8 +92,7 @@ def make_vm_train_step(dims: ModelDims,
             g_dense = {k: grads[k] for k in dense}
             updates, dense_state = optimizer.update(
                 g_dense, opt_state["dense"], dense)
-            new_params = dict(params,
-                              **optax.apply_updates(dense, updates))
+            new_params = dict(params, **apply_updates(dense, updates))
             # table ids gathered by vm_scores: src/dst/candidate token
             # rows, path rows
             _labels, src, pth, dst, _mask, cand_ids, _cm, _w = batch
@@ -103,10 +103,11 @@ def make_vm_train_step(dims: ModelDims,
                 "path_emb": pth.reshape(-1)}
             new_rows = {}
             for k in _VM_TABLE_KEYS:
-                new_params[k], new_rows[k] = rows_from_dense(
-                    params[k], opt_state["rows"][k], grads[k],
-                    table_ids[k], count=count, lr=learning_rate,
-                    fused=fused, block_rows=sparse_block_rows)
+                with jax.named_scope("c2v/table_apply"):
+                    new_params[k], new_rows[k] = rows_from_dense(
+                        params[k], opt_state["rows"][k], grads[k],
+                        table_ids[k], count=count, lr=learning_rate,
+                        fused=fused, block_rows=sparse_block_rows)
             return new_params, {"dense": dense_state,
                                 "rows": new_rows,
                                 "count": count}, loss
@@ -117,7 +118,7 @@ def make_vm_train_step(dims: ModelDims,
     def step(params, opt_state, batch, rng):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params = apply_updates(params, updates)
         return params, opt_state, loss
 
     return step
