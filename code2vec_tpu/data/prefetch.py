@@ -29,7 +29,11 @@ profiler's trace on the device's clock):
 
   infeed/read      producer   `next()` on the reader's iterator
                               (`seq`, `rows`, `epoch_first`: the first
-                              batch of a pass holds the permutation)
+                              batch of a pass holds the permutation;
+                              `pad_slots`: the slots of the batch that
+                              hold no context, counted from its mask,
+                              which is how often `take_rows` spreads
+                              a PAD read)
   infeed/transfer  producer   `put_fn(batch)`: host arrays, then the
                               device_put (`seq`, `bytes`)
   infeed/blocked   producer   the bounded put into the queue: the
@@ -52,6 +56,8 @@ import queue
 import threading
 from typing import Callable, Iterable, Iterator, Optional, Tuple
 
+import numpy as np
+
 from code2vec_tpu.obs.trace import memory_tracer
 
 _SENTINEL = object()
@@ -62,16 +68,18 @@ _BATCH_SEQ = itertools.count()
 
 
 class BatchRecord:
-    """One produced batch: its sequence number, rows and bytes, and on
-    the recorder's clock where its read started and its transfer
-    ended. Rides the queue item the producer builds; `on_produced`
-    (the `--trace` hook) gets it after the transfer."""
+    """One produced batch: its sequence number, rows, PAD slots and
+    bytes, and on the recorder's clock where its read started and its
+    transfer ended. Rides the queue item the producer builds;
+    `on_produced` (the `--trace` hook) gets it after the transfer."""
 
-    __slots__ = ("seq", "rows", "bytes", "read_start", "transfer_end")
+    __slots__ = ("seq", "rows", "pad_slots", "bytes", "read_start",
+                 "transfer_end")
 
-    def __init__(self, seq: int, rows, read_start: float):
+    def __init__(self, seq: int, rows, pad_slots, read_start: float):
         self.seq = seq
         self.rows = rows
+        self.pad_slots = pad_slots
         self.bytes = 0
         self.read_start = read_start
         self.transfer_end = None
@@ -102,8 +110,12 @@ def _read_batches(batches: Iterable, recorder
                 return
             seq = next(_BATCH_SEQ)
             rows = getattr(b, "num_valid_examples", None)
-            span.attrs.update(seq=seq, rows=rows, epoch_first=first)
-        yield b, BatchRecord(seq, rows, span.interval[0])
+            mask = getattr(b, "context_valid_mask", None)
+            pad_slots = (None if mask is None
+                         else int(np.count_nonzero(mask == 0)))
+            span.attrs.update(seq=seq, rows=rows, pad_slots=pad_slots,
+                              epoch_first=first)
+        yield b, BatchRecord(seq, rows, pad_slots, span.interval[0])
         first = False
 
 
@@ -285,7 +297,6 @@ class ChunkedDevicePrefetcher(_ThreadedInfeed):
         self._transfer = transfer
 
     def _produce(self, put: Callable) -> None:
-        import numpy as np
         transfer = self._transfer
         if transfer is None:
             import jax.numpy as jnp

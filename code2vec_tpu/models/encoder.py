@@ -29,6 +29,9 @@ from code2vec_tpu.ops.attention import attention_pool
 
 Params = Dict[str, jax.Array]
 
+# `vocab.vocabularies.Vocab` reserves index 0 for PAD in every table
+PAD_ID = 0
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelDims:
@@ -139,7 +142,31 @@ def take_rows(params: Params, name: str, ids: jax.Array) -> jax.Array:
         # significant bits; f32 would double the activation traffic)
         return (jnp.take(t["q"], ids, axis=0).astype(jnp.float32)
                 * jnp.take(t["s"], ids, axis=0)).astype(jnp.bfloat16)
-    return jnp.take(t, ids, axis=0)
+    # A PAD slot never reads row 0 through the gather: on the chip a
+    # million reads of one address cost more than as many reads of
+    # distinct rows (PERF.md finding 5). Each PAD slot reads a row of
+    # its own and the select puts the PAD row's value back: bit for
+    # bit `jnp.take(t, ids, axis=0)`, whatever the caller does with
+    # PAD slots afterwards. The backward is autodiff's, row 0 getting
+    # the sum of the PAD slots' cotangents (written out with
+    # `.at[0].add` it is 1 ms a step faster on one chip and 4 ms slower
+    # on four, where the partitioner then all-reduces the token
+    # table's two gradients apart; PERF.md, PR 27).
+    is_pad, spread = _spread_pad(t.shape[0], ids)
+    return jnp.where(is_pad[..., None], t[PAD_ID],
+                     jnp.take(t, spread, axis=0))
+
+
+def _spread_pad(vocab: int, ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """`ids == PAD_ID`, and the ids with each PAD slot naming the row
+    its own position hashes to (in bounds; Knuth's multiplier, modulo
+    2**32 and then the rows): rows scattered over the table's pages
+    read a little faster than the consecutive rows of `slot % vocab`
+    (PERF.md, PR 27)."""
+    is_pad = ids == PAD_ID
+    slot = jnp.arange(ids.size, dtype=jnp.uint32).reshape(ids.shape)
+    own = (slot * jnp.uint32(2654435761)) % jnp.uint32(vocab)
+    return is_pad, jnp.where(is_pad, own.astype(ids.dtype), ids)
 
 
 def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,

@@ -28,7 +28,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from code2vec_tpu.models.encoder import ModelDims
+from code2vec_tpu.models.encoder import ModelDims, take_rows
 
 
 def init_xf_params(rng: jax.Array, dims: ModelDims) -> Dict:
@@ -130,9 +130,9 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
     # phase scopes as in encoder.encode, `c2v/xf_layer_<i>` inside
     # `c2v/encode`
     with jax.named_scope("c2v/embed_gather"):
-        rows = [jnp.take(params["token_emb"], source_ids, axis=0),
-                jnp.take(params["path_emb"], path_ids, axis=0),
-                jnp.take(params["token_emb"], target_ids, axis=0)]
+        rows = [take_rows(params, "token_emb", source_ids),
+                take_rows(params, "path_emb", path_ids),
+                take_rows(params, "token_emb", target_ids)]
 
     with jax.named_scope("c2v/encode"):
         emb = jnp.concatenate(rows, axis=-1).astype(

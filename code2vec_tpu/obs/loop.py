@@ -65,7 +65,8 @@ def infeed_produce_instrument(tracer: Tracer,
     def on_produced(record) -> None:
         channel.send(tracer.record_span(
             "infeed/produce", record.read_start, record.transfer_end,
-            seq=record.seq, rows=record.rows, bytes=record.bytes))
+            seq=record.seq, rows=record.rows,
+            pad_slots=record.pad_slots, bytes=record.bytes))
     return on_produced
 
 

@@ -303,6 +303,50 @@ def test_sync_infeed_records_on_the_callers_thread_and_never_blocks():
     assert rec.records("infeed/pop_wait") == []
 
 
+# ---- how often the gather spreads a PAD read -----------------------------
+
+@pytest.mark.parametrize("reader_kind", ["binary", "text"])
+def test_infeed_read_counts_the_batchs_pad_slots(tmp_path, reader_kind):
+    """`pad_slots` on `infeed/read` (and on the `--trace` span built
+    from the record) is the count of the batch's slots whose path id is
+    PAD, taken from the mask the reader made."""
+    from code2vec_tpu.data.reader import open_reader
+    from code2vec_tpu.obs import SpanChannel, infeed_produce_instrument
+    from tests.helpers import build_tiny_dataset, load_tiny_vocabs
+
+    prefix = build_tiny_dataset(str(tmp_path), n_train=40, n_val=4,
+                                n_test=4, max_contexts=16,
+                                binarize=reader_kind == "binary")
+    vocabs = load_tiny_vocabs(prefix)
+    reader = open_reader(prefix + ".train.c2v", vocabs, 16, 16)
+    assert type(reader).__name__ == {"binary": "BinaryShardReader",
+                                     "text": "C2VTextReader"}[reader_kind]
+    tele = _Events()
+    infeed = _SyncInfeed(reader, lambda b: (b.path_indices,))
+    rec = infeed._recorder = MemoryTracer()
+    infeed._on_produced = infeed_produce_instrument(Tracer.create(tele),
+                                                    SpanChannel())
+    batches = [host for _dev, host in infeed]
+    assert len(batches) == 3            # the last one padded from 8 rows
+    pad = vocabs.path_vocab.pad_index
+    want = [int((b.path_indices == pad).sum()) for b in batches]
+    assert want == [int((b.context_valid_mask == 0).sum()) for b in batches]
+    assert 0 < want[0] < 16 * 16 and want[2] >= 8 * 16
+    named = [r for r in rec.records("infeed/read") if "seq" in r["attrs"]]
+    assert [r["attrs"]["pad_slots"] for r in named] == want
+    assert [r["attrs"]["rows"] for r in named] == [16, 16, 8]
+    assert [s["attrs"]["pad_slots"] for s in tele.spans] == want
+
+
+def test_a_batch_with_no_mask_has_no_pad_count():
+    clock = FakeClock()
+    infeed = _SyncInfeed(FakeReader(clock, 2), fake_put_fn(clock))
+    rec = infeed._recorder = MemoryTracer(clock=clock)
+    list(infeed)
+    named = [r for r in rec.records("infeed/read") if "seq" in r["attrs"]]
+    assert [r["attrs"]["pad_slots"] for r in named] == [None, None]
+
+
 # ---- the production record, on its threads ------------------------------
 
 @pytest.mark.parametrize("kind", ["per_batch", "chunked"])

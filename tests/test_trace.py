@@ -310,6 +310,25 @@ def test_step_breakdown_tool(traced_train_run):
     assert all(r["save_write_ms"] is not None for r in srows)
 
 
+def test_trace_report_prints_the_pad_slots(traced_train_run):
+    from tools.trace_report import pad_slot_summary, render
+    produced = [s for s in traced_train_run
+                if s["name"] == "infeed/produce"]
+    pad = pad_slot_summary(traced_train_run)
+    assert pad["batches"] == len(produced) > 0
+    assert pad["pad_slots"] == sum(s["attrs"]["pad_slots"]
+                                   for s in produced) > 0
+    text = render([({"config": {"MAX_CONTEXTS": 16}}, traced_train_run)])
+    share = 100.0 * pad["pad_slots"] / (pad["rows"] * 16)
+    assert (f"PAD slots: {pad['pad_slots']:,} in {pad['rows']:,} rows of "
+            f"{pad['batches']} batches ({share:.2f}% of rows x 16 "
+            "contexts)") in text
+    assert "PAD slots" not in render([({}, [
+        dict(s, attrs={k: v for k, v in s["attrs"].items()
+                       if k != "pad_slots"})
+        for s in produced])])
+
+
 def test_breakdown_primary_and_linked_requests_agree():
     """Regression: the flush's encode/device children share the
     PRIMARY request's trace id — they must be attributed through the

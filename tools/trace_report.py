@@ -29,7 +29,9 @@ emits) and produces:
     trace id for the flush's primary request, by link for coalesced
     ones) plus aggregate p50/p95/p99 per phase.
   - per-step table: infeed_wait / step / save_blocked (+ the writer's
-    save_write wall) from the `train/step_cycle` traces.
+    save_write wall) from the `train/step_cycle` traces, and the
+    batches' PAD slots (`pad_slots` of `infeed/produce`: how often the
+    embedding gather spreads a PAD read).
 
 Pure stdlib; reads only manifest + events files, so it works on a
 laptop over a run dir scp'd from a pod (same contract as
@@ -310,6 +312,21 @@ def step_breakdowns(spans: Sequence[Dict[str, Any]]
     return rows
 
 
+def pad_slot_summary(spans: Sequence[Dict[str, Any]]
+                     ) -> Optional[Dict[str, int]]:
+    """PAD slots, rows and batches over the run's `infeed/produce`
+    spans that carry a `pad_slots` count (how often the embedding
+    gather spreads a PAD read); None when none does."""
+    counted = [a for a in ((s.get("attrs") or {}) for s in spans
+                           if s["name"] == "infeed/produce")
+               if a.get("pad_slots") is not None and a.get("rows")]
+    if not counted:
+        return None
+    return {"batches": len(counted),
+            "rows": sum(a["rows"] for a in counted),
+            "pad_slots": sum(a["pad_slots"] for a in counted)}
+
+
 def save_breakdowns(spans: Sequence[Dict[str, Any]]
                     ) -> List[Dict[str, Any]]:
     rows = []
@@ -400,6 +417,17 @@ def render(loaded, limit: int = 10) -> str:
                     lines.append(f"| {key} | {len(vals)} | "
                                  + " | ".join(_fmt(_pct(vals, p))
                                               for p in PCTS) + " |")
+        pad = pad_slot_summary(spans)
+        if pad:
+            contexts = (manifest.get("config") or {}).get("MAX_CONTEXTS")
+            share = ""
+            if contexts:
+                percent = 100.0 * pad["pad_slots"] / (pad["rows"] * contexts)
+                share = f" ({percent:.2f}% of rows x {contexts} contexts)"
+            lines.append("")
+            lines.append(f"PAD slots: {pad['pad_slots']:,} in "
+                         f"{pad['rows']:,} rows of {pad['batches']} "
+                         f"batches{share}")
         save_rows = save_breakdowns(spans)
         if save_rows:
             lines.append("")
