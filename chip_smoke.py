@@ -2,7 +2,7 @@
 """Chip smoke: drive training and serving once on the TPU, at java-large
 width, through the entry points a user calls.
 
-    python3 chip_smoke.py [--config bag|transformer|int8|sparse]
+    python3 chip_smoke.py [--config bag|transformer|int8|sparse|lfm2_moe]
 
 One process, phases in order, the first failure ends the run with a
 non-zero exit and no result line:
@@ -50,12 +50,25 @@ TARGET_CLASSES = 512        # targets the generated methods actually use
 REQUEST_SIZES = (1, 2, 3, 5, 8, 13, 21, 34, 64, 1)
 _BACKEND = "tpu"
 
+# lfm2_moe at LFM2-24B-A2B's published widths and a small depth (one
+# dense convolution layer, one attention layer with 8 of 64 experts):
+# 175M float32 parameters under Adam, so 128 methods a batch
+LFM_BLOCK = {"layer_types": ["conv", "full_attention"],
+             "num_dense_layers": 1, "hidden_size": 2048,
+             "intermediate_size": 11776, "moe_intermediate_size": 1536,
+             "num_attention_heads": 32, "num_key_value_heads": 8,
+             "num_experts": 8, "num_routed_experts": 64, "first_expert": 0,
+             "num_experts_per_tok": 4, "conv_L_cache": 3, "norm_eps": 1e-5,
+             "rope_parameters": {"rope_theta": 1000000}}
+CONFIG_BATCH = {"lfm2_moe": 128}
+
 CONFIG_FLAGS = {
     "bag": [],
     "transformer": ["--encoder", "transformer"],
     "int8": ["--tables_dtype", "int8"],
     "sparse": ["--sparse_embeddings", "--embedding_optimizer", "adam",
                "--lr_schedule", "constant"],
+    "lfm2_moe": ["--encoder", "lfm2_moe"],
 }
 
 
@@ -162,7 +175,7 @@ def _method_lines(n: int, seed: int):
     return lines
 
 
-def data_phase(out_dir: str, seed: int) -> dict:
+def data_phase(out_dir: str, seed: int, batch: int) -> dict:
     prefix = os.path.join(out_dir, "data", "smoke")
     os.makedirs(os.path.dirname(prefix))
     # every word distinct in count, so the frequency cut keeps all of
@@ -172,8 +185,8 @@ def data_phase(out_dir: str, seed: int) -> dict:
             pickle.dump({f"{stem}{i}": n - i for i in range(n)}, f)
         pickle.dump({f"get|m{i}": TARGETS - i for i in range(TARGETS)},
                     f)
-        pickle.dump(TRAIN_STEPS * BATCH, f)
-    splits = {"train": (TRAIN_STEPS * BATCH, seed),
+        pickle.dump(TRAIN_STEPS * batch, f)
+    splits = {"train": (TRAIN_STEPS * batch, seed),
               "val": (VAL_METHODS, seed + 1)}
     val_lines = None
     for split, (n, s) in splits.items():
@@ -203,7 +216,8 @@ def _placement(x) -> dict:
             "shard_shape": list(shards[0].data.shape)}
 
 
-def train_phase(out_dir: str, prefix: str, config: str) -> dict:
+def train_phase(out_dir: str, prefix: str, config: str,
+                batch: int) -> dict:
     import jax
     import numpy as np
 
@@ -214,10 +228,15 @@ def train_phase(out_dir: str, prefix: str, config: str) -> dict:
     argv = ["--data", prefix, "--test", prefix + ".val.c2v",
             "--save", ckpt_dir, "--sampled_softmax",
             "--num_sampled", str(NUM_SAMPLED),
-            "--batch_size", str(BATCH),
+            "--batch_size", str(batch),
             "--max_contexts", str(MAX_CONTEXTS), "--epochs", "1",
             "--backend", _BACKEND, "--telemetry_dir", tele_dir,
             *CONFIG_FLAGS[config]]
+    if config == "lfm2_moe":
+        block = os.path.join(out_dir, "lfm_block.json")
+        with open(block, "w") as f:
+            json.dump(LFM_BLOCK, f)
+        argv += ["--lfm_config", block]
     print(f"chip_smoke: code2vec.main({' '.join(argv)})", flush=True)
     rc = code2vec.main(argv)
     check(rc == 0, "train", f"code2vec.main returned {rc}")
@@ -250,10 +269,10 @@ def train_phase(out_dir: str, prefix: str, config: str) -> dict:
     from code2vec_tpu.data.reader import BatchTensors
     from code2vec_tpu.models.jax_model import Code2VecModel
     model = Code2VecModel(Config.load_from_args(argv))
-    ids = np.zeros((BATCH, model.dims.max_contexts), np.int32)
+    ids = np.zeros((batch, model.dims.max_contexts), np.int32)
     dev_batch = model._device_batch(BatchTensors(
-        np.zeros((BATCH,), np.int32), ids, ids, ids,
-        ids.astype(np.float32), BATCH))
+        np.zeros((batch,), np.int32), ids, ids, ids,
+        ids.astype(np.float32), batch))
     hlo = model._train_step.lower(
         model.params, model.opt_state, dev_batch,
         jax.random.PRNGKey(0)).compile().as_text()
@@ -283,7 +302,7 @@ def train_phase(out_dir: str, prefix: str, config: str) -> dict:
 
 # ---- serve --------------------------------------------------------------
 
-def serve_phase(ckpt_dir: str, val_lines) -> dict:
+def serve_phase(ckpt_dir: str, val_lines, config: str) -> dict:
     from code2vec_tpu.config import Config
     from code2vec_tpu.models.jax_model import Code2VecModel
     from code2vec_tpu.serving.interactive_predict import \
@@ -315,8 +334,14 @@ def serve_phase(ckpt_dir: str, val_lines) -> dict:
                               for p in res.predictions), "serve",
                       f"no top-k names for {res.original_name}")
             answered += 1
-        # the batcher must hand back the rows the model itself gives
-        lines = val_lines[:REQUEST_SIZES[3]]
+        # the batcher must hand back the rows the model itself gives.
+        # Methods asked before come out of the cache, answered in batches
+        # of other sizes; a 2048-wide encoder's matmuls round differently
+        # from one batch shape to another and swap near-tied names, so
+        # lfm2_moe is asked methods not asked before: one batch of one
+        # size on both sides
+        first = at if config == "lfm2_moe" else 0
+        lines = val_lines[first:first + REQUEST_SIZES[3]]
         direct = model.predict(lines)
         served = server.predict_lines(lines)
         check([[p["name"] for p in r.predictions] for r in direct]
@@ -546,11 +571,12 @@ def main(argv=None) -> int:
 
     sync = timed("sync", sync_phase)
     shutil.rmtree(args.out, ignore_errors=True)
-    data = timed("data", data_phase, args.out, args.seed)
+    batch = CONFIG_BATCH.get(args.config, BATCH)
+    data = timed("data", data_phase, args.out, args.seed, batch)
     train = timed("train", train_phase, args.out, data["prefix"],
-                  args.config)
+                  args.config, batch)
     serve = timed("serve", serve_phase, train.pop("ckpt_dir"),
-                  data["val_lines"])
+                  data["val_lines"], args.config)
     kernels = timed("kernels", kernels_phase)
     shutil.rmtree(args.out, ignore_errors=True)
 

@@ -235,10 +235,18 @@ class Config:
     # death/refill still applies either way).
     SERVE_AUTOSCALE: bool = False
 
-    # ---- encoder architecture: "bag" (reference parity) or
+    # ---- encoder architecture: "bag" (reference parity),
     # "transformer" (set transformer over the contexts,
-    # models/transformer_encoder.py; BASELINE.json configs[4]). ----
+    # models/transformer_encoder.py; BASELINE.json configs[4]) or
+    # "lfm2_moe" (the LFM2-MoE decoder block over the contexts in
+    # reader order, models/lfm2_moe_encoder.py). ----
     ENCODER_TYPE: str = "bag"
+    # lfm2_moe: the block's sizes, a JSON file under the keys of the
+    # model's own config.json (layer_types, hidden_size, num_experts =
+    # the experts held HERE, num_routed_experts, first_expert, ...;
+    # models/encoder.Lfm2Dims). benchmark/configs/java-large-lfm2moe.json
+    # is one chip's share of LFM2-24B-A2B.
+    LFM_CONFIG: Optional[str] = None
     XF_LAYERS: int = 2
     # 3 heads -> head_dim = 384/3 = 128 = one MXU lane width: measured
     # 9% faster through the fused attention kernels at IDENTICAL
@@ -442,6 +450,15 @@ class Config:
         return bool(self.save_path)
 
     @property
+    def eval_batch_size(self) -> int:
+        """Methods per evaluation batch. The lfm2_moe block's batch is
+        what memory allows (a 2048-wide layer over every slot), so it
+        evaluates at no more than the training batch (--batch_size)."""
+        if self.ENCODER_TYPE == "lfm2_moe":
+            return min(self.TEST_BATCH_SIZE, self.TRAIN_BATCH_SIZE)
+        return self.TEST_BATCH_SIZE
+
+    @property
     def train_data_path_prefix(self) -> Optional[str]:
         return self.train_data_path
 
@@ -528,7 +545,12 @@ class Config:
                        action="store_true")
         p.add_argument("--num_sampled", dest="num_sampled", type=int, default=None)
         p.add_argument("--encoder", dest="encoder", default=None,
-                       choices=["bag", "transformer"])
+                       choices=["bag", "transformer", "lfm2_moe"])
+        p.add_argument("--lfm_config", dest="lfm_config", default=None,
+                       help="--encoder lfm2_moe: JSON file with the "
+                            "block's sizes under the model's config.json "
+                            "keys (num_experts = experts held here, "
+                            "num_routed_experts, first_expert)")
         p.add_argument("--xf_layers", dest="xf_layers", type=int,
                        default=None)
         p.add_argument("--xf_heads", dest="xf_heads", type=int,
@@ -812,6 +834,8 @@ class Config:
             cfg.NUM_SAMPLED_CLASSES = ns.num_sampled
         if ns.encoder is not None:
             cfg.ENCODER_TYPE = ns.encoder
+        if ns.lfm_config is not None:
+            cfg.LFM_CONFIG = ns.lfm_config
         if ns.xf_layers is not None:
             cfg.XF_LAYERS = ns.xf_layers
         if ns.xf_heads is not None:
@@ -989,7 +1013,8 @@ class Config:
             if self.ENCODER_TYPE != "bag":
                 raise ValueError(
                     "--tables_dtype int8 supports the bag encoder only "
-                    "(transformer_encoder gathers the tables directly).")
+                    "(the transformer and lfm2_moe encoders gather the "
+                    "tables directly).")
             if self.HEAD != "code2vec":
                 raise ValueError(
                     "--tables_dtype int8 supports the code2vec head "
@@ -1136,7 +1161,8 @@ class Config:
             # them — a train/eval architecture mismatch.
             raise ValueError(
                 "SPARSE_EMBEDDING_UPDATES supports the bag encoder only "
-                "(sparse_steps.py trains no transformer params).")
+                "(sparse_steps.py trains no transformer or lfm2_moe "
+                "params).")
         if not 0.0 <= self.ADV_RENAME_PROB <= 1.0:
             raise ValueError("--adv_rename_prob must be in [0, 1].")
         if self.ADV_RENAME_PROB > 0 and self.SPARSE_EMBEDDING_UPDATES:
@@ -1163,7 +1189,18 @@ class Config:
             # architecture.
             raise ValueError(
                 "--head varmisuse supports the bag encoder only "
-                "(no --encoder transformer / --mesh_context > 1).")
+                "(no --encoder transformer or lfm2_moe / "
+                "--mesh_context > 1).")
+        if self.ENCODER_TYPE == "lfm2_moe":
+            if self.RING_ATTENTION or self.MESH_CONTEXT_AXIS > 1:
+                raise ValueError(
+                    "--encoder lfm2_moe has no ring attention and no "
+                    "context-parallel layout (its causal convolution and "
+                    "mask run over whole sequences).")
+            if not self.LFM_CONFIG and not self.is_loading:
+                raise ValueError(
+                    "--encoder lfm2_moe needs --lfm_config <json> (the "
+                    "block's sizes; a checkpoint carries its own).")
 
     def get_logger(self) -> logging.Logger:
         if self._logger is None:

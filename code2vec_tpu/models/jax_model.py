@@ -150,6 +150,7 @@ class Code2VecModel(Code2VecModelBase):
                 xf_heads=cfg.XF_HEADS,
                 xf_remat=cfg.XF_REMAT,
                 ring_attention=cfg.RING_ATTENTION,
+                lfm=self._lfm_dims(),
             )
         if self.dims.tables_dtype == "int8" and self.mesh is not None:
             # data-parallel meshes replicate the quantized tables and
@@ -272,6 +273,18 @@ class Code2VecModel(Code2VecModelBase):
         self._predict_step = make_predict_step(
             self.dims, top_k=top_k, compute_dtype=self.compute_dtype,
             use_pallas=self.use_pallas, mesh=self.mesh)
+
+    def _lfm_dims(self):
+        """The LFM2-MoE block's sizes from `--lfm_config` (None for the
+        other encoders)."""
+        cfg = self.config
+        if cfg.ENCODER_TYPE != "lfm2_moe":
+            return None
+        import json
+
+        from code2vec_tpu.models.encoder import Lfm2Dims
+        with open(cfg.LFM_CONFIG) as f:
+            return Lfm2Dims.from_config(json.load(f))
 
     # ---- vocabs: dataset dict when training, checkpoint sidecar when
     # loading (SURVEY.md §3.2 "Model checkpoint") ----
@@ -419,6 +432,10 @@ class Code2VecModel(Code2VecModelBase):
             heartbeat=loop_hb if watchdog.enabled else None,
             alerts=alerts if alerts.enabled else None)
         self._trace_recorder = recorder
+        # routed experts: `moe/route` records go to the --trace log too
+        route_recorder = getattr(self._train_step, "route_recorder", None)
+        if route_recorder is not None:
+            route_recorder.tracer = tracer
         watchdog.start()
         plane.start()
         # tools/obs_top.py derives pc/s = examples-rate x this gauge
@@ -657,6 +674,10 @@ class Code2VecModel(Code2VecModelBase):
                 # submit/wait/close)
                 self._ckpt_writer.drain_quiet()
         profiler.finish(self.params)
+        if route_recorder is not None:
+            # the loop is over and its state synced: the last steps'
+            # counts are read with no wait of their own
+            route_recorder.flush()
         telemetry.close()
         scalars.close()
         self.log("training done")
@@ -690,7 +711,7 @@ class Code2VecModel(Code2VecModelBase):
         # hosts at the end — no redundant parsing, eval scales with H.
         reader = open_reader(
             cfg.test_data_path, self.vocabs, cfg.MAX_CONTEXTS,
-            cfg.TEST_BATCH_SIZE, shuffle=False, keep_strings=True,
+            cfg.eval_batch_size, shuffle=False, keep_strings=True,
             host_shard=jax.process_index() if multi else 0,
             num_host_shards=jax.process_count() if multi else 1)
         acc = MetricAccumulator(
@@ -1054,7 +1075,7 @@ class Code2VecModel(Code2VecModelBase):
         example, in input order (reference writes `<test>.vectors`)."""
         cfg = self.config
         reader = open_reader(test_path, self.vocabs, cfg.MAX_CONTEXTS,
-                             cfg.TEST_BATCH_SIZE, shuffle=False,
+                             cfg.eval_batch_size, shuffle=False,
                              keep_strings=True)
         encode_step = make_encode_step(self.dims,
                                        compute_dtype=self.compute_dtype,

@@ -1,0 +1,287 @@
+"""The LFM2-MoE decoder block as the path encoder (`--encoder lfm2_moe`).
+
+LiquidAI's LFM2-24B-A2B (`model_type` `lfm2_moe`,
+huggingface.co/LiquidAI/LFM2-24B-A2B `config.json`): a stack whose
+layers are of two kinds, gated short convolutions and grouped-query
+attention, with two kinds of feed-forward, one dense SwiGLU and then
+routed experts. Here the stack runs over a method's path-contexts in
+reader order: position = slot index, the reader fills valid contexts
+from the left. `x` is [B, C, H], `m` the context mask.
+
+  input    c = concat(tok[src], path[pth], tok[dst])      3E, dropout
+           x = (c W_in) m                                 3E -> H; masked
+                                                          slots enter as zeros
+  layer    x = x + Op(RMSNorm(x)) ; x = x + FF(RMSNorm(x))   eps norm_eps
+  conv     [b, g, u] = split3(h W_in3)                    H -> 3H, no bias
+           v = b * u * m
+           w_t = sum_{j<L} K[:, j] v_{t-(L-1)+j}          depthwise, causal,
+                                                          zeros before slot 0
+           Op = (g * w) W_out                             H -> H
+  full_attention
+           q = h W_q, k = h W_k, v = h W_v                heads of H / n_heads;
+                                                          n_kv key/value heads
+           q, k = RMSNorm over each head (learned scale), then rotary
+           (theta, over the whole head, rotate-half); scores / sqrt(head),
+           causal mask and padding mask, softmax in float32; kv head j
+           serves query heads j n/n_kv .. ; Op = concat(heads) W_o
+  FF, layers before num_dense_layers
+           (silu(h W1) * (h W3)) W2                       width intermediate_size
+  FF, the rest (ops/moe.py)
+           s = sigmoid(h W_r) ; chosen = top K of (s + bias)
+           p_e = s_e / (sum of the K chosen s + 1e-6)
+           FF = sum over chosen e held here of
+                p_e (silu(h W1_e) * (h W3_e)) W2_e        width moe_intermediate_size
+           no shared expert, no auxiliary loss; a masked slot is routed
+           nowhere
+  output   RMSNorm ; the product's learned-query pool over valid slots
+           at width H ; code = pooled W_out2               H -> 3E
+
+Departures from the model, all of them the product's: the vocabulary
+and the head are the three code2vec tables and the sampled softmax over
+the name table; the two projections W_in and W_out2 stand where the
+model's own embedding and head would; a sequence is a bag of at most
+MAX_CONTEXTS contexts in reader order; the loss is the product's. The
+q/k RMSNorm is LFM2's (its config's keys do not state it). The
+selection bias is a seeded buffer, small and non-zero, held fixed: it
+selects only, so no gradient reaches it, and its update rule is not in
+the config.
+
+Expert parallelism: `Lfm2Dims.num_experts` experts from `first_expert`
+are held here, of `routed` the router scores. On one chip the layer
+runs without its exchange, and what absent experts would add is left
+out. Under a mesh every device routes its own rows of the batch
+(`shard_map`), the weights replicated. Each layer is rematerialised in
+the backward pass: at H = 2048 a layer's activations are the memory.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from code2vec_tpu.models.encoder import Lfm2Dims, ModelDims, embed_contexts
+from code2vec_tpu.models.transformer_encoder import (_rms_norm,
+                                                     learned_query_pool,
+                                                     padding_log_mask)
+from code2vec_tpu.ops.moe import held_experts_ffn, route
+
+# small beside the gaps between a token's top scores (about 0.016
+# between the fourth and the fifth of 64): it turns near-ties and leaves
+# the load on the experts even, as the trained buffer's job is
+BIAS_SCALE = 0.005
+
+
+def _is_moe(cfg: Lfm2Dims, i: int) -> bool:
+    return i >= cfg.num_dense_layers
+
+
+def init_lfm_params(rng: jax.Array, dims: ModelDims) -> Dict:
+    """The "lfm" subtree. Every leaf has a key of its own, and an
+    expert's weights hang on its index in the whole layer, so the
+    shares of a layer drawn on different chips are slices of one
+    layer."""
+    cfg = dims.lfm
+    D, H = dims.context_vector_size, cfg.hidden_size
+    hd, f32 = cfg.head_dim, jnp.float32
+    init = jax.nn.initializers.variance_scaling(1.0, "fan_avg", "uniform")
+    k_in, k_out, k_pool = jax.random.split(rng, 3)
+    layers = []
+    for i, kind in enumerate(cfg.layer_types):
+        k = jax.random.split(jax.random.fold_in(rng, 100 + i), 7)
+        layer = {"op_norm": jnp.ones((H,), f32),
+                 "ff_norm": jnp.ones((H,), f32)}
+        if kind == "conv":
+            bound = 1.0 / math.sqrt(cfg.conv_L_cache)
+            layer.update(
+                conv_in=init(k[0], (H, 3 * H), f32),
+                conv_k=jax.random.uniform(k[1], (H, cfg.conv_L_cache), f32,
+                                          -bound, bound),
+                conv_out=init(k[2], (H, H), f32))
+        else:
+            kv = cfg.num_key_value_heads * hd
+            layer.update(
+                q=init(k[0], (H, H), f32), k=init(k[1], (H, kv), f32),
+                v=init(k[2], (H, kv), f32), o=init(k[3], (H, H), f32),
+                q_norm=jnp.ones((hd,), f32), k_norm=jnp.ones((hd,), f32))
+        if _is_moe(cfg, i):
+            F = cfg.moe_intermediate_size
+
+            def expert(e):
+                k1, k3, k2 = jax.random.split(jax.random.fold_in(k[6], e), 3)
+                return (init(k1, (H, F), f32), init(k3, (H, F), f32),
+                        init(k2, (F, H), f32))
+
+            w1, w3, w2 = jax.vmap(expert)(
+                cfg.first_expert + jnp.arange(cfg.num_experts))
+            layer.update(
+                router=init(k[4], (H, cfg.routed), f32),
+                expert_bias=BIAS_SCALE * jax.random.normal(
+                    k[5], (cfg.routed,), f32),
+                w1=w1, w3=w3, w2=w2)
+        else:
+            I = cfg.intermediate_size
+            layer.update(w1=init(k[4], (H, I), f32),
+                         w3=init(k[5], (H, I), f32),
+                         w2=init(k[6], (I, H), f32))
+        layers.append(layer)
+    return {"in_proj": init(k_in, (D, H), f32),
+            "out_proj": init(k_out, (H, D), f32),
+            "pool_query": init(k_pool, (H, 1), f32)[:, 0],
+            "ln_f_scale": jnp.ones((H,), f32),
+            "layers": layers}
+
+
+# ---- the two operators ---------------------------------------------------
+
+def _short_conv(h: jax.Array, mask: jax.Array, layer: Dict) -> jax.Array:
+    dtype, C = h.dtype, h.shape[1]
+    b, g, u = jnp.split(h @ layer["conv_in"].astype(dtype), 3, axis=-1)
+    v = b * u * mask[..., None].astype(dtype)
+    kernel = layer["conv_k"].astype(dtype)             # [H, L]
+    taps = kernel.shape[1]
+    padded = jnp.pad(v, ((0, 0), (taps - 1, 0), (0, 0)))
+    w = sum(kernel[:, j] * padded[:, j:j + C, :] for j in range(taps))
+    return (g * w) @ layer["conv_out"].astype(dtype)
+
+
+def _rotary(x: jax.Array, theta: float) -> jax.Array:
+    """x [B, heads, C, hd], position = index along C; the whole head
+    turns, pairs (i, i + hd/2) (rotate-half)."""
+    C, hd = x.shape[-2], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    angle = jnp.arange(C, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    half = jnp.concatenate([-x32[..., hd // 2:], x32[..., :hd // 2]], -1)
+    return (x32 * cos + half * sin).astype(x.dtype)
+
+
+def _attention(h: jax.Array, mask: jax.Array, layer: Dict,
+               cfg: Lfm2Dims) -> jax.Array:
+    dtype = h.dtype
+    B, C, H = h.shape
+    n, n_kv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+
+    def heads(t, count, scale=None):
+        t = t.reshape(B, C, count, hd)
+        if scale is not None:
+            t = _rms_norm(t, scale, cfg.norm_eps)
+        return t.transpose(0, 2, 1, 3)                 # [B, count, C, hd]
+
+    q = _rotary(heads(h @ layer["q"].astype(dtype), n, layer["q_norm"]),
+                cfg.rope_theta)
+    k = _rotary(heads(h @ layer["k"].astype(dtype), n_kv, layer["k_norm"]),
+                cfg.rope_theta)
+    v = heads(h @ layer["v"].astype(dtype), n_kv)
+    q = q.reshape(B, n_kv, n // n_kv, C, hd)
+    logits = jnp.einsum("bkgqd,bkcd->bkgqc", q, k).astype(jnp.float32) \
+        / math.sqrt(hd)
+    slot = jnp.arange(C)
+    seen = (slot[None, :] <= slot[:, None])[None] & (mask > 0)[:, None, :]
+    logits = jnp.where(seen[:, None, None], logits, -1e30)
+    att = jax.nn.softmax(logits, axis=-1).astype(dtype)
+    out = jnp.einsum("bkgqc,bkcd->bkgqd", att, v)
+    out = out.reshape(B, n, C, hd).transpose(0, 2, 1, 3).reshape(B, C, H)
+    return out @ layer["o"].astype(dtype)
+
+
+# ---- the two feed-forwards -----------------------------------------------
+
+def _dense_mlp(h: jax.Array, layer: Dict) -> jax.Array:
+    dtype = h.dtype
+    return (jax.nn.silu(h @ layer["w1"].astype(dtype))
+            * (h @ layer["w3"].astype(dtype))) @ layer["w2"].astype(dtype)
+
+
+def _routed_experts(h: jax.Array, mask: jax.Array, router: jax.Array,
+                    bias: jax.Array, w1: jax.Array, w3: jax.Array,
+                    w2: jax.Array, *, cfg: Lfm2Dims
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """(the held experts' output [B, C, H]; int32 [1, held + 1]: the
+    rows each held expert took, then the valid tokens)."""
+    B, C, H = h.shape
+    tokens = h.reshape(B * C, H)
+    valid = mask.reshape(B * C) > 0
+    with jax.named_scope("router"):
+        chosen, p = route(tokens, router, bias, cfg.num_experts_per_tok)
+    with jax.named_scope("experts"):
+        out, rows = held_experts_ffn(tokens, valid, chosen, p, w1, w3, w2,
+                                     cfg.first_expert)
+    counts = jnp.concatenate([rows, jnp.sum(valid, dtype=jnp.int32)[None]])
+    return out.reshape(B, C, H), counts[None]
+
+
+# ---- the encoder ---------------------------------------------------------
+
+def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
+                    path_ids: jax.Array, target_ids: jax.Array,
+                    mask: jax.Array, *, dims: ModelDims, mesh=None,
+                    dropout_rng: Optional[jax.Array] = None,
+                    dropout_keep_rate: float = 1.0,
+                    compute_dtype=jnp.float32,
+                    use_pallas: bool = False
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """encoder.encode's arguments and its two values, (code [B, 3E] in
+    the compute dtype, pool attention [B, C] f32), and a third: int32
+    [expert layers, held + 1], per expert layer the rows each held
+    expert took and, last, the valid tokens (the train step hands it to
+    `obs.route`; `get_encode_fn` drops it for the steps that do not
+    record). `use_pallas` is taken and not read: the
+    grouped product is XLA's own kernel on the TPU, the attention XLA's
+    on every backend."""
+    del use_pallas
+    cfg, lfm = dims.lfm, params["lfm"]
+    eps = cfg.norm_eps
+    emb = embed_contexts(params, source_ids, path_ids, target_ids,
+                         dropout_rng, dropout_keep_rate, compute_dtype)
+    experts = functools.partial(_routed_experts, cfg=cfg)
+    if mesh is not None:
+        # each device routes its own rows of the batch
+        from code2vec_tpu.parallel.sharding import shard_map_over_batch
+        experts = shard_map_over_batch(experts, mesh,
+                                       (True, True) + (False,) * 5)
+
+    def layer_fn(i: int):
+        conv = cfg.layer_types[i] == "conv"
+
+        def run(x, layer):
+            h = _rms_norm(x, layer["op_norm"], eps)
+            with jax.named_scope(f"c2v/blk_{i}/{'conv' if conv else 'attn'}"):
+                x = x + (_short_conv(h, mask, layer) if conv
+                         else _attention(h, mask, layer, cfg))
+            h = _rms_norm(x, layer["ff_norm"], eps)
+            if not _is_moe(cfg, i):
+                with jax.named_scope(f"c2v/blk_{i}/mlp"):
+                    return x + _dense_mlp(h, layer), None
+            with jax.named_scope(f"c2v/blk_{i}"):
+                ff, counts = experts(h, mask, layer["router"],
+                                     layer["expert_bias"], layer["w1"],
+                                     layer["w3"], layer["w2"])
+            return x + ff, jnp.sum(counts, axis=0)
+
+        return jax.checkpoint(run)
+
+    routes = []
+    with jax.named_scope("c2v/encode"):
+        # masked slots enter as zeros
+        x = (emb @ lfm["in_proj"].astype(compute_dtype)) \
+            * mask[..., None].astype(compute_dtype)
+        for i, layer in enumerate(lfm["layers"]):
+            x, counts = layer_fn(i)(x, layer)
+            if counts is not None:
+                routes.append(counts)
+
+    with jax.named_scope("c2v/pool"):
+        x = _rms_norm(x, lfm["ln_f_scale"], eps)
+        pooled, attn = learned_query_pool(x, lfm["pool_query"],
+                                          padding_log_mask(mask),
+                                          compute_dtype)
+        code = pooled @ lfm["out_proj"].astype(compute_dtype)
+    return code, attn, (jnp.stack(routes) if routes else jnp.zeros(
+        (0, cfg.num_experts + 1), jnp.int32))

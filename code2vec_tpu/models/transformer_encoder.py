@@ -28,7 +28,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from code2vec_tpu.models.encoder import ModelDims, take_rows
+from code2vec_tpu.models.encoder import ModelDims, embed_contexts
 
 
 def init_xf_params(rng: jax.Array, dims: ModelDims) -> Dict:
@@ -58,11 +58,33 @@ def init_xf_params(rng: jax.Array, dims: ModelDims) -> Dict:
     }
 
 
-def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
+def _rms_norm(x: jax.Array, scale: jax.Array,
+              eps: float = 1e-6) -> jax.Array:
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                    keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
+    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
             ).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def padding_log_mask(mask: jax.Array) -> jax.Array:
+    """[B, C] float32 additive mask: 0 at valid slots, log(1e-30) at
+    padding. An all-pad row keeps every slot live so that a softmax
+    over it stays finite."""
+    safe_mask = jnp.where(jnp.sum(mask, axis=-1, keepdims=True) > 0,
+                          mask, jnp.ones_like(mask))
+    return jnp.log(jnp.maximum(safe_mask, 1e-30)).astype(jnp.float32)
+
+
+def learned_query_pool(x: jax.Array, query: jax.Array,
+                       log_mask: jax.Array, compute_dtype
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """The reference's attention pool over already transformed
+    representations x [B, C, D]: (code [B, D], weights [B, C] f32)."""
+    pool_logits = (x.astype(jnp.float32)
+                   @ query.astype(jnp.float32)) + log_mask
+    attn = jax.nn.softmax(pool_logits, axis=-1)    # [B, C]
+    code = jnp.einsum("bc,bcd->bd", attn.astype(compute_dtype), x)
+    return code, attn
 
 
 def _mha(x: jax.Array, qkv: jax.Array, out: jax.Array,
@@ -129,24 +151,11 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
     xf = params["xf"]
     # phase scopes as in encoder.encode, `c2v/xf_layer_<i>` inside
     # `c2v/encode`
-    with jax.named_scope("c2v/embed_gather"):
-        rows = [take_rows(params, "token_emb", source_ids),
-                take_rows(params, "path_emb", path_ids),
-                take_rows(params, "token_emb", target_ids)]
-
+    emb = embed_contexts(params, source_ids, path_ids, target_ids,
+                         dropout_rng, dropout_keep_rate,
+                         compute_dtype)                # [B, C, D]
     with jax.named_scope("c2v/encode"):
-        emb = jnp.concatenate(rows, axis=-1).astype(
-            compute_dtype)                             # [B, C, D]
-        if dropout_rng is not None and dropout_keep_rate < 1.0:
-            keep = jax.random.bernoulli(dropout_rng, dropout_keep_rate,
-                                        emb.shape)
-            emb = jnp.where(keep, emb / dropout_keep_rate, 0.0)
-
-        # all-pad rows: keep one live key so softmax stays finite
-        safe_mask = jnp.where(jnp.sum(mask, axis=-1, keepdims=True) > 0,
-                              mask, jnp.ones_like(mask))
-        log_mask = jnp.log(jnp.maximum(safe_mask, 1e-30)).astype(
-            jnp.float32)
+        log_mask = padding_log_mask(mask)
 
     def layer_fn(x, layer):
         h = _rms_norm(x, layer["ln1_scale"])
@@ -169,10 +178,5 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
 
     with jax.named_scope("c2v/pool"):
         x = _rms_norm(x, xf["ln_f_scale"])
-        # learned-query pool (the reference's attention pool, over the
-        # transformed representations)
-        pool_logits = (x.astype(jnp.float32)
-                       @ xf["pool_query"].astype(jnp.float32)) + log_mask
-        attn = jax.nn.softmax(pool_logits, axis=-1)    # [B, C]
-        code = jnp.einsum("bc,bcd->bd", attn.astype(compute_dtype), x)
-    return code, attn
+        return learned_query_pool(x, xf["pool_query"], log_mask,
+                                  compute_dtype)

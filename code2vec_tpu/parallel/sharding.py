@@ -36,6 +36,10 @@ def param_pspecs() -> Dict[str, P]:
         # transformer encoder subtree ("xf"): one sharding for every leaf
         # (replicated — ~L*12*D^2 floats, tiny next to the vocab tables)
         "xf": P(),
+        # LFM2-MoE encoder subtree: replicated too (every device holds
+        # the same experts and routes its own rows; the expert exchange
+        # over a mesh axis is not built)
+        "lfm": P(),
     }
 
 

@@ -68,6 +68,7 @@ chaos scenarios exercise both the retry and the sticky-error path.
 
 from __future__ import annotations
 
+import dataclasses
 import errno
 import hashlib
 import json
@@ -81,7 +82,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import orbax.checkpoint as ocp
 
-from code2vec_tpu.models.encoder import ModelDims
+from code2vec_tpu.models.encoder import Lfm2Dims, ModelDims
 from code2vec_tpu.resilience import faults
 from code2vec_tpu.resilience import retry as retry_mod
 from code2vec_tpu.vocab.vocabularies import Code2VecVocabs
@@ -133,6 +134,7 @@ def _build_manifest(step: int, dims: ModelDims,
         "xf_mlp_ratio": dims.xf_mlp_ratio,
         "xf_remat": dims.xf_remat,
         "ring_attention": dims.ring_attention,
+        "lfm": dataclasses.asdict(dims.lfm) if dims.lfm else None,
         "step": step,
     }
     if extra_manifest:
@@ -601,6 +603,7 @@ def load_dims(ckpt_dir: str) -> ModelDims:
         xf_mlp_ratio=m.get("xf_mlp_ratio", 4),
         xf_remat=m.get("xf_remat", False),
         ring_attention=m.get("ring_attention", False),
+        lfm=Lfm2Dims.from_config(m["lfm"]) if m.get("lfm") else None,
     )
 
 

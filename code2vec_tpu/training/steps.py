@@ -34,23 +34,23 @@ def _weighted_mean(values: jax.Array, weights: jax.Array) -> jax.Array:
     return jnp.sum(values * weights) / denom
 
 
-def make_train_loss_fn(dims: ModelDims, *,
-                       use_sampled_softmax: bool = False,
-                       num_sampled: int = 4096,
-                       compute_dtype=jnp.float32,
-                       use_pallas: bool = False,
-                       mesh=None) -> Callable:
-    """The training-time loss `loss_fn(params, batch, rng)` (dropout on,
-    sampled or full softmax). Single source of truth: make_train_step
-    differentiates exactly this, and bench.py's fwd+bwd roofline floor
-    measures exactly this — the two MUST share it or the floor silently
-    measures different math than the step."""
-    encode = get_encode_fn(dims, mesh)
+def _make_loss_and_route_fn(dims: ModelDims, *, use_sampled_softmax: bool,
+                            num_sampled: int, compute_dtype,
+                            use_pallas: bool, mesh) -> Callable:
+    """`fn(params, batch, rng) -> (loss, route counts)`, for
+    `value_and_grad(..., has_aux=True)`. The counts are None but for
+    the encoder with routed experts (lfm2_moe_encoder.encode_lfm2_moe's
+    third value)."""
+    if dims.encoder_type == "lfm2_moe":
+        from code2vec_tpu.models.lfm2_moe_encoder import encode_lfm2_moe
+        encode = functools.partial(encode_lfm2_moe, dims=dims, mesh=mesh)
+    else:
+        encode = get_encode_fn(dims, mesh)
 
     def loss_fn(params, batch, rng):
         labels, src, pth, dst, mask, weights = batch
         drop_rng, sample_rng = jax.random.split(rng)
-        code, _attn = encode(
+        code, _attn, *route = encode(
             params, src, pth, dst, mask, dropout_rng=drop_rng,
             dropout_keep_rate=dims.dropout_keep_rate,
             compute_dtype=compute_dtype, use_pallas=use_pallas)
@@ -66,9 +66,27 @@ def make_train_loss_fn(dims: ModelDims, *,
                 ce = optax.softmax_cross_entropy_with_integer_labels(
                     logits, labels)
                 loss = _weighted_mean(ce, weights)
-        return loss
+        return loss, (route[0] if route else None)
 
     return loss_fn
+
+
+def make_train_loss_fn(dims: ModelDims, *,
+                       use_sampled_softmax: bool = False,
+                       num_sampled: int = 4096,
+                       compute_dtype=jnp.float32,
+                       use_pallas: bool = False,
+                       mesh=None) -> Callable:
+    """The training-time loss `loss_fn(params, batch, rng)` (dropout on,
+    sampled or full softmax). Single source of truth: make_train_step
+    differentiates exactly this, and bench.py's fwd+bwd roofline floor
+    measures exactly this — the two MUST share it or the floor silently
+    measures different math than the step."""
+    loss_and_route = _make_loss_and_route_fn(
+        dims, use_sampled_softmax=use_sampled_softmax,
+        num_sampled=num_sampled, compute_dtype=compute_dtype,
+        use_pallas=use_pallas, mesh=mesh)
+    return lambda params, batch, rng: loss_and_route(params, batch, rng)[0]
 
 
 def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
@@ -121,26 +139,50 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
             sparse_update_fused=sparse_update_fused,
             sparse_block_rows=sparse_block_rows, mesh=mesh)
 
-    loss_fn = make_train_loss_fn(
-        dims, use_sampled_softmax=use_sampled_softmax,
-        num_sampled=num_sampled, compute_dtype=compute_dtype,
-        use_pallas=use_pallas, mesh=mesh)
-
+    loss_kw = dict(use_sampled_softmax=use_sampled_softmax,
+                   num_sampled=num_sampled, compute_dtype=compute_dtype,
+                   use_pallas=use_pallas, mesh=mesh)
     if dims.tables_dtype == "int8":
-        return _make_quantized_train_step(optimizer, loss_fn, augment_fn,
-                                          requant_fused, mesh)
+        return _make_quantized_train_step(
+            optimizer, make_train_loss_fn(dims, **loss_kw), augment_fn,
+            requant_fused, mesh)
+    loss_and_route = _make_loss_and_route_fn(dims, **loss_kw)
+    routed = dims.encoder_type == "lfm2_moe"
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step(params, opt_state, batch, rng):
         if augment_fn is not None:
             rng, aug_rng = jax.random.split(rng)
             batch = augment_fn(batch, aug_rng)
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
+        (loss, route), grads = jax.value_and_grad(
+            loss_and_route, has_aux=True)(params, batch, rng)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = apply_updates(params, updates)
+        return params, opt_state, (loss, route) if routed else loss
+
+    return _recording_route(step) if routed else step
+
+
+def _recording_route(step: Callable) -> Callable:
+    """A routed-experts train step behind the contract of the others,
+    `(params, opt_state, loss)` and `.lower`: the jitted step's loss is
+    `(loss, route counts)`, and the counts go to `obs.route`, which
+    reads them a step later without a wait. The recorder is the
+    returned function's `route_recorder` (the train loop hands it the
+    run's tracer and flushes it after its last sync)."""
+    from code2vec_tpu.obs.route import RouteRecorder
+
+    recorder = RouteRecorder()
+
+    def recorded(params, opt_state, batch, rng):
+        params, opt_state, (loss, counts) = step(params, opt_state, batch,
+                                                 rng)
+        recorder.push(counts)
         return params, opt_state, loss
 
-    return step
+    recorded.route_recorder = recorder
+    recorded.lower = step.lower
+    return recorded
 
 
 def _make_quantized_train_step(optimizer, loss_fn, augment_fn,

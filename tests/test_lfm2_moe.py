@@ -1,0 +1,529 @@
+"""`--encoder lfm2_moe` (models/lfm2_moe_encoder.py, ops/moe.py,
+obs/route.py) at tiny sizes on the CPU, against the configuration's plain
+reference (benchmark/reference_lfm2moe.py): code vector, loss, every
+leaf's gradient and three optimizer steps in float32 and bfloat16; the
+convolution's causality and masking; the router; the expert-parallel
+shares adding up to the whole layer; no dropped row; no recompilation
+across routings; what `Config.verify()` refuses; the `moe/route` record;
+the step's named scopes; the model class end to end under the tests'
+8-device mesh."""
+
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from code2vec_tpu.models.encoder import (Lfm2Dims, ModelDims,
+                                         get_encode_fn, init_params)
+from code2vec_tpu.models import lfm2_moe_encoder as lfm
+from code2vec_tpu.ops import moe
+from tests.helpers import build_tiny_dataset
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmark"))
+
+import reference_lfm2moe as ref_mod  # noqa: E402
+
+BLOCK = dict(layer_types=["conv", "full_attention", "conv"],
+             num_dense_layers=1, hidden_size=64, intermediate_size=96,
+             moe_intermediate_size=48, num_attention_heads=4,
+             num_key_value_heads=2, num_experts=4, num_routed_experts=8,
+             first_expert=2, num_experts_per_tok=2, conv_L_cache=3,
+             norm_eps=1e-5, rope_parameters={"rope_theta": 1e6})
+LFM = Lfm2Dims.from_config(BLOCK)
+# the tables keep the product's width and at least 128 rows: optax
+# factors Adafactor's second moment only from 128 up, as the reference
+# always does
+SIZES = dict(tokens=200, paths=150, targets=130, embedding=128,
+             max_contexts=12, num_sampled=16, dropout_keep=0.75)
+DIMS = ModelDims(token_vocab_size=202, path_vocab_size=152,
+                 target_vocab_size=132, embeddings_size=128, max_contexts=12,
+                 dropout_keep_rate=0.75, encoder_type="lfm2_moe", lfm=LFM)
+SEED = 7
+
+
+def spec(dtype="float32"):
+    s = dict(SIZES, encoder="lfm2_moe", tables_dtype=dtype, lr=1e-3,
+             lr_schedule="cosine", lr_total_steps=400,
+             **{k: v for k, v in BLOCK.items() if k != "rope_parameters"})
+    s["rope_theta"] = 1e6
+    return s
+
+
+def batches(n=8, steps=3, seed=3):
+    r = np.random.default_rng(seed)
+    C = SIZES["max_contexts"]
+    out = []
+    for _ in range(steps):
+        lens = r.integers(1, C + 1, n)
+        mask = (np.arange(C)[None, :] < lens[:, None]).astype(np.float32)
+
+        def ids(v):
+            return (r.integers(2, v + 2, (n, C)) * mask).astype(np.int32)
+
+        out.append((r.integers(2, SIZES["targets"] + 2, n).astype(np.int32),
+                    ids(SIZES["tokens"]), ids(SIZES["paths"]),
+                    ids(SIZES["tokens"]), mask, np.ones(n, np.float32)))
+    return out
+
+
+def program_weights(dtype="float32"):
+    """The program's own start from SEED, as `Code2VecModel` draws it."""
+    rng, init_rng = jax.random.split(jax.random.PRNGKey(SEED))
+    dims = dataclasses.replace(DIMS, tables_dtype=dtype)
+    return dims, init_params(init_rng, dims), rng
+
+
+def flat(tree):
+    leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return {"/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                     for k in path): v for path, v in leaves}
+
+
+def test_reference_draws_the_programs_weights():
+    _dims, params, _ = program_weights()
+    ref, _key = ref_mod.make_weights(SEED, spec())
+    got = flat(params)
+    assert set(got) == set(ref)
+    for k in ref:
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(ref[k]),
+                                      err_msg=k)
+
+
+# the norm of the difference over the norm: float32 leaves room for
+# summation order alone; bfloat16 (8 bits of mantissa through three
+# layers) for its rounding and, at these widths, for the few tokens
+# whose second and third scores it swaps
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                       ("bfloat16", 0.12)])
+def test_code_vector_matches_reference(dtype, tol):
+    dims, params, _ = program_weights()
+    _labels, src, pth, dst, mask, _w = batches()[0]
+    code, attn = get_encode_fn(dims)(params, src, pth, dst,
+                                     jnp.asarray(mask),
+                                     compute_dtype=jnp.dtype(dtype))
+    p, _ = ref_mod.make_weights(SEED, spec())
+    c = jnp.concatenate([p["token_emb"][src], p["path_emb"][pth],
+                         p["token_emb"][dst]], axis=-1)
+    with jax.default_matmul_precision("highest"):
+        want = ref_mod.encode(p, c, jnp.asarray(mask),
+                              ref_mod.base.rounding(None), spec())
+    gap = float(jnp.linalg.norm(code.astype(jnp.float32) - want)
+                / jnp.linalg.norm(want))
+    assert gap <= tol, gap
+    assert np.all(np.asarray(attn)[mask == 0] < 1e-6)
+
+
+@pytest.mark.parametrize("dtype,loss_tol,grad_tol,change_tol", [
+    ("float32", 1e-5, 2e-4, 2e-3), ("bfloat16", 5e-3, 0.15, 0.05)])
+def test_three_steps_match_reference(dtype, loss_tol, grad_tol, change_tol):
+    """Loss, every leaf's first gradient (the norm of the difference over
+    the leaf's norm or the median leaf's) and the norm of each leaf's
+    change over three optimizer steps."""
+    from code2vec_tpu.training.optimizers import make_lr, make_optimizer
+    from code2vec_tpu.training.steps import (make_train_loss_fn,
+                                             make_train_step)
+
+    dims, params, rng = program_weights(dtype)
+    bs = batches()
+    ref = ref_mod.follow(SEED, spec(dtype), bs, block=4)
+    compute = jnp.dtype(dtype)
+    loss_fn = make_train_loss_fn(dims, use_sampled_softmax=True,
+                                 num_sampled=16, compute_dtype=compute)
+    # jitted, as the step runs it: op by op bfloat16 rounds at other
+    # places and swaps other near-tied experts than the step does
+    first_gradient = jax.jit(jax.value_and_grad(loss_fn))
+    loss, grads = first_gradient(
+        params, tuple(jnp.asarray(a) for a in bs[0]),
+        jax.random.fold_in(rng, 0))
+    assert float(loss) == pytest.approx(ref["losses"][0], rel=loss_tol)
+    norms = {k: float(np.linalg.norm(v))
+             for k, v in ref["dense_grads"].items()}
+    median = float(np.median(list(norms.values())))
+    got = flat(grads)
+    for k, want in ref["dense_grads"].items():
+        diff = float(np.linalg.norm(np.asarray(got[k], np.float32) - want))
+        assert diff <= grad_tol * max(norms[k], median), k
+    for k in ("token_emb", "path_emb", "target_emb"):
+        assert float(jnp.linalg.norm(got[k].astype(jnp.float32))) == \
+            pytest.approx(ref["grad_norms"][k], rel=grad_tol)
+
+    opt = make_optimizer(make_lr(1e-3, "cosine", 400))
+    step = make_train_step(dims, opt, use_sampled_softmax=True,
+                           num_sampled=16, compute_dtype=compute)
+    start = jax.tree_util.tree_map(jnp.copy, params)
+    state = opt.init(params)
+    losses = []
+    for i, b in enumerate(bs):
+        params, state, loss = step(params, state,
+                                   tuple(jnp.asarray(a) for a in b),
+                                   jax.random.fold_in(rng, i))
+        losses.append(float(loss))
+    np.testing.assert_allclose(losses, ref["losses"], rtol=loss_tol * 4)
+    after, before = flat(params), flat(start)
+    c_ref = ref["change_norms"]
+    c_median = float(np.median([v for v in c_ref.values() if v > 0]))
+    for k, want in c_ref.items():
+        change = float(jnp.linalg.norm((after[k].astype(jnp.float32)
+                                        - before[k].astype(jnp.float32))))
+        assert abs(change - want) <= change_tol * max(want, c_median), k
+    # the selection bias is a buffer: no gradient, no change
+    assert c_ref["lfm/layers/1/expert_bias"] == 0.0
+    np.testing.assert_array_equal(
+        np.asarray(after["lfm/layers/1/expert_bias"]),
+        np.asarray(before["lfm/layers/1/expert_bias"]))
+
+
+def test_convolution_is_causal_and_ignores_masked_slots():
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    H, C = 8, 10
+    layer = {"conv_in": jax.random.normal(k[0], (H, 3 * H)),
+             "conv_k": jax.random.normal(k[1], (H, 3)),
+             "conv_out": jax.random.normal(k[2], (H, H))}
+    h = jax.random.normal(k[3], (2, C, H))
+    mask = np.ones((2, C), np.float32)
+    mask[:, 4] = 0.0                      # a hole, and padding at the end
+    mask[:, 8:] = 0.0
+    out = lfm._short_conv(h, jnp.asarray(mask), layer)
+    # causal: slots before t do not see a change at t
+    moved = lfm._short_conv(h.at[:, 6].add(1.0), jnp.asarray(mask), layer)
+    np.testing.assert_array_equal(np.asarray(out[:, :6]),
+                                  np.asarray(moved[:, :6]))
+    assert not np.allclose(np.asarray(out[:, 6:8]), np.asarray(moved[:, 6:8]))
+    # reach: three taps, so slot 9 does not see slot 6
+    np.testing.assert_array_equal(np.asarray(out[:, 9]),
+                                  np.asarray(moved[:, 9]))
+    # a masked slot's input reaches no other slot
+    holed = lfm._short_conv(h.at[:, 4].add(3.0).at[:, 8].add(3.0),
+                            jnp.asarray(mask), layer)
+    keep = [0, 1, 2, 3, 5, 6, 7, 9]
+    np.testing.assert_allclose(np.asarray(out[:, keep]),
+                               np.asarray(holed[:, keep]), atol=1e-6)
+
+
+def test_masked_contexts_do_not_affect_code():
+    _dims, params, _ = program_weights()
+    _labels, src, pth, dst, mask, _w = batches()[0]
+    mask = mask.copy()
+    mask[:, 5:] = 0.0
+    enc = get_encode_fn(DIMS)
+    code1, attn = enc(params, src, pth, dst, jnp.asarray(mask))
+    src2 = src.copy()
+    src2[:, 5:] = (src2[:, 5:] + 7) % DIMS.token_vocab_size
+    code2, _ = enc(params, jnp.asarray(src2), pth, dst, jnp.asarray(mask))
+    np.testing.assert_allclose(np.asarray(code1), np.asarray(code2),
+                               atol=1e-6)
+    assert np.all(np.isfinite(np.asarray(code1)))
+    assert np.all(np.asarray(attn)[:, 5:] < 1e-6)
+
+
+def test_order_of_contexts_matters():
+    """Contexts have an order for the first time: reversing a full bag
+    changes the code vector (the bag and the set transformer do not)."""
+    _dims, params, _ = program_weights()
+    _labels, src, pth, dst, _mask, _w = batches()[0]
+    ones = jnp.ones(src.shape, jnp.float32)
+    enc = get_encode_fn(DIMS)
+    code1, _ = enc(params, src, pth, dst, ones)
+    code2, _ = enc(params, src[:, ::-1], pth[:, ::-1], dst[:, ::-1], ones)
+    assert float(jnp.max(jnp.abs(code1 - code2))) > 1e-3
+
+
+# ---- the router ----------------------------------------------------------
+
+def _router_case(n=64, H=16, E=16):
+    k = jax.random.split(jax.random.PRNGKey(1), 3)
+    return (jax.random.normal(k[0], (n, H)),
+            0.5 * jax.random.normal(k[1], (H, E)),
+            0.3 * jax.random.normal(k[2], (E,)))
+
+
+def test_router_bias_changes_who_is_chosen_and_not_p():
+    h, router, bias = _router_case()
+    chosen0, p0 = moe.route(h, router, jnp.zeros_like(bias), 4)
+    chosen1, p1 = moe.route(h, router, bias, 4)
+    assert np.any(np.sort(chosen0, -1) != np.sort(chosen1, -1))
+    # p is made of the scores alone, whatever the bias that chose them
+    s = jax.nn.sigmoid(h @ router)
+    s1 = jnp.take_along_axis(s, chosen1, axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(p1), np.asarray(s1 / (s1.sum(-1, keepdims=True) + 1e-6)),
+        rtol=1e-5)
+    # a token whose choice the bias left alone keeps its p
+    same = np.all(np.sort(chosen0, -1) == np.sort(chosen1, -1), axis=-1)
+    assert same.any()
+    np.testing.assert_allclose(np.sort(np.asarray(p0)[same], -1),
+                               np.sort(np.asarray(p1)[same], -1), rtol=1e-6)
+
+
+def test_router_p_sums_to_one_over_the_chosen():
+    h, router, bias = _router_case()
+    chosen, p = moe.route(h, router, bias, 4)
+    assert chosen.shape == p.shape == (64, 4)
+    assert all(len(set(row)) == 4 for row in np.asarray(chosen).tolist())
+    np.testing.assert_allclose(np.asarray(p.sum(-1)), 1.0, atol=1e-5)
+    assert np.all(np.asarray(p) > 0)
+
+
+# ---- expert parallelism --------------------------------------------------
+
+def _layer_case(E=64, H=32, F=24, n_tokens=96, seed=2):
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    return dict(
+        h=jax.random.normal(k[0], (n_tokens, H)),
+        valid=jnp.arange(n_tokens) % 7 != 3,
+        router=0.4 * jax.random.normal(k[1], (H, E)),
+        bias=0.05 * jax.random.normal(k[2], (E,)),
+        w1=0.2 * jax.random.normal(k[3], (E, H, F)),
+        w3=0.2 * jax.random.normal(k[4], (E, H, F)),
+        w2=0.2 * jax.random.normal(k[5], (E, F, H)))
+
+
+def _share(case, first, held, per_token=4):
+    chosen, p = moe.route(case["h"], case["router"], case["bias"], per_token)
+    sl = slice(first, first + held)
+    return moe.held_experts_ffn(case["h"], case["valid"], chosen, p,
+                                case["w1"][sl], case["w3"][sl],
+                                case["w2"][sl], first)
+
+
+def test_the_eight_shares_add_up_to_the_whole_layer():
+    """Experts 0-7, 8-15, ... as eight chips would hold them: their parts
+    of the result sum to the uncut 64-expert reference layer, and their
+    rows to every choice of every valid token."""
+    case = _layer_case()
+    with jax.default_matmul_precision("highest"):
+        whole = ref_mod.expert_layer(
+            case["h"], case["valid"], case["router"], case["bias"],
+            case["w1"], case["w3"], case["w2"], first=0, per_token=4)
+        parts = [_share(case, first, 8) for first in range(0, 64, 8)]
+    total = sum(out for out, _rows in parts)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=2e-5)
+    rows = sum(int(r.sum()) for _out, r in parts)
+    assert rows == 4 * int(case["valid"].sum())
+    # one share against the reference given the same share
+    with jax.default_matmul_precision("highest"):
+        want = ref_mod.expert_layer(
+            case["h"], case["valid"], case["router"], case["bias"],
+            case["w1"][16:24], case["w3"][16:24], case["w2"][16:24],
+            first=16, per_token=4)
+    np.testing.assert_allclose(np.asarray(parts[2][0]), np.asarray(want),
+                               atol=2e-5)
+    assert not np.allclose(np.asarray(parts[2][0]), np.asarray(whole),
+                           atol=1e-3)
+
+
+def test_no_row_is_dropped_when_all_tokens_choose_one_held_expert():
+    case = _layer_case(E=16)
+    case["valid"] = jnp.ones(96, bool)
+    # expert 5's score saturates for every token
+    case["router"] = case["router"].at[:, 5].set(0.0)
+    case["bias"] = case["bias"].at[5].set(10.0)
+    with jax.default_matmul_precision("highest"):
+        out, rows = _share(case, 4, 4)
+        want = ref_mod.expert_layer(
+            case["h"], case["valid"], case["router"], case["bias"],
+            case["w1"][4:8], case["w3"][4:8], case["w2"][4:8], first=4,
+            per_token=4)
+    assert int(rows[1]) == 96                   # every token, none dropped
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+def test_masked_tokens_are_routed_nowhere():
+    case = _layer_case(E=16)
+    out, rows = _share(case, 0, 16)
+    assert int(rows.sum()) == 4 * int(case["valid"].sum())
+    np.testing.assert_array_equal(np.asarray(out)[~np.asarray(case["valid"])],
+                                  0.0)
+
+
+def test_compiles_stay_zero_across_batches_of_different_routing():
+    import optax
+
+    from code2vec_tpu.training.steps import make_train_step
+
+    _dims, params, rng = program_weights()
+    opt = optax.adam(1e-3)
+    step = make_train_step(DIMS, opt, use_sampled_softmax=True,
+                           num_sampled=16)
+    state = opt.init(params)
+    compiles = [0]
+
+    def on_duration(event, duration, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    rows = []
+    for i, b in enumerate(batches(steps=5, seed=11)):
+        params, state, _loss = step(params, state,
+                                    tuple(jnp.asarray(a) for a in b),
+                                    jax.random.fold_in(rng, i))
+        if i == 0:
+            jax.block_until_ready(params)
+            compiles[0] = 0
+    step.route_recorder.flush()
+    from code2vec_tpu.obs import memory_tracer
+    rows = [tuple(map(tuple, r["attrs"]["layers"]))
+            for r in memory_tracer().records("moe/route")[-5:]]
+    assert len(set(rows)) == 5                  # five different routings
+    assert compiles[0] == 0
+
+
+# ---- configuration -------------------------------------------------------
+
+def _lfm_config_file(tmp_path):
+    path = tmp_path / "block.json"
+    path.write_text(json.dumps(BLOCK))
+    return str(path)
+
+
+@pytest.mark.parametrize("flags,message", [
+    (["--tables_dtype", "int8"], "int8"),
+    (["--sparse_embeddings", "--embedding_optimizer", "adam",
+      "--lr_schedule", "constant"], "SPARSE_EMBEDDING_UPDATES"),
+    (["--head", "varmisuse"], "varmisuse"),
+    (["--ring_attention"], "ring attention"),
+    (["--mesh_context", "2"], "context-parallel"),
+    (["--no_lfm_config"], "--lfm_config")])
+def test_verify_refuses(tmp_path, flags, message):
+    from code2vec_tpu.config import Config
+
+    argv = ["--data", str(tmp_path / "d"), "--encoder", "lfm2_moe",
+            "--backend", "cpu"]
+    if flags == ["--no_lfm_config"]:
+        flags = []
+    else:
+        argv += ["--lfm_config", _lfm_config_file(tmp_path)]
+    with pytest.raises(ValueError, match=message):
+        Config.load_from_args(argv + flags)
+
+
+def test_block_sizes_come_from_the_config_json():
+    assert LFM.routed == 8 and LFM.head_dim == 16
+    assert LFM.layer_types == ("conv", "full_attention", "conv")
+    # every width comes from the file; only the share has a default:
+    # all the router's experts, held from the first
+    whole = Lfm2Dims.from_config({k: v for k, v in BLOCK.items() if k not in
+                                  ("num_routed_experts", "first_expert")})
+    assert (whole.routed, whole.first_expert) == (4, 0)
+    with pytest.raises(ValueError, match="hidden_size"):
+        Lfm2Dims.from_config({k: v for k, v in BLOCK.items()
+                              if k != "hidden_size"})
+    with pytest.raises(ValueError, match="norm_topk_prob"):
+        Lfm2Dims.from_config(dict(BLOCK, norm_topk_prob=False))
+    with pytest.raises(ValueError, match="held"):
+        Lfm2Dims.from_config(dict(BLOCK, first_expert=6))
+    with pytest.raises(ValueError, match="layer_types"):
+        Lfm2Dims.from_config(dict(BLOCK, layer_types=["mamba"]))
+
+
+# ---- tracing -------------------------------------------------------------
+
+class _FakeCounts:
+    """What `RouteRecorder` uses of a device array."""
+
+    def __init__(self, table, ready=True):
+        self.table, self.ready, self.copied = table, ready, False
+
+    def copy_to_host_async(self):
+        self.copied = True
+
+    def is_ready(self):
+        return self.ready
+
+    def tolist(self):
+        return self.table
+
+
+def test_route_records_are_written_a_step_behind_and_never_waited_for(
+        monkeypatch):
+    from code2vec_tpu.obs import route, trace
+
+    monkeypatch.setattr(trace, "_MEMORY_TRACER", trace.MemoryTracer())
+    records = lambda: trace.memory_tracer().records("moe/route")  # noqa: E731
+    rec = route.RouteRecorder()
+    first = _FakeCounts([[3, 1, 9], [2, 2, 9]])
+    rec.push(first)
+    assert first.copied and records() == []      # never the step just sent
+    slow = _FakeCounts([[1, 1, 5]], ready=False)
+    rec.push(slow)
+    (only,) = records()
+    assert only["attrs"] == {"seq": 0, "layers": [[3, 1], [2, 2]],
+                             "rows_here": 8, "valid_tokens": 9}
+    rec.push(_FakeCounts([[0, 0, 0]]))
+    assert len(records()) == 1                   # the unready one is not awaited
+    rec.flush()
+    assert [r["attrs"]["seq"] for r in records()] == [0, 1, 2]
+
+
+def test_named_scopes_stand_in_every_step_that_runs_the_encoder():
+    from code2vec_tpu.training.steps import make_eval_step, make_train_step
+    import optax
+
+    _dims, params, rng = program_weights()
+    batch = tuple(jnp.asarray(a) for a in batches()[0])
+    opt = optax.adam(1e-3)
+    train = make_train_step(DIMS, opt, use_sampled_softmax=True,
+                            num_sampled=16).lower(
+        params, opt.init(params), batch, rng).as_text(debug_info=True)
+    evaluate = make_eval_step(DIMS, top_k=3).lower(params, batch).as_text(
+        debug_info=True)
+    for text in (train, evaluate):
+        for scope in ("c2v/encode", "c2v/blk_0/conv", "c2v/blk_0/mlp",
+                      "c2v/blk_1/attn", "c2v/blk_1/router",
+                      "c2v/blk_1/experts", "c2v/blk_2/conv",
+                      "c2v/blk_2/experts", "c2v/pool"):
+            assert scope in text, scope
+
+
+# ---- the model class -----------------------------------------------------
+
+def test_model_trains_evaluates_predicts_and_reloads(tmp_path):
+    """Through `Code2VecModel` on the tests' 8-device mesh (every device
+    routes its own rows), with the encoder's sizes kept by the
+    checkpoint."""
+    from code2vec_tpu.models.jax_model import Code2VecModel
+    from tests.test_model import tiny_config
+
+    prefix = build_tiny_dataset(str(tmp_path), n_train=256, n_val=32,
+                                n_test=64, max_contexts=16)
+    cfg = tiny_config(prefix, ENCODER_TYPE="lfm2_moe",
+                      LFM_CONFIG=_lfm_config_file(tmp_path),
+                      NUM_TRAIN_EPOCHS=6, LEARNING_RATE=0.003,
+                      TELEMETRY_DIR=str(tmp_path / "tele"), TRACE=True)
+    ckpt_dir = str(tmp_path / "ckpt")
+    cfg.save_path = ckpt_dir
+    model = Code2VecModel(cfg)
+    model.train()
+    result = model.evaluate()
+    assert result.subtoken_f1 > 0.3
+    model.save(ckpt_dir)
+    from code2vec_tpu.obs import memory_tracer
+    assert memory_tracer().records("moe/route")[-1]["attrs"]["rows_here"] > 0
+    # the --trace log holds a record a step, and the report prints them
+    from tests.test_trace import _spans
+    from tools.trace_report import render, route_summary
+    spans = _spans(model.telemetry.run_dir)
+    route = route_summary(spans)
+    assert route["steps"] == model.step_num
+    assert (route["expert_layers"], route["held_experts"]) == (2, 4)
+    assert 0 < route["rows_here"] <= 2 * 2 * route["valid_tokens"]
+    assert route["imbalance"] >= 1.0
+    assert f"Routed experts: {route['rows_here']:,} rows" in render(
+        [({}, spans)])
+    assert route_summary([s for s in spans if s["name"] != "moe/route"]) \
+        is None
+
+    cfg2 = tiny_config(prefix)
+    cfg2.load_path = ckpt_dir
+    model2 = Code2VecModel(cfg2)
+    assert model2.dims.lfm == LFM
+    loaded = model2.evaluate()
+    assert loaded.topk_acc == pytest.approx(result.topk_acc)

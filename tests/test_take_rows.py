@@ -10,7 +10,7 @@ import numpy as np
 import optax
 import pytest
 
-from code2vec_tpu.models import encoder, transformer_encoder
+from code2vec_tpu.models import encoder
 from code2vec_tpu.models.encoder import (PAD_ID, ModelDims, init_params,
                                          take_rows)
 from code2vec_tpu.training.steps import make_train_step
@@ -179,8 +179,8 @@ def test_three_train_steps_equal_those_with_plain_take(
         return losses, params
 
     losses, params = three_steps()
-    for module in (encoder, transformer_encoder):
-        monkeypatch.setattr(module, "take_rows", plain_take)
+    # every encoder gathers through `encoder.embed_contexts`
+    monkeypatch.setattr(encoder, "take_rows", plain_take)
     want_losses, want = three_steps()
     # a PAD slot's cotangent is an exact zero under the bag's mask and
     # about 1e-30 under the transformer's, so row 0's sum is the same

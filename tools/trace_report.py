@@ -31,7 +31,10 @@ emits) and produces:
   - per-step table: infeed_wait / step / save_blocked (+ the writer's
     save_write wall) from the `train/step_cycle` traces, and the
     batches' PAD slots (`pad_slots` of `infeed/produce`: how often the
-    embedding gather spreads a PAD read).
+    embedding gather spreads a PAD read), and for a routed-experts
+    encoder the `moe/route` records: rows routed to the experts held
+    here over the valid tokens' choices, and the fullest expert's rows
+    over the mean.
 
 Pure stdlib; reads only manifest + events files, so it works on a
 laptop over a run dir scp'd from a pod (same contract as
@@ -327,6 +330,29 @@ def pad_slot_summary(spans: Sequence[Dict[str, Any]]
             "pad_slots": sum(a["pad_slots"] for a in counted)}
 
 
+def route_summary(spans: Sequence[Dict[str, Any]]
+                  ) -> Optional[Dict[str, Any]]:
+    """The run's `moe/route` spans (one a train step of an encoder with
+    routed experts; obs/route.py) summed: steps, rows routed to the
+    experts held here, valid tokens, expert layers, and the mean over
+    the steps of the fullest held expert's rows over the mean expert's,
+    by the worst layer. None when the run has none."""
+    routes = [s.get("attrs") or {} for s in spans
+              if s["name"] == "moe/route"]
+    routes = [a for a in routes if a.get("layers")]
+    if not routes:
+        return None
+    worst = [max(max(rows) * len(rows) / sum(rows)
+                 for rows in a["layers"] if sum(rows))
+             for a in routes if a["rows_here"]]
+    return {"steps": len(routes),
+            "rows_here": sum(a["rows_here"] for a in routes),
+            "valid_tokens": sum(a["valid_tokens"] for a in routes),
+            "expert_layers": len(routes[0]["layers"]),
+            "held_experts": len(routes[0]["layers"][0]),
+            "imbalance": sum(worst) / len(worst) if worst else None}
+
+
 def save_breakdowns(spans: Sequence[Dict[str, Any]]
                     ) -> List[Dict[str, Any]]:
     rows = []
@@ -428,6 +454,15 @@ def render(loaded, limit: int = 10) -> str:
             lines.append(f"PAD slots: {pad['pad_slots']:,} in "
                          f"{pad['rows']:,} rows of {pad['batches']} "
                          f"batches{share}")
+        route = route_summary(spans)
+        if route:
+            lines.append("")
+            lines.append(
+                f"Routed experts: {route['rows_here']:,} rows to the "
+                f"{route['held_experts']} experts held here in "
+                f"{route['expert_layers']} layers over {route['steps']} "
+                f"steps ({route['valid_tokens']:,} valid tokens), "
+                f"fullest expert {_fmt(route['imbalance'])}x the mean")
         save_rows = save_breakdowns(spans)
         if save_rows:
             lines.append("")
