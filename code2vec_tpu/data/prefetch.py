@@ -35,7 +35,14 @@ profiler's trace on the device's clock):
                               which is how often `take_rows` spreads
                               a PAD read)
   infeed/transfer  producer   `put_fn(batch)`: host arrays, then the
-                              device_put (`seq`, `bytes`)
+                              device_put (`seq`, `bytes`;
+                              `gather_slots` where what it returns
+                              carries one, as the model's training
+                              batches do: the slots the step chosen
+                              for the batch takes table rows for, the
+                              staircase's area when the batch fits it
+                              and rows x max_contexts when it does
+                              not; data/staircase.py)
   infeed/blocked   producer   the bounded put into the queue: the
                               producer's slack (`seq`)
   infeed/pop_wait  consumer   `q.get()` (`seq` of the batch it popped;
@@ -68,18 +75,20 @@ _BATCH_SEQ = itertools.count()
 
 
 class BatchRecord:
-    """One produced batch: its sequence number, rows, PAD slots and
-    bytes, and on the recorder's clock where its read started and its
-    transfer ended. Rides the queue item the producer builds;
-    `on_produced` (the `--trace` hook) gets it after the transfer."""
+    """One produced batch: its sequence number, rows, PAD slots,
+    gathered slots and bytes, and on the recorder's clock where its
+    read started and its transfer ended. Rides the queue item the
+    producer builds; `on_produced` (the `--trace` hook) gets it after
+    the transfer."""
 
-    __slots__ = ("seq", "rows", "pad_slots", "bytes", "read_start",
-                 "transfer_end")
+    __slots__ = ("seq", "rows", "pad_slots", "gather_slots", "bytes",
+                 "read_start", "transfer_end")
 
     def __init__(self, seq: int, rows, pad_slots, read_start: float):
         self.seq = seq
         self.rows = rows
         self.pad_slots = pad_slots
+        self.gather_slots = None
         self.bytes = 0
         self.read_start = read_start
         self.transfer_end = None
@@ -122,10 +131,13 @@ def _read_batches(batches: Iterable, recorder
 def _transfer(fn: Callable, b, record: BatchRecord, recorder,
               on_produced: Optional[Callable]):
     """`fn(b)` under `infeed/transfer`; the bytes are those of what it
-    returns."""
+    returns, and the gathered slots what it says of itself."""
     with recorder.start_span("infeed/transfer", seq=record.seq) as span:
         out = fn(b)
         record.bytes = span.attrs["bytes"] = _nbytes(out)
+        record.gather_slots = getattr(out, "gather_slots", None)
+        if record.gather_slots is not None:
+            span.attrs["gather_slots"] = record.gather_slots
     record.transfer_end = span.interval[1]
     if on_produced is not None:
         on_produced(record)

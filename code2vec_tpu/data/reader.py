@@ -33,6 +33,7 @@ from typing import Iterator, List, NamedTuple, Optional
 
 import numpy as np
 
+from code2vec_tpu.data.staircase import length_order
 from code2vec_tpu.vocab.vocabularies import Code2VecVocabs
 
 
@@ -250,7 +251,15 @@ class C2VTextReader:
 class BinaryShardReader:
     """Fast-path reader over the pre-tokenized int32 shard written by
     data/binarize.py: a memmapped [N, 1 + 3*C] int32 matrix
-    (label, src*C, path*C, tgt*C) + a JSON manifest."""
+    (label, src*C, path*C, tgt*C) + a JSON manifest.
+
+    Row order inside a batch is the reader's to choose (membership is
+    the shuffled permutation's). As opened, rows stand in file order.
+    After `order_by_length(groups)`, which only the training infeed
+    calls (`Code2VecModel._train_infeed`, for a model that takes table
+    rows over a staircase: data/staircase.py), every whole batch is
+    ordered by bag length instead, longest first. A reader opened for
+    evaluation is never asked: its results are matched to rows."""
 
     def __init__(self, prefix: str, batch_size: int, shuffle: bool = False,
                  seed: int = 0, host_shard: int = 0,
@@ -285,6 +294,17 @@ class BinaryShardReader:
         # see C2VTextReader: resume replays the interrupted epoch's
         # seeded permutation instead of restarting the stream at 0
         self._epoch = epoch_offset
+        self._length_groups = 0     # 0: batches keep file order
+
+    def order_by_length(self, groups: int = 1) -> None:
+        """From the next pass on, order each whole batch's rows by bag
+        length, longest first, in `groups` contiguous blocks of equal
+        load (`staircase.length_order`: a mesh's device `g` gets block
+        `g`). A short last batch keeps file order: its padding rows
+        must stay last (`num_valid_examples` counts from the front)."""
+        assert groups >= 1 and self.batch_size % groups == 0, (
+            groups, self.batch_size)
+        self._length_groups = groups
 
     def __iter__(self) -> Iterator[BatchTensors]:
         C = self.max_contexts
@@ -312,8 +332,17 @@ class BinaryShardReader:
             src = rows[:, 1:1 + C]
             pth = rows[:, 1 + C:1 + 2 * C]
             dst = rows[:, 1 + 2 * C:1 + 3 * C]
-            mask = (pth != self.pad_index).astype(np.float32)
+            valid = pth != self.pad_index
             nv = rows.shape[0]
+            if self._length_groups and nv == self.batch_size:
+                # the permuted copies stand in for the contiguous ones
+                # below: about the same bytes moved
+                perm = length_order(np.count_nonzero(valid, axis=1),
+                                    self._length_groups)
+                sorted_idx, labels, valid = (sorted_idx[perm], labels[perm],
+                                             valid[perm])
+                src, pth, dst = src[perm], pth[perm], dst[perm]
+            mask = valid.astype(np.float32)
             tstr = None
             if self.target_strings is not None:
                 tstr = [self.target_strings[i] for i in sorted_idx]

@@ -20,10 +20,12 @@ TPU-first design choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from code2vec_tpu.ops.attention import attention_pool
 
@@ -251,23 +253,123 @@ def _spread_pad(vocab: int, ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return is_pad, jnp.where(is_pad, own.astype(ids.dtype), ids)
 
 
+def _kept_of_column(rects) -> np.ndarray:
+    """int32 [C]: the rows the rectangle over each column keeps."""
+    return np.repeat([kept for _, _, kept in rects],
+                     [hi - lo for lo, hi, _ in rects]).astype(np.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _place(flat: jax.Array, pad_value: jax.Array, rects, rows: int
+           ) -> jax.Array:
+    """`[rows, C, E]` with `flat`'s rows at the slots of the rectangles
+    `rects` (`(first column, end column, rows kept)`, left to right up
+    to C; `flat` holds them one after another, each row-major) and
+    `pad_value` `[E]` at every slot outside. A rectangle reaches its
+    place as a block (filled up to `rows` below, set side by side, one
+    select on the slot's row puts `pad_value` outside), never by an
+    index, and the backward is written out as what it is: slices of
+    the cotangent for `flat`, the sum of the rest for `pad_value`
+    (autodiff's own transpose masks the whole cotangent before it
+    slices it, one more pass over `[rows, C, E]`)."""
+    E = flat.shape[1]
+    blocks, start = [], 0
+    for lo, hi, kept in rects:
+        stop = start + kept * (hi - lo)
+        blocks.append(jax.lax.pad(
+            flat[start:stop].reshape(kept, hi - lo, E),
+            jnp.zeros((), flat.dtype),
+            ((0, rows - kept, 0), (0, 0, 0), (0, 0, 0))))
+        start = stop
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, rects[-1][1], 1), 0)
+    return jnp.where(row < _kept_of_column(rects)[None, :, None],
+                     jnp.concatenate(blocks, axis=1), pad_value)
+
+
+def _place_fwd(flat, pad_value, rects, rows):
+    return _place(flat, pad_value, rects, rows), None
+
+
+def _place_bwd(rects, rows, _, ct):
+    row = jax.lax.broadcasted_iota(jnp.int32, ct.shape[:2] + (1,), 0)
+    outside = row >= _kept_of_column(rects)[None, :, None]
+    return (jnp.concatenate([ct[:kept, lo:hi].reshape(-1, ct.shape[2])
+                             for lo, hi, kept in rects]),
+            jnp.sum(jnp.where(outside, ct, 0), axis=(0, 1)))
+
+
+_place.defvjp(_place_fwd, _place_bwd)
+
+
+def _take_staircase(token_emb: jax.Array, path_emb: jax.Array,
+                    source_ids: jax.Array, path_ids: jax.Array,
+                    target_ids: jax.Array, stairs):
+    """`(token_emb[source], path_emb[path], token_emb[target])`, each
+    `[B, C, E]`, for ids that are PAD outside the staircase `stairs`
+    (data/staircase.py: rectangles `rows[:kept] x columns[first:next
+    first]`): table rows are taken for the rectangles' slots only, one
+    flat gather a table, and every slot outside holds the PAD row's
+    value, which is what `take_rows` gives a PAD slot. So the values
+    are `take_rows`' bit for bit, and the gathers' scatters in the
+    backward see the rectangles' rows only (the cotangent of the slots
+    outside is summed into row 0, as before)."""
+    B, C = path_ids.shape
+    firsts = [first for first, _ in stairs] + [C]
+    rects = tuple((first, firsts[k + 1], kept)
+                  for k, (first, kept) in enumerate(stairs))
+
+    def cut(ids):       # the rectangles' ids, one flat vector
+        return [ids[:kept, lo:hi].reshape(-1) for lo, hi, kept in rects]
+
+    tables = {"token_emb": token_emb, "path_emb": path_emb}
+    tok = take_rows(tables, "token_emb",
+                    jnp.concatenate(cut(source_ids) + cut(target_ids)))
+    pth = take_rows(tables, "path_emb", jnp.concatenate(cut(path_ids)))
+    src, dst = jnp.split(tok, 2)
+    return (_place(src, token_emb[PAD_ID], rects, B),
+            _place(pth, path_emb[PAD_ID], rects, B),
+            _place(dst, token_emb[PAD_ID], rects, B))
+
+
 def embed_contexts(params: Params, source_ids: jax.Array,
                    path_ids: jax.Array, target_ids: jax.Array,
                    dropout_rng: Optional[jax.Array],
-                   dropout_keep_rate: float, compute_dtype) -> jax.Array:
+                   dropout_keep_rate: float, compute_dtype,
+                   staircase=None, mesh=None) -> jax.Array:
     """[B, C, 3E] contexts in the compute dtype, dropout applied: what
-    every encoder starts from."""
+    every encoder starts from.
+
+    `staircase` (a `data/staircase.Stairs`; training only) says that
+    the caller has CHECKED that every id outside its rectangles is PAD:
+    `BinaryShardReader.order_by_length` orders a training batch's rows
+    by bag length, the producer checks the ordered batch
+    (`staircase.fits`) and `training/steps` hands the staircase to the
+    step it runs for a batch that fits. Table rows are then taken for
+    the rectangles only, and the result is the same array bit for bit.
+    Nothing here looks at the ids: with a staircase and other ids the
+    slots outside would read as PAD. Evaluation, prediction and serving
+    pass none and are never ordered. Over `mesh` each device takes its
+    own rows' rectangles (the staircase is a device's)."""
     # the step's phases by name (`c2v/...`): an op's metadata carries
     # the scope path, the backward's as `transpose(jvp(c2v/...))`, so
     # a profile tells the phases apart without reading shapes
     with jax.named_scope("c2v/embed_gather"):
-        src = take_rows(params, "token_emb", source_ids)
-        pth = take_rows(params, "path_emb", path_ids)
-        dst = take_rows(params, "token_emb", target_ids)
+        if staircase is None:
+            rows = (take_rows(params, "token_emb", source_ids),
+                    take_rows(params, "path_emb", path_ids),
+                    take_rows(params, "token_emb", target_ids))
+        else:
+            take = functools.partial(_take_staircase, stairs=staircase)
+            if mesh is not None:
+                from code2vec_tpu.parallel.sharding import \
+                    shard_map_over_batch
+                take = shard_map_over_batch(
+                    take, mesh, (False, False, True, True, True))
+            rows = take(params["token_emb"], params["path_emb"],
+                        source_ids, path_ids, target_ids)
 
     with jax.named_scope("c2v/encode"):
-        contexts = jnp.concatenate([src, pth, dst],
-                                   axis=-1).astype(compute_dtype)
+        contexts = jnp.concatenate(rows, axis=-1).astype(compute_dtype)
         if dropout_rng is not None and dropout_keep_rate < 1.0:
             keep = jax.random.bernoulli(dropout_rng, dropout_keep_rate,
                                         contexts.shape)
@@ -281,17 +383,19 @@ def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,
            dropout_keep_rate: float = 1.0,
            compute_dtype=jnp.float32,
            use_pallas: bool = False,
-           mesh=None) -> Tuple[jax.Array, jax.Array]:
+           mesh=None, staircase=None) -> Tuple[jax.Array, jax.Array]:
     """Forward to the code vector.
 
     Args: [B, C] int32 ids for source token / path / target token, [B, C]
     f32 mask. Returns (code_vectors [B, D] in compute dtype,
     attention [B, C] f32). use_pallas selects the fused Pallas pooling
     kernel (ops/pallas_attention.py); inside a step partitioned over
-    `mesh` each device runs it on its own batch rows.
+    `mesh` each device runs it on its own batch rows. `staircase`:
+    `embed_contexts`.
     """
     contexts = embed_contexts(params, source_ids, path_ids, target_ids,
-                              dropout_rng, dropout_keep_rate, compute_dtype)
+                              dropout_rng, dropout_keep_rate, compute_dtype,
+                              staircase, mesh)
 
     with jax.named_scope("c2v/pool"):
         if use_pallas:
@@ -316,8 +420,6 @@ def get_encode_fn(dims: ModelDims, mesh=None):
     `mesh` places the Pallas kernels per device (and feeds the
     transformer's ring-attention path: dims.ring_attention with a ctx
     axis > 1)."""
-    import functools
-
     if dims.encoder_type == "transformer":
         from code2vec_tpu.models.transformer_encoder import (
             encode_transformer)

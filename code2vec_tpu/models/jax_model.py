@@ -22,18 +22,22 @@ from code2vec_tpu import device
 from code2vec_tpu.common import (EvaluationResults, MethodPredictionResults,
                                  SpecialVocabWords)
 from code2vec_tpu.config import Config
-from code2vec_tpu.data.reader import (BatchTensors, _pad_batch, open_reader,
+from code2vec_tpu.data import staircase
+from code2vec_tpu.data.reader import (BatchTensors, BinaryShardReader,
+                                      _pad_batch, open_reader,
                                       parse_c2v_rows)
-from code2vec_tpu.models.encoder import ModelDims, init_params
+from code2vec_tpu.models.encoder import PAD_ID, ModelDims, init_params
 from code2vec_tpu.models.model_base import Code2VecModelBase, MetricAccumulator
 from code2vec_tpu.parallel.distributed import fetch_global
-from code2vec_tpu.parallel.mesh import DATA_AXIS, DCN_AXIS, MODEL_AXIS
+from code2vec_tpu.parallel.mesh import (CONTEXT_AXIS, DATA_AXIS, DCN_AXIS,
+                                        MODEL_AXIS)
 from code2vec_tpu.parallel.sharding import (shard_batch, shard_opt_state,
                                             shard_params)
 from code2vec_tpu.training import checkpoint as ckpt
 from code2vec_tpu.training.profiler import StepProfiler
-from code2vec_tpu.training.steps import (make_encode_step, make_eval_step,
-                                         make_predict_step, make_train_step)
+from code2vec_tpu.training.steps import (TrainBatch, make_encode_step,
+                                         make_eval_step, make_predict_step,
+                                         make_train_step)
 from code2vec_tpu.vocab.vocabularies import Code2VecVocabs, VocabType
 
 
@@ -249,6 +253,8 @@ class Code2VecModel(Code2VecModelBase):
         from code2vec_tpu.ops.quant import resolve_requant_mode
         from code2vec_tpu.training.sparse_update import \
             resolve_sparse_update_mode
+        self._stair_groups, self._staircase = self._training_staircase()
+        self._full_step_logged = False
         self._train_step = make_train_step(
             self.dims, self.optimizer,
             use_sampled_softmax=cfg.USE_SAMPLED_SOFTMAX,
@@ -260,7 +266,8 @@ class Code2VecModel(Code2VecModelBase):
             sparse_updates=cfg.SPARSE_EMBEDDING_UPDATES,
             learning_rate=cfg.LEARNING_RATE,
             sparse_update_fused=resolve_sparse_update_mode(
-                cfg.SPARSE_UPDATE_PALLAS))
+                cfg.SPARSE_UPDATE_PALLAS),
+            staircase=self._staircase)
         # background checkpoint writer (--async_checkpoint, default on):
         # created lazily at the first save so load/predict-only model
         # instances never start the thread
@@ -323,13 +330,77 @@ class Code2VecModel(Code2VecModelBase):
         # prefetch thread parse-only (round-4 infeed A/B finding)
         return tuple(jnp.asarray(a) for a in arrays)
 
+    def _training_staircase(self):
+        """(groups, staircase): the rectangles the train step takes
+        table rows over (data/staircase.py), worked out from the
+        training shard's bag lengths for one device's rows of a batch,
+        and the number of devices a batch is dealt to; (1, None) where
+        today's step runs alone: no training data, a text corpus, the
+        int8 or sparse step, a mesh that splits tables or contexts, a
+        chunked infeed (its batches reach the step as slices, unmarked),
+        several processes (each would choose its step by its own rows,
+        and one program has to run on all), bags so full that the
+        staircase is the whole rectangle."""
+        cfg = self.config
+        groups = 1
+        if self.mesh is not None:
+            shape = dict(self.mesh.shape)
+            if shape.get(MODEL_AXIS, 1) > 1 or shape.get(CONTEXT_AXIS, 1) > 1:
+                return 1, None
+            groups = shape.get(DCN_AXIS, 1) * shape.get(DATA_AXIS, 1)
+        if (not cfg.is_training or cfg.SPARSE_EMBEDDING_UPDATES
+                or self.dims.tables_dtype == "int8"
+                or jax.process_count() > 1
+                or (cfg.INFEED_CHUNK > 1 and self.mesh is None)
+                or cfg.TRAIN_BATCH_SIZE % groups):
+            return 1, None
+        reader = open_reader(cfg.data_path("train"), self.vocabs,
+                             cfg.MAX_CONTEXTS, cfg.TRAIN_BATCH_SIZE)
+        if not isinstance(reader, BinaryShardReader) \
+                or reader.pad_index != PAD_ID:
+            return 1, None
+        rows = cfg.TRAIN_BATCH_SIZE // groups
+        stairs = staircase.from_lengths(
+            staircase.shard_lengths(reader.data, reader.max_contexts,
+                                    reader.pad_index),
+            rows, reader.max_contexts)
+        if stairs == ((0, rows),):      # the whole rectangle
+            return 1, None
+        self.log(f"embedding rows taken over the staircase {stairs} of "
+                 f"{rows} x {reader.max_contexts} slots a device")
+        return groups, stairs
+
+    def _train_device_batch(self, b: BatchTensors) -> TrainBatch:
+        """`_device_batch` for the training infeed, with the producer's
+        answer on it: whether every id of a whole batch outside the
+        staircase is PAD (`staircase.fits`; an ordered batch of the
+        shard the staircase was sized from does, nearly always), and
+        the slots the step it chooses takes table rows for."""
+        stairs, groups = self._staircase, self._stair_groups
+        whole = b.num_valid_examples == b.target_index.shape[0]
+        fits = stairs is not None and whole and staircase.fits(
+            stairs, (b.path_source_token_indices, b.path_indices,
+                     b.path_target_token_indices), groups)
+        if fits:
+            slots = groups * staircase.area(stairs, self.dims.max_contexts)
+        else:
+            slots = b.num_valid_examples * self.dims.max_contexts
+            if stairs is not None and whole and not self._full_step_logged:
+                self._full_step_logged = True
+                self.log("a whole batch does not fit the staircase: it "
+                         "runs the full step (compiled at its first use)")
+        return TrainBatch(self._device_batch(b), fits, slots)
+
     def _train_infeed(self, reader, instrument=None, heartbeat=None):
         from code2vec_tpu.data.prefetch import build_train_infeed
+        if self._staircase is not None \
+                and isinstance(reader, BinaryShardReader):
+            reader.order_by_length(self._stair_groups)
         return build_train_infeed(
             reader, chunk=self.config.INFEED_CHUNK,
             depth=self.config.INFEED_PREFETCH, mesh=self.mesh,
             host_arrays_fn=self._host_batch_arrays,
-            device_batch_fn=self._device_batch, log=self.log,
+            device_batch_fn=self._train_device_batch, log=self.log,
             instrument=instrument, heartbeat=heartbeat)
 
 

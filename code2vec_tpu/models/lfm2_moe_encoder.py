@@ -225,7 +225,7 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
                     dropout_rng: Optional[jax.Array] = None,
                     dropout_keep_rate: float = 1.0,
                     compute_dtype=jnp.float32,
-                    use_pallas: bool = False
+                    use_pallas: bool = False, staircase=None
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """encoder.encode's arguments and its two values, (code [B, 3E] in
     the compute dtype, pool attention [B, C] f32), and a third: int32
@@ -239,7 +239,8 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
     cfg, lfm = dims.lfm, params["lfm"]
     eps = cfg.norm_eps
     emb = embed_contexts(params, source_ids, path_ids, target_ids,
-                         dropout_rng, dropout_keep_rate, compute_dtype)
+                         dropout_rng, dropout_keep_rate, compute_dtype,
+                         staircase, mesh)
     experts = functools.partial(_routed_experts, cfg=cfg)
     if mesh is not None:
         # each device routes its own rows of the batch

@@ -132,7 +132,8 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
                        dropout_rng: Optional[jax.Array] = None,
                        dropout_keep_rate: float = 1.0,
                        compute_dtype=jnp.float32,
-                       use_pallas: bool = False
+                       use_pallas: bool = False,
+                       staircase=None
                        ) -> Tuple[jax.Array, jax.Array]:
     """Same contract as encoder.encode: returns (code [B, D] in compute
     dtype, pool attention [B, C] f32). With `use_pallas`, the
@@ -152,8 +153,8 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
     # phase scopes as in encoder.encode, `c2v/xf_layer_<i>` inside
     # `c2v/encode`
     emb = embed_contexts(params, source_ids, path_ids, target_ids,
-                         dropout_rng, dropout_keep_rate,
-                         compute_dtype)                # [B, C, D]
+                         dropout_rng, dropout_keep_rate, compute_dtype,
+                         staircase, mesh)              # [B, C, D]
     with jax.named_scope("c2v/encode"):
         log_mask = padding_log_mask(mask)
 

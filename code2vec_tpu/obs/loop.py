@@ -66,7 +66,8 @@ def infeed_produce_instrument(tracer: Tracer,
         channel.send(tracer.record_span(
             "infeed/produce", record.read_start, record.transfer_end,
             seq=record.seq, rows=record.rows,
-            pad_slots=record.pad_slots, bytes=record.bytes))
+            pad_slots=record.pad_slots,
+            gather_slots=record.gather_slots, bytes=record.bytes))
     return on_produced
 
 

@@ -29,6 +29,26 @@ from code2vec_tpu.ops.sampled_softmax import sampled_softmax_loss
 from code2vec_tpu.training.optimizers import apply_updates
 
 
+class TrainBatch(tuple):
+    """A training batch's six device arrays, and what the producer's
+    staircase check said of the batch they were put from
+    (`Code2VecModel._train_device_batch`; data/staircase.py): `fits`,
+    by which `make_train_step`'s step picks the program it runs, and
+    `gather_slots`, the slots that program takes table rows for (the
+    `infeed/transfer` span carries it). To jit it is the plain tuple:
+    the marks are the host's."""
+
+    def __new__(cls, arrays, fits: bool, gather_slots: int):
+        self = super().__new__(cls, arrays)
+        self.fits = fits
+        self.gather_slots = gather_slots
+        return self
+
+
+jax.tree_util.register_pytree_node(
+    TrainBatch, lambda b: (tuple(b), None), lambda _, arrays: tuple(arrays))
+
+
 def _weighted_mean(values: jax.Array, weights: jax.Array) -> jax.Array:
     denom = jnp.maximum(jnp.sum(weights), 1.0)
     return jnp.sum(values * weights) / denom
@@ -36,16 +56,19 @@ def _weighted_mean(values: jax.Array, weights: jax.Array) -> jax.Array:
 
 def _make_loss_and_route_fn(dims: ModelDims, *, use_sampled_softmax: bool,
                             num_sampled: int, compute_dtype,
-                            use_pallas: bool, mesh) -> Callable:
+                            use_pallas: bool, mesh,
+                            staircase=None) -> Callable:
     """`fn(params, batch, rng) -> (loss, route counts)`, for
     `value_and_grad(..., has_aux=True)`. The counts are None but for
     the encoder with routed experts (lfm2_moe_encoder.encode_lfm2_moe's
-    third value)."""
+    third value). `staircase`: `encoder.embed_contexts`."""
     if dims.encoder_type == "lfm2_moe":
         from code2vec_tpu.models.lfm2_moe_encoder import encode_lfm2_moe
         encode = functools.partial(encode_lfm2_moe, dims=dims, mesh=mesh)
     else:
         encode = get_encode_fn(dims, mesh)
+    if staircase is not None:
+        encode = functools.partial(encode, staircase=staircase)
 
     def loss_fn(params, batch, rng):
         labels, src, pth, dst, mask, weights = batch
@@ -100,7 +123,8 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
                     sparse_updates: bool = False,
                     learning_rate: float | None = None,
                     sparse_update_fused=None,
-                    sparse_block_rows: int | None = None) -> Callable:
+                    sparse_block_rows: int | None = None,
+                    staircase=None) -> Callable:
     """Returns jitted `step(params, opt_state, batch, rng) ->
     (params, opt_state, loss)` where batch is a 6-tuple of arrays
     (labels [B], src/path/dst ids [B, C], mask [B, C],
@@ -121,7 +145,15 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
     Pallas live-row kernel vs the XLA reference; opt_state must then
     come from sparse_steps.init_sparse_opt_state and `learning_rate`
     names the tables' row-Adam LR. This keeps ONE step-construction
-    entry point for models/jax_model.py and bench.py."""
+    entry point for models/jax_model.py and bench.py.
+
+    With a `staircase` (data/staircase.py; the float step only: the
+    int8 and sparse steps take none) the returned step holds two
+    programs and runs, batch by batch, the one the producer's check
+    chose: the step whose embedding gather and scatter stop at the
+    staircase for a `TrainBatch` that `fits`, this step as it always
+    was for every other batch (a plain tuple among them). Each is
+    compiled when first run; `.lower` is the full step's."""
     if sparse_updates:
         assert augment_fn is None, (
             "sparse_updates has no augmentation hook "
@@ -146,21 +178,44 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
         return _make_quantized_train_step(
             optimizer, make_train_loss_fn(dims, **loss_kw), augment_fn,
             requant_fused, mesh)
-    loss_and_route = _make_loss_and_route_fn(dims, **loss_kw)
     routed = dims.encoder_type == "lfm2_moe"
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def step(params, opt_state, batch, rng):
-        if augment_fn is not None:
-            rng, aug_rng = jax.random.split(rng)
-            batch = augment_fn(batch, aug_rng)
-        (loss, route), grads = jax.value_and_grad(
-            loss_and_route, has_aux=True)(params, batch, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
-        return params, opt_state, (loss, route) if routed else loss
+    def jitted(staircase):
+        loss_and_route = _make_loss_and_route_fn(dims, staircase=staircase,
+                                                 **loss_kw)
 
+        @functools.partial(jax.jit, donate_argnums=(0, 1))
+        def step(params, opt_state, batch, rng):
+            if augment_fn is not None:
+                # a rename keeps PAD where it was: the staircase holds
+                rng, aug_rng = jax.random.split(rng)
+                batch = augment_fn(batch, aug_rng)
+            (loss, route), grads = jax.value_and_grad(
+                loss_and_route, has_aux=True)(params, batch, rng)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
+            return params, opt_state, (loss, route) if routed else loss
+
+        return step
+
+    step = jitted(None)
+    if staircase is not None:
+        step = _by_fit(jitted(staircase), step)
     return _recording_route(step) if routed else step
+
+
+def _by_fit(staircase_step: Callable, full_step: Callable) -> Callable:
+    """One step over two jitted programs, chosen batch by batch by what
+    the producer's check left on the batch (`TrainBatch.fits`); both
+    get the plain tuple, so each is traced once."""
+
+    def step(params, opt_state, batch, rng):
+        run = staircase_step if getattr(batch, "fits", False) else full_step
+        return run(params, opt_state, tuple(batch), rng)
+
+    step.lower = full_step.lower
+    step.full_step, step.staircase_step = full_step, staircase_step
+    return step
 
 
 def _recording_route(step: Callable) -> Callable:

@@ -347,6 +347,31 @@ def test_a_batch_with_no_mask_has_no_pad_count():
     assert [r["attrs"]["pad_slots"] for r in named] == [None, None]
 
 
+def test_infeed_transfer_carries_the_gathered_slots_a_batch_names():
+    """`gather_slots` on `infeed/transfer` (and on the `--trace` span)
+    is what the transferred batch says of itself: the model's training
+    batches do (`training/steps.TrainBatch`), a plain tuple does not."""
+    from code2vec_tpu.obs import SpanChannel, infeed_produce_instrument
+    from code2vec_tpu.training.steps import TrainBatch
+
+    clock, tele = FakeClock(), _Events()
+    marked = _SyncInfeed(FakeReader(clock, 3), lambda b: TrainBatch(
+        (np.zeros(4),), b.i % 2 == 0, 100 + b.i))
+    rec = marked._recorder = MemoryTracer(clock=clock)
+    marked._on_produced = infeed_produce_instrument(Tracer.create(tele),
+                                                    SpanChannel())
+    assert [dev.fits for dev, _host in marked] == [True, False, True]
+    assert [r["attrs"]["gather_slots"]
+            for r in rec.records("infeed/transfer")] == [100, 101, 102]
+    assert [s["attrs"]["gather_slots"] for s in tele.spans] == [100, 101,
+                                                                102]
+    plain = _SyncInfeed(FakeReader(clock, 2), fake_put_fn(clock))
+    rec = plain._recorder = MemoryTracer(clock=clock)
+    list(plain)
+    assert all("gather_slots" not in r["attrs"]
+               for r in rec.records("infeed/transfer"))
+
+
 # ---- the production record, on its threads ------------------------------
 
 @pytest.mark.parametrize("kind", ["per_batch", "chunked"])

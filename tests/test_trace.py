@@ -329,6 +329,23 @@ def test_trace_report_prints_the_pad_slots(traced_train_run):
         for s in produced])])
 
 
+def test_trace_report_prints_the_gathered_slots_beside_them(
+        traced_train_run):
+    """A text corpus gets no staircase: every slot of every batch is
+    gathered, and the report says so from the producer's own count."""
+    from tools.trace_report import pad_slot_summary, render
+    pad = pad_slot_summary(traced_train_run)
+    assert pad["gather_slots"] == pad["rows"] * 16
+    text = render([({"config": {"MAX_CONTEXTS": 16}}, traced_train_run)])
+    assert f"Gathered slots: {pad['gather_slots']:,} (100.00%)" in text
+    produced = [s for s in traced_train_run
+                if s["name"] == "infeed/produce"]
+    assert "Gathered slots" not in render([({}, [
+        dict(s, attrs={k: v for k, v in s["attrs"].items()
+                       if k != "gather_slots"})
+        for s in produced])])
+
+
 def test_breakdown_primary_and_linked_requests_agree():
     """Regression: the flush's encode/device children share the
     PRIMARY request's trace id — they must be attributed through the

@@ -31,7 +31,9 @@ emits) and produces:
   - per-step table: infeed_wait / step / save_blocked (+ the writer's
     save_write wall) from the `train/step_cycle` traces, and the
     batches' PAD slots (`pad_slots` of `infeed/produce`: how often the
-    embedding gather spreads a PAD read), and for a routed-experts
+    embedding gather spreads a PAD read) beside their gathered slots
+    (`gather_slots`: what the step chosen for each batch took table
+    rows for, the staircase's area or every slot), and for a routed-experts
     encoder the `moe/route` records: rows routed to the experts held
     here over the valid tokens' choices, and the fullest expert's rows
     over the mean.
@@ -319,15 +321,20 @@ def pad_slot_summary(spans: Sequence[Dict[str, Any]]
                      ) -> Optional[Dict[str, int]]:
     """PAD slots, rows and batches over the run's `infeed/produce`
     spans that carry a `pad_slots` count (how often the embedding
-    gather spreads a PAD read); None when none does."""
+    gather spreads a PAD read); None when none does. `gather_slots`
+    beside them where every counted batch carries one: the slots the
+    steps took table rows for."""
     counted = [a for a in ((s.get("attrs") or {}) for s in spans
                            if s["name"] == "infeed/produce")
                if a.get("pad_slots") is not None and a.get("rows")]
     if not counted:
         return None
-    return {"batches": len(counted),
-            "rows": sum(a["rows"] for a in counted),
-            "pad_slots": sum(a["pad_slots"] for a in counted)}
+    out = {"batches": len(counted),
+           "rows": sum(a["rows"] for a in counted),
+           "pad_slots": sum(a["pad_slots"] for a in counted)}
+    if all(a.get("gather_slots") is not None for a in counted):
+        out["gather_slots"] = sum(a["gather_slots"] for a in counted)
+    return out
 
 
 def route_summary(spans: Sequence[Dict[str, Any]]
@@ -454,6 +461,14 @@ def render(loaded, limit: int = 10) -> str:
             lines.append(f"PAD slots: {pad['pad_slots']:,} in "
                          f"{pad['rows']:,} rows of {pad['batches']} "
                          f"batches{share}")
+            if "gather_slots" in pad:
+                share = ""
+                if contexts:
+                    percent = 100.0 * pad["gather_slots"] / (
+                        pad["rows"] * contexts)
+                    share = f" ({percent:.2f}%)"
+                lines.append(f"Gathered slots: {pad['gather_slots']:,}"
+                             f"{share}")
         route = route_summary(spans)
         if route:
             lines.append("")
