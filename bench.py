@@ -37,14 +37,6 @@ Extra keys:
     its roofline). int8_hbm_gbps uses the quantized-carrier-aware
     traffic model (bf16 [V, E] grad carrier + int8 q / f32 s r+w).
 
-  - phase_*: the per-phase breakdown of the sparse step (ISSUE 15) —
-    the training/phase_probes.py chain slope-timed and differenced
-    (embed_gather / concat_dense / forward_pool / backward, table_apply
-    as the fused remainder), each with analytic bytes + utilization vs
-    the ceiling. tools/bench_regression.py gates every phase's ms
-    (LOWER_IS_BETTER) so a single-phase regression cannot hide behind
-    a steady headline.
-
 Baseline denominator: derived, methodology-documented single-V100
 estimate of the reference step (fp32, full softmax, dense Adam, input
 pipeline assumed free — every assumption favoring the reference):
@@ -337,70 +329,6 @@ def _measure_sparse_step():
             floor_bytes / dt / 1e9, floor_bytes)
 
 
-def _measure_phase_breakdown(sparse_step_ms: float, ceiling: float):
-    """Slope-time the sparse-config probe chain
-    (training/phase_probes.py — the SAME cumulative prefixes the
-    in-train sampler dispatches, so the bench breakdown and the live
-    `train/phase/*` timers can never measure different math) and
-    difference it into the per-phase attribution (ISSUE 15):
-    embed_gather / concat_dense / forward_pool / backward, with
-    table_apply = the measured full sparse step minus the chain tail
-    (the fused remainder — the sampled path's rule). Each phase also
-    reports its analytic bytes (sparse_update.phase_traffic_bytes) and
-    utilization vs the streaming ceiling, so tools/bench_regression.py
-    can gate each phase's ms (LOWER_IS_BETTER) instead of only the
-    headline pc/s. Returns the `phase_*` result keys."""
-    import jax
-    import jax.numpy as jnp
-
-    from code2vec_tpu.models.encoder import init_params
-    from code2vec_tpu.obs.phases import derive_chain_phases
-    from code2vec_tpu.training.phase_probes import make_code2vec_probes
-    from code2vec_tpu.training.sparse_update import phase_traffic_bytes
-
-    dims = _java_large_dims()
-    params = init_params(jax.random.PRNGKey(0), dims)
-    kit = make_code2vec_probes(dims, None, use_sampled_softmax=True,
-                               num_sampled=NUM_SAMPLED,
-                               compute_dtype=jnp.bfloat16,
-                               sparse_updates=True)
-    batches = _device_batches()
-    names, cum = [], []
-    for name, fn in kit.chain:
-        def chain(n, rng, fn=fn):
-            rng, sub = jax.random.split(rng)
-            keys = list(jax.random.split(sub, max(n, 1)))
-            out = None
-            t0 = time.perf_counter()
-            for i in range(n):
-                out = fn(params, batches[i % len(batches)], keys[i])
-            # hard sync via a scalar host transfer (slope contract;
-            # ravel handles the forward probe's 0-d loss)
-            leaf = jax.tree_util.tree_leaves(out)[0]
-            float(jnp.sum(leaf.ravel()[:1].astype(jnp.float32)))
-            return time.perf_counter() - t0, rng
-
-        dt = max(_slope_time(chain, jax.random.PRNGKey(11)), 0.0)
-        names.append(name)
-        cum.append(dt * 1e3)
-    phases = dict(derive_chain_phases(names, cum))
-    phases["table_apply"] = max(0.0, sparse_step_ms - cum[-1])
-    nbytes = phase_traffic_bytes(params, BATCH, MAX_CONTEXTS,
-                                 num_sampled=NUM_SAMPLED, sparse=True)
-    out = {}
-    for name, ms in phases.items():
-        out[f"phase_{name}_ms"] = round(ms, 3)
-        nb = nbytes.get(name)
-        if nb:
-            out[f"phase_{name}_bytes"] = int(nb)
-            if ms > 0:
-                gbps = nb / (ms / 1e3) / 1e9
-                out[f"phase_{name}_vs_ceiling"] = round(
-                    gbps / (ceiling / 1e9), 3)
-    out["phase_sum_ms"] = round(cum[-1] + phases["table_apply"], 3)
-    return out
-
-
 def _measure_requant_phase():
     """Slope-time the int8 requantize apply ALONE over the two
     quantized tables (the fused Pallas row-pass on TPU, the XLA
@@ -556,14 +484,9 @@ def main(argv=None) -> None:
     su_ms, su_bytes, su_rows, su_fused = _measure_sparse_update_phase()
     su_gbps = su_bytes / (su_ms / 1e3) / 1e9
     _live(sparse_update_ms=su_ms, phases_done=7)
-    # per-phase breakdown of the sparse step (ISSUE 15): the full
-    # attribution table every round, so bench_regression gates each
-    # phase's ms instead of only the headline pc/s
-    phase_keys = _measure_phase_breakdown(sp_ms, ceiling)
-    _live(phases_done=8, **phase_keys)
     xf_value, xf_ms, xf_hbm = _measure_encoder("transformer")
     _live(transformer_pc_per_sec=xf_value,
-          transformer_ms_per_step=xf_ms, phases_done=9)
+          transformer_ms_per_step=xf_ms, phases_done=8)
     result = {
         "metric": "path-contexts/sec/chip",
         "value": round(value, 1),
@@ -631,16 +554,6 @@ def main(argv=None) -> None:
             su_gbps / (ceiling / 1e9), 3),
         "sparse_update_unique_rows": int(su_rows),
         "sparse_update_fused": su_fused,
-        # per-phase breakdown of the sparse step (ISSUE 15): the
-        # slope-timed probe chain (training/phase_probes.py — the same
-        # prefixes --phase_profile samples in-train) differenced into
-        # embed_gather / concat_dense / forward_pool / backward ms,
-        # table_apply as the fused remainder, each with its analytic
-        # bytes + utilization vs the streaming ceiling. Gated
-        # LOWER_IS_BETTER by tools/bench_regression.py so a single
-        # phase regressing hides behind neither the headline nor
-        # another phase's win.
-        **phase_keys,
         "transformer_pc_per_sec": round(xf_value, 1),
         "transformer_ms_per_step": round(xf_ms, 2),
         "transformer_hbm_gbps": round(xf_hbm, 1),

@@ -71,8 +71,8 @@ class RarityDetector:
         @jax.jit
         def attn_fn(params, src, pth, dst, mask):
             # batched [M, C]: one dispatch scores a whole sweep chunk
-            _, attn = encode(params, src, pth, dst, mask,
-                             compute_dtype=compute_dtype)
+            _, attn, _ = encode(params, src, pth, dst, mask,
+                                compute_dtype=compute_dtype)
             return attn
 
         self._attn_fn = attn_fn

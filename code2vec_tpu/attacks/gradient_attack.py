@@ -262,8 +262,8 @@ def _raw_attack_steps(dims: ModelDims, *, compute_dtype=jnp.float32):
     encode = get_encode_fn(dims)
 
     def _loss_from_params(params, src, pth, dst, mask, label):
-        code, _ = encode(params, src[None], pth[None], dst[None],
-                         mask[None], compute_dtype=compute_dtype)
+        code, _, _ = encode(params, src[None], pth[None], dst[None],
+                            mask[None], compute_dtype=compute_dtype)
         logits = full_logits(params, code, dims.target_vocab_size)
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, label[None])[0]
@@ -303,8 +303,8 @@ def _raw_attack_steps(dims: ModelDims, *, compute_dtype=jnp.float32):
         dstK = jnp.where(occ_dst[None, :], cand_ids[:, None], dst[None, :])
         pthK = jnp.broadcast_to(pth[None, :], (K, pth.shape[0]))
         maskK = jnp.broadcast_to(mask[None, :], (K, mask.shape[0]))
-        code, _ = encode(params, srcK, pthK, dstK, maskK,
-                         compute_dtype=compute_dtype)
+        code, _, _ = encode(params, srcK, pthK, dstK, maskK,
+                            compute_dtype=compute_dtype)
         logits = full_logits(params, code, dims.target_vocab_size)
         labels = jnp.full((K,), label, dtype=jnp.int32)
         loss = optax.softmax_cross_entropy_with_integer_labels(
@@ -314,8 +314,8 @@ def _raw_attack_steps(dims: ModelDims, *, compute_dtype=jnp.float32):
 
     def predict_fn(params, ids):
         src, pth, dst, mask = ids
-        code, _ = encode(params, src[None], pth[None], dst[None],
-                         mask[None], compute_dtype=compute_dtype)
+        code, _, _ = encode(params, src[None], pth[None], dst[None],
+                            mask[None], compute_dtype=compute_dtype)
         logits = full_logits(params, code, dims.target_vocab_size)
         return jnp.argmax(logits[0])
 

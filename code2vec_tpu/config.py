@@ -18,6 +18,9 @@ import os
 import sys
 from typing import Optional
 
+from code2vec_tpu.models.registry import names as encoder_names
+from code2vec_tpu.models.registry import spec as encoder_spec
+
 
 # Config attrs with NO CLI flag by design: capacity/architecture
 # constants (reference parity values a flag would invite mis-tuning
@@ -235,17 +238,18 @@ class Config:
     # death/refill still applies either way).
     SERVE_AUTOSCALE: bool = False
 
-    # ---- encoder architecture: "bag" (reference parity),
-    # "transformer" (set transformer over the contexts,
-    # models/transformer_encoder.py; BASELINE.json configs[4]) or
-    # "lfm2_moe" (the LFM2-MoE decoder block over the contexts in
-    # reader order, models/lfm2_moe_encoder.py). ----
+    # ---- encoder architecture, a name of models/registry.py: "bag"
+    # (reference parity), "transformer" (set transformer over the
+    # contexts, models/transformer_encoder.py; BASELINE.json
+    # configs[4]) or "lfm2_moe" (the LFM2-MoE decoder block over the
+    # contexts in reader order, models/lfm2_moe_encoder.py). ----
     ENCODER_TYPE: str = "bag"
     # lfm2_moe: the block's sizes, a JSON file under the keys of the
     # model's own config.json (layer_types, hidden_size, num_experts =
     # the experts held HERE, num_routed_experts, first_expert, ...;
-    # models/encoder.Lfm2Dims). benchmark/configs/java-large-lfm2moe.json
-    # is one chip's share of LFM2-24B-A2B.
+    # models/lfm2_moe_encoder.Lfm2Dims).
+    # benchmark/configs/java-large-lfm2moe.json is one chip's share of
+    # LFM2-24B-A2B.
     LFM_CONFIG: Optional[str] = None
     XF_LAYERS: int = 2
     # 3 heads -> head_dim = 384/3 = 128 = one MXU lane width: measured
@@ -374,18 +378,6 @@ class Config:
     # sweep never touches the hot path either way).
     HEALTH_EVERY_S: float = 1.0
 
-    # ---- sampled phase attribution (code2vec_tpu/obs/phases.py,
-    # ISSUE 15): --phase_profile on dispatches one step in every
-    # --phase_sample_every through a phase-split path (each phase its
-    # own synced dispatch over the training/phase_probes.py prefixes;
-    # the state update stays the fused dispatch, so the trajectory is
-    # bit-identical to an unprofiled run) and publishes per-phase
-    # `train/phase/<p>_ms` timers + live `health/phase_*` roofline
-    # gauges. Off (default): one boolean check per step. Needs a live
-    # registry: --telemetry_dir or --metrics_port.
-    PHASE_PROFILE: str = "off"   # "off" | "on"
-    PHASE_SAMPLE_EVERY: int = 64
-
     # ---- deterministic fault injection (code2vec_tpu/resilience/,
     # ISSUE 10): --faults <file-or-inline-json> arms the seeded
     # failpoint registry (sites: ckpt/write, infeed/produce,
@@ -451,10 +443,10 @@ class Config:
 
     @property
     def eval_batch_size(self) -> int:
-        """Methods per evaluation batch. The lfm2_moe block's batch is
-        what memory allows (a 2048-wide layer over every slot), so it
+        """Methods per evaluation batch. An encoder whose batch is what
+        memory allows (lfm2_moe: a 2048-wide layer over every slot)
         evaluates at no more than the training batch (--batch_size)."""
-        if self.ENCODER_TYPE == "lfm2_moe":
+        if encoder_spec(self.ENCODER_TYPE).eval_batch_at_most_train:
             return min(self.TEST_BATCH_SIZE, self.TRAIN_BATCH_SIZE)
         return self.TEST_BATCH_SIZE
 
@@ -545,7 +537,7 @@ class Config:
                        action="store_true")
         p.add_argument("--num_sampled", dest="num_sampled", type=int, default=None)
         p.add_argument("--encoder", dest="encoder", default=None,
-                       choices=["bag", "transformer", "lfm2_moe"])
+                       choices=list(encoder_names()))
         p.add_argument("--lfm_config", dest="lfm_config", default=None,
                        help="--encoder lfm2_moe: JSON file with the "
                             "block's sizes under the model's config.json "
@@ -670,21 +662,6 @@ class Config:
                        help="JSON rule file replacing the built-in "
                             "alert rules (threshold + multi-window "
                             "burn-rate; see README)")
-        p.add_argument("--phase_profile", dest="phase_profile",
-                       default=None, choices=["off", "on"],
-                       help="sampled per-phase device timing: every "
-                            "--phase_sample_every steps one step runs "
-                            "phase-split (synced per-phase dispatches; "
-                            "the state update stays the fused step) "
-                            "and publishes train/phase/* timers + "
-                            "health_phase_* roofline gauges (needs "
-                            "--telemetry_dir or --metrics_port)")
-        p.add_argument("--phase_sample_every",
-                       dest="phase_sample_every", type=int,
-                       default=None,
-                       help="steps between phase-split samples "
-                            "(default 64; the non-sampled hot path is "
-                            "untouched)")
         p.add_argument("--serve_batch_max", dest="serve_batch_max",
                        type=int, default=None,
                        help="max methods per coalesced serving batch "
@@ -898,10 +875,6 @@ class Config:
             cfg.ALERTS_MODE = ns.alerts_mode
         if ns.alerts_rules is not None:
             cfg.ALERTS_RULES = ns.alerts_rules
-        if ns.phase_profile is not None:
-            cfg.PHASE_PROFILE = ns.phase_profile
-        if ns.phase_sample_every is not None:
-            cfg.PHASE_SAMPLE_EVERY = ns.phase_sample_every
         if ns.serve_batch_max is not None:
             cfg.SERVE_BATCH_MAX = ns.serve_batch_max
         if ns.serve_batch_timeout_ms is not None:
@@ -957,6 +930,8 @@ class Config:
 
     def verify(self) -> None:
         """Validate flag combinations (reference `Config.verify`)."""
+        # an unknown name is refused here, with the names there are
+        encoder = encoder_spec(self.ENCODER_TYPE)
         if self.DL_FRAMEWORK not in ("jax", "tensorflow", "keras"):
             raise ValueError(
                 f"--framework {self.DL_FRAMEWORK!r} unknown (expected "
@@ -1010,11 +985,10 @@ class Config:
             # the token/path tables as plain arrays (transformer/vm
             # gathers, attack matvec, LAMB's ||param||) or shard by flat
             # key (mesh rules) and would need the dequantized view.
-            if self.ENCODER_TYPE != "bag":
+            if not encoder.table_step_variants:
                 raise ValueError(
                     "--tables_dtype int8 supports the bag encoder only "
-                    "(the transformer and lfm2_moe encoders gather the "
-                    "tables directly).")
+                    "(the int8 step is written for it).")
             if self.HEAD != "code2vec":
                 raise ValueError(
                     "--tables_dtype int8 supports the code2vec head "
@@ -1107,18 +1081,6 @@ class Config:
                 "would be silently ignored.")
         if self.HEALTH_EVERY_S <= 0:
             raise ValueError("HEALTH_EVERY_S must be positive.")
-        if self.PHASE_PROFILE not in ("off", "on"):
-            raise ValueError(
-                "--phase_profile must be off or on "
-                f"(got {self.PHASE_PROFILE!r}).")
-        if self.PHASE_SAMPLE_EVERY < 1:
-            raise ValueError("--phase_sample_every must be >= 1.")
-        if self.PHASE_PROFILE == "on" and not self.TELEMETRY_DIR \
-                and self.METRICS_PORT <= 0:
-            raise ValueError(
-                "--phase_profile on needs a live registry: pass "
-                "--telemetry_dir (persisted phase events) or "
-                "--metrics_port (in-memory, scrape-only).")
         if self.LR_WARMUP_STEPS < 0:
             raise ValueError("--warmup_steps must be >= 0.")
         if self.INFEED_PREFETCH < 0:
@@ -1155,14 +1117,14 @@ class Config:
                 "SPARSE_EMBEDDING_UPDATES supports constant LR only "
                 "(sparse_steps.py applies a fixed per-row learning "
                 "rate).")
-        if self.SPARSE_EMBEDDING_UPDATES and self.ENCODER_TYPE != "bag":
+        if self.SPARSE_EMBEDDING_UPDATES \
+                and not encoder.table_step_variants:
             # sparse_steps hard-codes the bag attention pool and would
-            # silently leave transformer params untrained while eval runs
-            # them — a train/eval architecture mismatch.
+            # silently leave an encoder's own params untrained while
+            # eval runs them — a train/eval architecture mismatch.
             raise ValueError(
                 "SPARSE_EMBEDDING_UPDATES supports the bag encoder only "
-                "(sparse_steps.py trains no transformer or lfm2_moe "
-                "params).")
+                "(sparse_steps.py trains no encoder's own params).")
         if not 0.0 <= self.ADV_RENAME_PROB <= 1.0:
             raise ValueError("--adv_rename_prob must be in [0, 1].")
         if self.ADV_RENAME_PROB > 0 and self.SPARSE_EMBEDDING_UPDATES:
@@ -1182,25 +1144,15 @@ class Config:
         if self.ATTACK and self.HEAD == "varmisuse":
             raise ValueError(
                 "--attack applies to the code2vec head only.")
-        if self.HEAD == "varmisuse" and (self.ENCODER_TYPE != "bag"
+        if self.HEAD == "varmisuse" and (not encoder.table_step_variants
                                          or self.MESH_CONTEXT_AXIS > 1):
             # vm_scores calls the bag encode() directly; accepting
-            # --encoder transformer here would silently train the wrong
+            # another --encoder here would silently train the wrong
             # architecture.
             raise ValueError(
                 "--head varmisuse supports the bag encoder only "
-                "(no --encoder transformer or lfm2_moe / "
-                "--mesh_context > 1).")
-        if self.ENCODER_TYPE == "lfm2_moe":
-            if self.RING_ATTENTION or self.MESH_CONTEXT_AXIS > 1:
-                raise ValueError(
-                    "--encoder lfm2_moe has no ring attention and no "
-                    "context-parallel layout (its causal convolution and "
-                    "mask run over whole sequences).")
-            if not self.LFM_CONFIG and not self.is_loading:
-                raise ValueError(
-                    "--encoder lfm2_moe needs --lfm_config <json> (the "
-                    "block's sizes; a checkpoint carries its own).")
+                "(no other --encoder / --mesh_context > 1).")
+        encoder.check_config(self)
 
     def get_logger(self) -> logging.Logger:
         if self._logger is None:

@@ -1,2 +1,2 @@
-from code2vec_tpu.models.encoder import (  # noqa: F401
-    ModelDims, init_params, encode, full_logits)
+# Nothing is imported here: `code2vec_tpu.models.registry` is read by
+# config.py, which stays free of jax at import time.

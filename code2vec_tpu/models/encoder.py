@@ -21,93 +21,19 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from code2vec_tpu.models.registry import EncoderSpec, spec
 from code2vec_tpu.ops.attention import attention_pool
 
 Params = Dict[str, jax.Array]
 
 # `vocab.vocabularies.Vocab` reserves index 0 for PAD in every table
 PAD_ID = 0
-
-
-@dataclasses.dataclass(frozen=True)
-class Lfm2Dims:
-    """The LFM2-MoE block's sizes (models/lfm2_moe_encoder.py), under
-    the keys of the model's own `config.json` (`model_type`
-    `lfm2_moe`); every one comes from the file `--lfm_config` names.
-    `num_experts` counts the experts whose weights THIS process holds,
-    `first_expert` the first of them, and `num_routed_experts` the
-    router's width (None: all are held here, as the published file
-    means it)."""
-    layer_types: Tuple[str, ...]
-    num_dense_layers: int
-    hidden_size: int
-    intermediate_size: int
-    moe_intermediate_size: int
-    num_attention_heads: int
-    num_key_value_heads: int
-    num_experts: int
-    num_experts_per_tok: int
-    conv_L_cache: int
-    norm_eps: float
-    rope_theta: float
-    num_routed_experts: Optional[int] = None
-    first_expert: int = 0
-
-    @property
-    def routed(self) -> int:
-        return self.num_routed_experts or self.num_experts
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
-
-    @classmethod
-    def from_config(cls, config: dict) -> "Lfm2Dims":
-        """From a parsed `config.json`. Keys the block does not read
-        are passed over; a switch the block does not implement is an
-        error, not a silent default."""
-        fixed = {"norm_topk_prob": True, "use_expert_bias": True,
-                 "conv_bias": False, "routed_scaling_factor": 1}
-        for k, want in fixed.items():
-            if config.get(k, want) != want:
-                raise ValueError(f"lfm2_moe implements {k}={want!r} only "
-                                 f"(the file gives {config[k]!r})")
-        kw = {f.name: config[f.name] for f in dataclasses.fields(cls)
-              if f.name in config}
-        if "rope_parameters" in config:
-            kw["rope_theta"] = float(config["rope_parameters"]["rope_theta"])
-        missing = [f.name for f in dataclasses.fields(cls)
-                   if f.default is dataclasses.MISSING and f.name not in kw]
-        if missing:
-            raise ValueError(f"lfm2_moe: the block's file lacks {missing}")
-        kw["layer_types"] = tuple(kw["layer_types"])
-        dims = cls(**kw)
-        dims.check()
-        return dims
-
-    def check(self) -> None:
-        bad = set(self.layer_types) - {"conv", "full_attention"}
-        if bad or not self.layer_types:
-            raise ValueError(f"lfm2_moe layer_types {sorted(bad)} unknown "
-                             "(conv, full_attention)")
-        if self.hidden_size % self.num_attention_heads or \
-                self.num_attention_heads % self.num_key_value_heads or \
-                self.head_dim % 2:
-            raise ValueError("lfm2_moe: heads must divide hidden_size, "
-                             "kv heads the heads, and a head be even")
-        if not (0 <= self.first_expert
-                and self.first_expert + self.num_experts <= self.routed
-                and self.num_experts_per_tok <= self.routed):
-            raise ValueError(
-                f"lfm2_moe: experts {self.first_expert}.."
-                f"{self.first_expert + self.num_experts - 1} held of "
-                f"{self.routed} routed, {self.num_experts_per_tok} a token")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,11 +58,8 @@ class ModelDims:
     # bf16 (the sampled-softmax head matmuls against it).
     # TRANSFORM/ATTENTION always stay f32.
     tables_dtype: str = "float32"
-    # Encoder architecture: "bag" (the reference's single-query
-    # attention pool), "transformer" (set transformer over the
-    # contexts, models/transformer_encoder.py; BASELINE.json configs[4])
-    # or "lfm2_moe" (the LFM2-MoE decoder block over the contexts in
-    # reader order, models/lfm2_moe_encoder.py; its sizes in `lfm`).
+    # Encoder architecture, a name of models/registry.py: "bag" is the
+    # reference's single-query attention pool (this module).
     encoder_type: str = "bag"
     xf_layers: int = 2
     # 3 -> head_dim 384/3 = 128 = one MXU lane width (shipped default,
@@ -155,7 +78,8 @@ class ModelDims:
     # sequence parallelism. Takes effect only when the mesh's ctx axis
     # is > 1 (numerically exact either way).
     ring_attention: bool = False
-    lfm: Optional[Lfm2Dims] = None
+    # lfm2_moe's own sizes (models/lfm2_moe_encoder.Lfm2Dims)
+    lfm: Optional[Any] = None
 
     @property
     def context_vector_size(self) -> int:
@@ -200,14 +124,9 @@ def init_params(rng: jax.Array, dims: ModelDims,
                                             quantize_table)
         for k in QUANTIZED_TABLE_KEYS:
             params[k] = quantize_table(params[k])
-    if dims.encoder_type == "transformer":
-        from code2vec_tpu.models.transformer_encoder import init_xf_params
-        params["xf"] = init_xf_params(
-            jax.random.fold_in(rng, 0x5f), dims)
-    if dims.encoder_type == "lfm2_moe":
-        from code2vec_tpu.models.lfm2_moe_encoder import init_lfm_params
-        params["lfm"] = init_lfm_params(
-            jax.random.fold_in(rng, 0x1f2), dims)
+    encoder = spec(dims.encoder_type)
+    if encoder.params_key is not None:
+        params[encoder.params_key] = encoder.init(rng, dims)
     return params
 
 
@@ -383,16 +302,20 @@ def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,
            dropout_keep_rate: float = 1.0,
            compute_dtype=jnp.float32,
            use_pallas: bool = False,
-           mesh=None, staircase=None) -> Tuple[jax.Array, jax.Array]:
-    """Forward to the code vector.
+           mesh=None, staircase=None, dims: Optional[ModelDims] = None
+           ) -> Tuple[jax.Array, jax.Array, None]:
+    """Forward to the code vector: the bag encoder, under the one
+    encode contract (registry.EncoderSpec).
 
     Args: [B, C] int32 ids for source token / path / target token, [B, C]
     f32 mask. Returns (code_vectors [B, D] in compute dtype,
-    attention [B, C] f32). use_pallas selects the fused Pallas pooling
+    attention [B, C] f32, None: it hands the step nothing beside the
+    loss). use_pallas selects the fused Pallas pooling
     kernel (ops/pallas_attention.py); inside a step partitioned over
     `mesh` each device runs it on its own batch rows. `staircase`:
-    `embed_contexts`.
+    `embed_contexts`. `dims` is taken and not read: the shapes say all.
     """
+    del dims
     contexts = embed_contexts(params, source_ids, path_ids, target_ids,
                               dropout_rng, dropout_keep_rate, compute_dtype,
                               staircase, mesh)
@@ -409,30 +332,19 @@ def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,
                                             (True, False, False, True))
             code, attn = pool(contexts, params["transform"],
                               params["attention"], mask)
-            return code.astype(compute_dtype), attn
-        return attention_pool(contexts, params["transform"],
-                              params["attention"], mask)
+            return code.astype(compute_dtype), attn, None
+        return (*attention_pool(contexts, params["transform"],
+                                params["attention"], mask), None)
 
 
 def get_encode_fn(dims: ModelDims, mesh=None):
-    """The encode callable for dims.encoder_type (same signature as
-    `encode`); the jitted steps in training/steps.py close over it.
-    `mesh` places the Pallas kernels per device (and feeds the
-    transformer's ring-attention path: dims.ring_attention with a ctx
-    axis > 1)."""
-    if dims.encoder_type == "transformer":
-        from code2vec_tpu.models.transformer_encoder import (
-            encode_transformer)
-        return functools.partial(encode_transformer, dims=dims,
-                                 mesh=mesh)
-    if dims.encoder_type == "lfm2_moe":
-        from code2vec_tpu.models.lfm2_moe_encoder import encode_lfm2_moe
-
-        def encode_lfm(*args, **kw):
-            # its third value, the route counts, is the train step's
-            return encode_lfm2_moe(*args, dims=dims, mesh=mesh, **kw)[:2]
-        return encode_lfm
-    return functools.partial(encode, mesh=mesh)
+    """`dims.encoder_type`'s `encode` (registry.EncoderSpec) with
+    `dims` and `mesh` bound; the jitted steps in training/steps.py
+    close over it. `mesh` places the Pallas kernels per device (and
+    feeds the transformer's ring-attention path: dims.ring_attention
+    with a ctx axis > 1)."""
+    return functools.partial(spec(dims.encoder_type).encode, dims=dims,
+                             mesh=mesh)
 
 
 def logits_vs_table(table: jax.Array, code_vectors: jax.Array,
@@ -454,3 +366,6 @@ def full_logits(params: Params, code_vectors: jax.Array,
                 true_target_vocab_size: Optional[int] = None) -> jax.Array:
     return logits_vs_table(params["target_emb"], code_vectors,
                            true_target_vocab_size)
+
+
+SPEC = EncoderSpec(encode=encode, table_step_variants=True)

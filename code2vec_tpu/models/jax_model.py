@@ -27,6 +27,7 @@ from code2vec_tpu.data.reader import (BatchTensors, BinaryShardReader,
                                       _pad_batch, open_reader,
                                       parse_c2v_rows)
 from code2vec_tpu.models.encoder import PAD_ID, ModelDims, init_params
+from code2vec_tpu.models.registry import spec as encoder_spec
 from code2vec_tpu.models.model_base import Code2VecModelBase, MetricAccumulator
 from code2vec_tpu.parallel.distributed import fetch_global
 from code2vec_tpu.parallel.mesh import (CONTEXT_AXIS, DATA_AXIS, DCN_AXIS,
@@ -154,7 +155,8 @@ class Code2VecModel(Code2VecModelBase):
                 xf_heads=cfg.XF_HEADS,
                 xf_remat=cfg.XF_REMAT,
                 ring_attention=cfg.RING_ATTENTION,
-                lfm=self._lfm_dims(),
+                # the encoder's own sizes, read by its spec
+                **encoder_spec(cfg.ENCODER_TYPE).sizes_from_config(cfg),
             )
         if self.dims.tables_dtype == "int8" and self.mesh is not None:
             # data-parallel meshes replicate the quantized tables and
@@ -280,18 +282,6 @@ class Code2VecModel(Code2VecModelBase):
         self._predict_step = make_predict_step(
             self.dims, top_k=top_k, compute_dtype=self.compute_dtype,
             use_pallas=self.use_pallas, mesh=self.mesh)
-
-    def _lfm_dims(self):
-        """The LFM2-MoE block's sizes from `--lfm_config` (None for the
-        other encoders)."""
-        cfg = self.config
-        if cfg.ENCODER_TYPE != "lfm2_moe":
-            return None
-        import json
-
-        from code2vec_tpu.models.encoder import Lfm2Dims
-        with open(cfg.LFM_CONFIG) as f:
-            return Lfm2Dims.from_config(json.load(f))
 
     # ---- vocabs: dataset dict when training, checkpoint sidecar when
     # loading (SURVEY.md §3.2 "Model checkpoint") ----
@@ -515,17 +505,6 @@ class Code2VecModel(Code2VecModelBase):
                         emit=False, static=True)
         model_shards = 1 if self.mesh is None else \
             int(self.mesh.shape.get(MODEL_AXIS, 1))
-        # shared analytic-model inputs (the floor gauges below AND the
-        # phase comparator): derived once so the two planes cannot
-        # disagree about the same quantity
-        ns = cfg.NUM_SAMPLED_CLASSES if cfg.USE_SAMPLED_SOFTMAX else 0
-        if self.mesh is None:
-            data_shards = 1
-        else:
-            data_shards = max(1, int(
-                self.mesh.shape.get(DCN_AXIS, 1)
-                * self.mesh.shape.get(DATA_AXIS, 1)))
-        procs = jax.process_count()
         # the floors divide bytes by this chip's published HBM peak;
         # a device_kind the table does not list gets no floor gauge
         peak_gbps = device.hbm_peak_gbps()
@@ -554,6 +533,11 @@ class Code2VecModel(Code2VecModelBase):
             # reading false-good/bad.)
             from code2vec_tpu.training.sparse_update import (
                 sparse_step_floor_bytes, sparse_update_phase_bytes)
+            ns = cfg.NUM_SAMPLED_CLASSES if cfg.USE_SAMPLED_SOFTMAX else 0
+            data_shards = 1 if self.mesh is None else max(1, int(
+                self.mesh.shape.get(DCN_AXIS, 1)
+                * self.mesh.shape.get(DATA_AXIS, 1)))
+            procs = jax.process_count()
             step_bytes = sparse_step_floor_bytes(
                 self.params, cfg.TRAIN_BATCH_SIZE, cfg.MAX_CONTEXTS,
                 num_sampled=ns, data_shards=data_shards,
@@ -570,44 +554,6 @@ class Code2VecModel(Code2VecModelBase):
             telemetry.gauge("train/sparse_update_floor_ms",
                             upd_bytes / ceiling * 1e3, emit=False,
                             static=True)
-        # sampled phase attribution (--phase_profile, ISSUE 15): every
-        # PHASE_SAMPLE_EVERY steps one step dispatches phase-split
-        # (synced probe prefixes for attribution, the fused step for
-        # the state update — trajectory bit-identical to unprofiled);
-        # off, the loop pays one boolean check per step. Probes build
-        # + warm lazily at the first sampled step.
-        from code2vec_tpu.obs.phases import PhaseProfiler
-        phase_kw = {}
-        if cfg.PHASE_PROFILE == "on" and telemetry.enabled \
-                and model_shards == 1 and peak_gbps is not None:
-            # the analytic per-phase comparator (model-sharded tables
-            # are not described by it — same rule as the floor gauges
-            # above: no gauge beats a false one)
-            from code2vec_tpu.training.sparse_update import \
-                phase_traffic_bytes
-            phase_kw["phase_bytes"] = phase_traffic_bytes(
-                self.params, cfg.TRAIN_BATCH_SIZE, cfg.MAX_CONTEXTS,
-                num_sampled=ns, sparse=cfg.SPARSE_EMBEDDING_UPDATES,
-                data_shards=data_shards, processes=procs)
-            phase_kw["ceiling_gbps"] = peak_gbps
-
-        def _phase_probes():
-            from code2vec_tpu.training.phase_probes import \
-                make_code2vec_probes
-            return make_code2vec_probes(
-                self.dims, self.optimizer,
-                use_sampled_softmax=cfg.USE_SAMPLED_SOFTMAX,
-                num_sampled=cfg.NUM_SAMPLED_CLASSES,
-                compute_dtype=self.compute_dtype,
-                use_pallas=self.use_pallas, mesh=self.mesh,
-                sparse_updates=cfg.SPARSE_EMBEDDING_UPDATES)
-
-        phase_profiler = PhaseProfiler.create(
-            telemetry, fused_step=self._train_step,
-            probes_factory=_phase_probes,
-            enabled=cfg.PHASE_PROFILE == "on",
-            sample_every=cfg.PHASE_SAMPLE_EVERY, log=self.log,
-            **phase_kw)
         loop_hb.busy()  # the first deadline covers step-0 compile too
         steps_into_training = 0
         # Double-buffered infeed (SURVEY.md §3.3): host parse +
@@ -643,24 +589,8 @@ class Code2VecModel(Code2VecModelBase):
                     # recovery replays the trajectory bit-for-bit
                     step_rng = jax.random.fold_in(self.rng,
                                                   self.step_num)
-                    if phase_profiler.enabled \
-                            and phase_profiler.should_sample(
-                                steps_into_training):
-                        # sampled: probes first (measurement-only),
-                        # then the fused dispatch for the real update
-                        self.params, self.opt_state, loss = \
-                            phase_profiler.run_split(
-                                self.params, self.opt_state, dev_batch,
-                                step_rng, step=self.step_num,
-                                infeed_wait_ms=recorder.infeed_wait_ms
-                                if recorder.enabled else None,
-                                recorder=recorder
-                                if recorder.enabled else None)
-                    else:
-                        self.params, self.opt_state, loss = \
-                            self._train_step(self.params,
-                                             self.opt_state, dev_batch,
-                                             step_rng)
+                    self.params, self.opt_state, loss = self._train_step(
+                        self.params, self.opt_state, dev_batch, step_rng)
                     if nan_fp.armed and nan_fp.hit():
                         loss = loss * float("nan")  # poison the loss
                     if kill_fp.armed:

@@ -56,14 +56,17 @@ the backward pass: at H = 2048 a layer's activations are the memory.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import json
 import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from code2vec_tpu.models.encoder import Lfm2Dims, ModelDims, embed_contexts
+from code2vec_tpu.models.encoder import ModelDims, embed_contexts
+from code2vec_tpu.models.registry import EncoderSpec
 from code2vec_tpu.models.transformer_encoder import (_rms_norm,
                                                      learned_query_pool,
                                                      padding_log_mask)
@@ -73,6 +76,81 @@ from code2vec_tpu.ops.moe import held_experts_ffn, route
 # between the fourth and the fifth of 64): it turns near-ties and leaves
 # the load on the experts even, as the trained buffer's job is
 BIAS_SCALE = 0.005
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2Dims:
+    """The block's sizes, under the keys of the model's own
+    `config.json` (`model_type` `lfm2_moe`); every one comes from the
+    file `--lfm_config` names.
+    `num_experts` counts the experts whose weights THIS process holds,
+    `first_expert` the first of them, and `num_routed_experts` the
+    router's width (None: all are held here, as the published file
+    means it)."""
+    layer_types: Tuple[str, ...]
+    num_dense_layers: int
+    hidden_size: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    num_experts: int
+    num_experts_per_tok: int
+    conv_L_cache: int
+    norm_eps: float
+    rope_theta: float
+    num_routed_experts: Optional[int] = None
+    first_expert: int = 0
+
+    @property
+    def routed(self) -> int:
+        return self.num_routed_experts or self.num_experts
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @classmethod
+    def from_config(cls, config: dict) -> "Lfm2Dims":
+        """From a parsed `config.json`. Keys the block does not read
+        are passed over; a switch the block does not implement is an
+        error, not a silent default."""
+        fixed = {"norm_topk_prob": True, "use_expert_bias": True,
+                 "conv_bias": False, "routed_scaling_factor": 1}
+        for k, want in fixed.items():
+            if config.get(k, want) != want:
+                raise ValueError(f"lfm2_moe implements {k}={want!r} only "
+                                 f"(the file gives {config[k]!r})")
+        kw = {f.name: config[f.name] for f in dataclasses.fields(cls)
+              if f.name in config}
+        if "rope_parameters" in config:
+            kw["rope_theta"] = float(config["rope_parameters"]["rope_theta"])
+        missing = [f.name for f in dataclasses.fields(cls)
+                   if f.default is dataclasses.MISSING and f.name not in kw]
+        if missing:
+            raise ValueError(f"lfm2_moe: the block's file lacks {missing}")
+        kw["layer_types"] = tuple(kw["layer_types"])
+        dims = cls(**kw)
+        dims.check()
+        return dims
+
+    def check(self) -> None:
+        bad = set(self.layer_types) - {"conv", "full_attention"}
+        if bad or not self.layer_types:
+            raise ValueError(f"lfm2_moe layer_types {sorted(bad)} unknown "
+                             "(conv, full_attention)")
+        if self.hidden_size % self.num_attention_heads or \
+                self.num_attention_heads % self.num_key_value_heads or \
+                self.head_dim % 2:
+            raise ValueError("lfm2_moe: heads must divide hidden_size, "
+                             "kv heads the heads, and a head be even")
+        if not (0 <= self.first_expert
+                and self.first_expert + self.num_experts <= self.routed
+                and self.num_experts_per_tok <= self.routed):
+            raise ValueError(
+                f"lfm2_moe: experts {self.first_expert}.."
+                f"{self.first_expert + self.num_experts - 1} held of "
+                f"{self.routed} routed, {self.num_experts_per_tok} a token")
 
 
 def _is_moe(cfg: Lfm2Dims, i: int) -> bool:
@@ -227,12 +305,12 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
                     compute_dtype=jnp.float32,
                     use_pallas: bool = False, staircase=None
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """encoder.encode's arguments and its two values, (code [B, 3E] in
-    the compute dtype, pool attention [B, C] f32), and a third: int32
+    """The encode contract (registry.EncoderSpec): (code [B, 3E] in
+    the compute dtype, pool attention [B, C] f32, aux), aux being int32
     [expert layers, held + 1], per expert layer the rows each held
     expert took and, last, the valid tokens (the train step hands it to
-    `obs.route`; `get_encode_fn` drops it for the steps that do not
-    record). `use_pallas` is taken and not read: the
+    the spec's recorder, `obs.route`; the other steps let it fall).
+    `use_pallas` is taken and not read: the
     grouped product is XLA's own kernel on the TPU, the attention XLA's
     on every backend."""
     del use_pallas
@@ -286,3 +364,43 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
         code = pooled @ lfm["out_proj"].astype(compute_dtype)
     return code, attn, (jnp.stack(routes) if routes else jnp.zeros(
         (0, cfg.num_experts + 1), jnp.int32))
+
+
+# ---- the spec ------------------------------------------------------------
+
+def _init(rng: jax.Array, dims: ModelDims) -> Dict:
+    return init_lfm_params(jax.random.fold_in(rng, 0x1f2), dims)
+
+
+def _sizes_from_config(cfg) -> Dict:
+    """`--lfm_config`'s file (`check_config` has seen that it is named)."""
+    with open(cfg.LFM_CONFIG) as f:
+        return {"lfm": Lfm2Dims.from_config(json.load(f))}
+
+
+def _sizes_from_manifest(manifest: dict) -> Dict:
+    return {"lfm": Lfm2Dims.from_config(manifest["lfm"])}
+
+
+def _check_config(cfg) -> None:
+    if cfg.RING_ATTENTION or cfg.MESH_CONTEXT_AXIS > 1:
+        raise ValueError(
+            "--encoder lfm2_moe has no ring attention and no "
+            "context-parallel layout (its causal convolution and "
+            "mask run over whole sequences).")
+    if not cfg.LFM_CONFIG and not cfg.is_loading:
+        raise ValueError(
+            "--encoder lfm2_moe needs --lfm_config <json> (the "
+            "block's sizes; a checkpoint carries its own).")
+
+
+def _recorder():
+    from code2vec_tpu.obs.route import RouteRecorder
+    return RouteRecorder()
+
+
+SPEC = EncoderSpec(
+    encode=encode_lfm2_moe, params_key="lfm", init=_init,
+    sizes_from_config=_sizes_from_config,
+    sizes_from_manifest=_sizes_from_manifest, check_config=_check_config,
+    eval_batch_at_most_train=True, recorder=_recorder)

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from code2vec_tpu.models.encoder import ModelDims, embed_contexts
+from code2vec_tpu.models.registry import EncoderSpec
 
 
 def init_xf_params(rng: jax.Array, dims: ModelDims) -> Dict:
@@ -134,10 +135,10 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
                        compute_dtype=jnp.float32,
                        use_pallas: bool = False,
                        staircase=None
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """Same contract as encoder.encode: returns (code [B, D] in compute
-    dtype, pool attention [B, C] f32). With `use_pallas`, the
-    self-attention runs as the fused Pallas kernel pair
+                       ) -> Tuple[jax.Array, jax.Array, None]:
+    """The encode contract (registry.EncoderSpec): returns (code [B, D]
+    in compute dtype, pool attention [B, C] f32, None). With
+    `use_pallas`, the self-attention runs as the fused Pallas kernel pair
     (ops/xf_attention.py — no [B, H, C, C] HBM materialization in
     either direction). With dims.ring_attention and a mesh whose 'ctx'
     axis is > 1, it runs as ring attention instead (K/V rotate via
@@ -179,5 +180,12 @@ def encode_transformer(params: Dict, source_ids: jax.Array,
 
     with jax.named_scope("c2v/pool"):
         x = _rms_norm(x, xf["ln_f_scale"])
-        return learned_query_pool(x, xf["pool_query"], log_mask,
-                                  compute_dtype)
+        return (*learned_query_pool(x, xf["pool_query"], log_mask,
+                                    compute_dtype), None)
+
+
+def _init(rng: jax.Array, dims: ModelDims) -> Dict:
+    return init_xf_params(jax.random.fold_in(rng, 0x5f), dims)
+
+
+SPEC = EncoderSpec(encode=encode_transformer, params_key="xf", init=_init)

@@ -58,11 +58,11 @@ def vm_scores(params: Params, source_ids: jax.Array, path_ids: jax.Array,
     and 0/1 candidate mask. Returns (scores [B, K] f32 with -inf on
     padded candidates, attention [B, C]).
     """
-    code, attn = encode(params, source_ids, path_ids, target_ids, mask,
-                        dropout_rng=dropout_rng,
-                        dropout_keep_rate=dropout_keep_rate,
-                        compute_dtype=compute_dtype,
-                        use_pallas=use_pallas)
+    code, attn, _ = encode(params, source_ids, path_ids, target_ids, mask,
+                           dropout_rng=dropout_rng,
+                           dropout_keep_rate=dropout_keep_rate,
+                           compute_dtype=compute_dtype,
+                           use_pallas=use_pallas)
     cand = jnp.take(params["token_emb"], cand_ids, axis=0)  # [B, K, E]
     q = code.astype(jnp.float32) @ params["vm_pointer"]     # [B, E]
     scores = jnp.einsum("be,bke->bk", q,

@@ -261,26 +261,6 @@ class VarMisuseModel:
         plane.start()
         telemetry.gauge("train/max_contexts", cfg.MAX_CONTEXTS,
                         emit=False, static=True)
-        # sampled phase attribution (--phase_profile, ISSUE 15) — the
-        # same profiler as jax_model over the vm head's probe kit (no
-        # pre-attention seam: gather → forward → backward + the dense
-        # apply probe; no analytic bytes — the vm id-count model is
-        # not phase_traffic_bytes', so the roofline gauges stay absent
-        # rather than wrong, the floor-gauge discipline)
-        from code2vec_tpu.obs.phases import PhaseProfiler
-
-        def _phase_probes():
-            from code2vec_tpu.training.phase_probes import \
-                make_vm_probes
-            return make_vm_probes(self.dims,
-                                  compute_dtype=self.compute_dtype,
-                                  use_pallas=self.use_pallas)
-
-        phase_profiler = PhaseProfiler.create(
-            telemetry, fused_step=self._train_step,
-            probes_factory=_phase_probes,
-            enabled=cfg.PHASE_PROFILE == "on",
-            sample_every=cfg.PHASE_SAMPLE_EVERY, log=self.log)
         loop_hb.busy()  # the first deadline covers step-0 compile too
         steps_into_training = 0
         from code2vec_tpu.data.prefetch import (build_train_infeed,
@@ -310,22 +290,8 @@ class VarMisuseModel:
                     # absolute-step-keyed rng: auto-resume replays the
                     # uninterrupted run's key stream (see jax_model)
                     k = jax.random.fold_in(self.rng, self.step_num)
-                    if phase_profiler.enabled \
-                            and phase_profiler.should_sample(
-                                steps_into_training):
-                        self.params, self.opt_state, loss = \
-                            phase_profiler.run_split(
-                                self.params, self.opt_state, dev_batch,
-                                k, step=self.step_num,
-                                infeed_wait_ms=recorder.infeed_wait_ms
-                                if recorder.enabled else None,
-                                recorder=recorder
-                                if recorder.enabled else None)
-                    else:
-                        self.params, self.opt_state, loss = \
-                            self._train_step(self.params,
-                                             self.opt_state, dev_batch,
-                                             k)
+                    self.params, self.opt_state, loss = self._train_step(
+                        self.params, self.opt_state, dev_batch, k)
                     if nan_fp.armed and nan_fp.hit():
                         loss = loss * float("nan")  # poison the loss
                     if kill_fp.armed:

@@ -55,7 +55,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 __all__ = ["HealthEngine", "Monitor", "NonFiniteGauges", "EwmaZScore",
            "CounterRate", "TimerShare", "CounterRatio", "OptEfficiency",
-           "PhaseRoofline", "default_train_monitors",
+           "default_train_monitors",
            "default_serving_monitors"]
 
 
@@ -355,71 +355,6 @@ class OptEfficiency(Monitor):
                       "bad" if eff < self.bad_below else "ok")
 
 
-class PhaseRoofline(Monitor):
-    """Per-phase roofline gauges + split-coverage verdict (ISSUE 15).
-
-    Reads the sampled phase-split timers (`train/phase/<p>_ms`, written
-    by obs/phases.PhaseProfiler every --phase_sample_every steps) and
-    the static analytic traffic gauges (`train/phase_bytes/<p>`, from
-    training/sparse_update.phase_traffic_bytes) and publishes one
-    `health/phase_<p>` gauge per phase: achieved GB/s (bytes over the
-    observed p50) divided by the `train/phase_ceiling_gbps` streaming
-    ceiling — each phase's live roofline attainment, the per-phase
-    generalization of OptEfficiency above. The monitor's own value is
-    the SPLIT COVERAGE: sum of device-phase p50s over the fused sampled
-    dispatch's p50 — the live form of the "phases sum to within 15% of
-    the fused step" acceptance; far from 1 means the split no longer
-    describes the fused step (a new unattributed stage, or fusion wins
-    the probes cannot see). Unknown until the first sampled step lands
-    (phase profiling off = no timers, no verdict)."""
-
-    _PREFIX = "train/phase/"
-
-    def __init__(self, name: str = "phase_coverage",
-                 bad_beyond: float = 0.25):
-        super().__init__(name)
-        self.bad_beyond = bad_beyond
-
-    def evaluate(self, telemetry, now: float) -> None:
-        # the one list of phases that are device time inside the fused
-        # dispatch (infeed wait is host time outside it; the allreduce
-        # pair is comm the backward phase already carries) — owned by
-        # the profiler, stdlib-only at import time like this module
-        from code2vec_tpu.obs.phases import DEVICE_PHASES
-        fused = telemetry.timers.get(self._PREFIX + "fused_step_ms")
-        if fused is None or fused.count == 0:
-            self._publish(telemetry, float("nan"), "unknown",
-                          "no sampled phase-split step yet")
-            return
-        ceiling = telemetry.gauges.get("train/phase_ceiling_gbps")
-        total = 0.0
-        for tname, stat in list(telemetry.timers.items()):
-            if not tname.startswith(self._PREFIX) \
-                    or not tname.endswith("_ms") or stat.count == 0:
-                continue
-            phase = tname[len(self._PREFIX):-3]
-            p50 = stat.percentile(50)
-            if phase in DEVICE_PHASES:
-                total += p50
-            nbytes = telemetry.gauges.get(f"train/phase_bytes/{phase}")
-            if nbytes and _is_finite(nbytes) and ceiling \
-                    and _is_finite(ceiling) and p50 > 0:
-                util = (float(nbytes) / (p50 / 1e3)) \
-                    / (float(ceiling) * 1e9)
-                telemetry.gauge(f"health/phase_{phase}",
-                                min(1.0, util), emit=False)
-        fused_p50 = fused.percentile(50)
-        if fused_p50 <= 0:
-            self._publish(telemetry, self.value, self.status,
-                          "zero fused p50")
-            return
-        cov = total / fused_p50
-        self._publish(telemetry, cov,
-                      "bad" if abs(cov - 1.0) > self.bad_beyond
-                      else "ok",
-                      f"split phases cover {cov:.2f} of fused p50")
-
-
 def default_train_monitors() -> List[Monitor]:
     """The train-loop set: non-finite loss, loss spike, throughput
     regression, infeed starvation, analytic-floor attainment. Raw
@@ -432,7 +367,6 @@ def default_train_monitors() -> List[Monitor]:
         CounterRate("train/examples", name="throughput"),
         TimerShare(name="infeed_starvation"),
         OptEfficiency(name="opt_efficiency"),
-        PhaseRoofline(name="phase_coverage"),
     ]
 
 

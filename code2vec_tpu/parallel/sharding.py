@@ -25,22 +25,28 @@ from code2vec_tpu.parallel.mesh import (CONTEXT_AXIS, DATA_AXIS, DCN_AXIS,
                                         MODEL_AXIS)
 
 
+class _ParamRules(dict):
+    """The rules by the parameter tree's keys. A key they do not name
+    is an encoder's own subtree (models/registry.EncoderSpec
+    .params_key: "xf", "lfm"): one sharding for every leaf of it,
+    replicated (the transformer's ~L*12*D^2 floats are tiny next to
+    the vocab tables; of lfm2_moe every device holds the same experts
+    and routes its own rows, the expert exchange over a mesh axis is
+    not built)."""
+
+    def __missing__(self, key: str) -> P:
+        return P()
+
+
 def param_pspecs() -> Dict[str, P]:
-    return {
+    return _ParamRules({
         "token_emb": P(MODEL_AXIS, None),
         "path_emb": P(MODEL_AXIS, None),
         "target_emb": P(MODEL_AXIS, None),
         "transform": P(None, None),
         "attention": P(None),
         "vm_pointer": P(None, None),   # VarMisuse head (tiny: replicated)
-        # transformer encoder subtree ("xf"): one sharding for every leaf
-        # (replicated — ~L*12*D^2 floats, tiny next to the vocab tables)
-        "xf": P(),
-        # LFM2-MoE encoder subtree: replicated too (every device holds
-        # the same experts and routes its own rows; the expert exchange
-        # over a mesh axis is not built)
-        "lfm": P(),
-    }
+    })
 
 
 def batch_pspec() -> P:

@@ -82,7 +82,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import orbax.checkpoint as ocp
 
-from code2vec_tpu.models.encoder import Lfm2Dims, ModelDims
+from code2vec_tpu.models.encoder import ModelDims
+from code2vec_tpu.models.registry import spec as encoder_spec
 from code2vec_tpu.resilience import faults
 from code2vec_tpu.resilience import retry as retry_mod
 from code2vec_tpu.vocab.vocabularies import Code2VecVocabs
@@ -603,7 +604,8 @@ def load_dims(ckpt_dir: str) -> ModelDims:
         xf_mlp_ratio=m.get("xf_mlp_ratio", 4),
         xf_remat=m.get("xf_remat", False),
         ring_attention=m.get("ring_attention", False),
-        lfm=Lfm2Dims.from_config(m["lfm"]) if m.get("lfm") else None,
+        # the encoder's own sizes (`lfm`), read by its spec
+        **encoder_spec(m.get("encoder_type", "bag")).sizes_from_manifest(m),
     )
 
 

@@ -79,10 +79,8 @@ def _gather_rows(table, ids):
 
 def prepare_step_inputs(params, batch, rng, *, use_sampled_softmax:
                         bool, num_sampled: int, target_vocab: int):
-    """The sparse step's non-differentiated preliminaries + gathers —
-    extracted from `step_impl` so the phase probes
-    (training/phase_probes.py, ISSUE 15) measure EXACTLY the gathers
-    the step performs, never a drifted copy. Returns
+    """The sparse step's non-differentiated preliminaries + gathers.
+    Returns
     `(dense, gathered, ctx)`: the dense-param dict, the gathered-row
     dict autodiff differentiates, and a ctx dict carrying everything
     `make_gathered_loss` and the apply section need (drop_rng, qrngs,
@@ -216,10 +214,6 @@ def make_sparse_train_step(dims: ModelDims, *, learning_rate: float,
 
     def step_impl(params, opt_state, batch, rng):
         labels, src, pth, dst, mask, weights = batch
-        # preliminaries + gathers + the differentiated loss live in
-        # module-level helpers shared with the ISSUE-15 phase probes
-        # (training/phase_probes.py): ONE definition, so a sampled
-        # phase-split prefix can never measure drifted math
         dense, gathered, ctx = prepare_step_inputs(
             params, batch, rng, use_sampled_softmax=use_sampled_softmax,
             num_sampled=S, target_vocab=V)

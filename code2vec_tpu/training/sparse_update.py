@@ -550,97 +550,6 @@ def sparse_step_floor_bytes(params, batch_size: int, max_contexts: int,
     return int(total)
 
 
-def phase_traffic_bytes(params, batch_size: int, max_contexts: int, *,
-                        num_sampled: int = 0, sparse: bool = False,
-                        compute_itemsize: int = 2,
-                        block_rows: int = _BLOCK_ROWS,
-                        data_shards: int = 1,
-                        processes: int = 1) -> dict:
-    """Analytic PER-DEVICE HBM bytes of each step phase (ISSUE 15):
-    the per-phase generalization of sparse_step_floor_bytes, keyed by
-    the phase names obs/phases.py publishes, so the live
-    `health/phase_*` roofline gauges and bench.py's `phase_*`
-    attribution divide measured ms by the SAME comparator. Coarse by
-    design — streaming lower bounds (gathers run at random-access,
-    not streaming, bandwidth; activations that stay resident are
-    still counted once), so derived utilizations are conservative:
-
-      embed_gather — forward row gathers per occurrence (row read +
-        gathered-activation write at the compute dtype). The sampled-
-        softmax target gathers are counted here for both paths (the
-        dense step performs them inside the loss; one coarse rule).
-      concat_dense — concat write + read of the [B, C, 3E] context
-        tensor, the TRANSFORM weights, the transformed-tensor write.
-      forward_pool — transformed read, attention-weighted reduction,
-        code write, sampled logits.
-      backward — activation re-read + context-cotangent write, plus
-        per-occurrence table cotangents (at gathered-row granularity
-        when `sparse`, the dense [V, E] carrier write + read
-        otherwise — the asymmetry IS the sparse path's win).
-      table_apply — `sparse`: sparse_update_phase_bytes (the [U, E]
-        live-row model); dense: grad read + param read/write + two
-        f32 moment sweeps per leaf (the Adam-shaped comparator
-        _step_hbm_bytes uses).
-
-    Mesh model follows sparse_step_floor_bytes: `batch_size` is the
-    per-process batch; forward/backward cover the device's batch
-    shard, the sparse apply covers the all-gathered GLOBAL list."""
-    counts = table_id_counts(batch_size, max_contexts, num_sampled)
-    gather = 0
-    cot = 0
-    carrier = 0
-    emb_any = 0
-    for key, n in counts.items():
-        table = params.get(key)
-        if table is None:
-            continue
-        n_local = n * processes / data_shards
-        if is_quantized(table):
-            rows, emb = table["q"].shape
-            row_bytes, grad_itemsize = emb * 1 + 4, 2
-            table_elems = table["q"].size
-        else:
-            rows, emb = table.shape
-            row_bytes = emb * table.dtype.itemsize
-            grad_itemsize = table.dtype.itemsize
-            table_elems = table.size
-        emb_any = emb
-        gather += int(n_local * (row_bytes + emb * compute_itemsize))
-        cot += int(n_local * emb * grad_itemsize)
-        carrier += table_elems * grad_itemsize * 2  # dense w + r
-    transform = params.get("transform")
-    D = int(transform.shape[0]) if transform is not None else 3 * emb_any
-    B_local = batch_size * processes / max(1, data_shards)
-    ctx_bytes = int(B_local * max_contexts * D * compute_itemsize)
-    out = {"embed_gather": gather}
-    out["concat_dense"] = int(
-        ctx_bytes * 3 + (D * D * 4 if transform is not None else 0))
-    out["forward_pool"] = int(
-        ctx_bytes + B_local * D * compute_itemsize
-        + B_local * (1 + num_sampled) * 4)
-    out["backward"] = int(ctx_bytes * 2
-                          + (cot if sparse else cot + carrier))
-    if sparse:
-        out["table_apply"] = sparse_update_phase_bytes(
-            params, batch_size, max_contexts, num_sampled=num_sampled,
-            block_rows=block_rows, processes=processes)
-    else:
-        apply = 0
-        for p in params.values():
-            if is_quantized(p):
-                apply += p["q"].size * 2          # carrier grad read
-                apply += p["q"].size * 2          # q r + w
-                apply += p["s"].size * 4 * 2      # s r + w
-                apply += p["q"].size * 4 * 2 * 2  # Adam-shaped moments
-                continue
-            for leaf in jax.tree_util.tree_leaves(p):
-                b = leaf.size * leaf.dtype.itemsize
-                apply += b * 3                    # grad r, param r + w
-                apply += leaf.size * 4 * 2 * 2    # two f32 moments r+w
-        out["table_apply"] = int(apply)
-    return {k: int(v) for k, v in out.items()}
-
-
 def expected_unique_rows(n_ids: int, num_rows: int) -> int:
     """E[U] for n uniform draws over V rows (the bench worst case):
     V * (1 - (1 - 1/V)^n). Real corpora are Zipfian (fewer uniques),

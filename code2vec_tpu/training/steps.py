@@ -25,6 +25,7 @@ import optax
 
 from code2vec_tpu.models.encoder import (ModelDims, full_logits,
                                          get_encode_fn)
+from code2vec_tpu.models.registry import spec as encoder_spec
 from code2vec_tpu.ops.sampled_softmax import sampled_softmax_loss
 from code2vec_tpu.training.optimizers import apply_updates
 
@@ -54,29 +55,26 @@ def _weighted_mean(values: jax.Array, weights: jax.Array) -> jax.Array:
     return jnp.sum(values * weights) / denom
 
 
-def _make_loss_and_route_fn(dims: ModelDims, *, use_sampled_softmax: bool,
-                            num_sampled: int, compute_dtype,
-                            use_pallas: bool, mesh,
-                            staircase=None) -> Callable:
-    """`fn(params, batch, rng) -> (loss, route counts)`, for
-    `value_and_grad(..., has_aux=True)`. The counts are None but for
-    the encoder with routed experts (lfm2_moe_encoder.encode_lfm2_moe's
-    third value). `staircase`: `encoder.embed_contexts`."""
-    if dims.encoder_type == "lfm2_moe":
-        from code2vec_tpu.models.lfm2_moe_encoder import encode_lfm2_moe
-        encode = functools.partial(encode_lfm2_moe, dims=dims, mesh=mesh)
-    else:
-        encode = get_encode_fn(dims, mesh)
-    if staircase is not None:
-        encode = functools.partial(encode, staircase=staircase)
+def _make_loss_and_aux_fn(dims: ModelDims, *, use_sampled_softmax: bool,
+                          num_sampled: int, compute_dtype,
+                          use_pallas: bool, mesh,
+                          staircase=None) -> Callable:
+    """`fn(params, batch, rng) -> (loss, aux)`, for
+    `value_and_grad(..., has_aux=True)`: the training-time loss
+    (dropout on, sampled or full softmax) and what the encoder hands
+    the step beside it (the encode contract's third value, None for
+    most encoders). The one description of the float step's forward.
+    `staircase`: `encoder.embed_contexts`."""
+    encode = get_encode_fn(dims, mesh)
 
     def loss_fn(params, batch, rng):
         labels, src, pth, dst, mask, weights = batch
         drop_rng, sample_rng = jax.random.split(rng)
-        code, _attn, *route = encode(
+        code, _attn, aux = encode(
             params, src, pth, dst, mask, dropout_rng=drop_rng,
             dropout_keep_rate=dims.dropout_keep_rate,
-            compute_dtype=compute_dtype, use_pallas=use_pallas)
+            compute_dtype=compute_dtype, use_pallas=use_pallas,
+            staircase=staircase)
         if use_sampled_softmax:
             loss, _ = sampled_softmax_loss(
                 params["target_emb"], code, labels, sample_rng,
@@ -89,7 +87,7 @@ def _make_loss_and_route_fn(dims: ModelDims, *, use_sampled_softmax: bool,
                 ce = optax.softmax_cross_entropy_with_integer_labels(
                     logits, labels)
                 loss = _weighted_mean(ce, weights)
-        return loss, (route[0] if route else None)
+        return loss, aux
 
     return loss_fn
 
@@ -100,16 +98,15 @@ def make_train_loss_fn(dims: ModelDims, *,
                        compute_dtype=jnp.float32,
                        use_pallas: bool = False,
                        mesh=None) -> Callable:
-    """The training-time loss `loss_fn(params, batch, rng)` (dropout on,
-    sampled or full softmax). Single source of truth: make_train_step
-    differentiates exactly this, and bench.py's fwd+bwd roofline floor
-    measures exactly this — the two MUST share it or the floor silently
-    measures different math than the step."""
-    loss_and_route = _make_loss_and_route_fn(
+    """`loss_fn(params, batch, rng)`: the first value of the function
+    the float step differentiates (`_make_loss_and_aux_fn`, no
+    staircase). The int8 step differentiates this; the measuring tools
+    (bench.py, tools/*_profile.py) time it."""
+    loss_and_aux = _make_loss_and_aux_fn(
         dims, use_sampled_softmax=use_sampled_softmax,
         num_sampled=num_sampled, compute_dtype=compute_dtype,
         use_pallas=use_pallas, mesh=mesh)
-    return lambda params, batch, rng: loss_and_route(params, batch, rng)[0]
+    return lambda params, batch, rng: loss_and_aux(params, batch, rng)[0]
 
 
 def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
@@ -144,8 +141,7 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
     `sparse_block_rows` (Config.SPARSE_UPDATE_PALLAS) selecting the
     Pallas live-row kernel vs the XLA reference; opt_state must then
     come from sparse_steps.init_sparse_opt_state and `learning_rate`
-    names the tables' row-Adam LR. This keeps ONE step-construction
-    entry point for models/jax_model.py and bench.py.
+    names the tables' row-Adam LR.
 
     With a `staircase` (data/staircase.py; the float step only: the
     int8 and sparse steps take none) the returned step holds two
@@ -153,7 +149,11 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
     chose: the step whose embedding gather and scatter stop at the
     staircase for a `TrainBatch` that `fits`, this step as it always
     was for every other batch (a plain tuple among them). Each is
-    compiled when first run; `.lower` is the full step's."""
+    compiled when first run; `.lower` is the full step's.
+
+    An encoder whose `encode` hands the step an `aux` (the registry's
+    spec names its recorder) gets the step behind `_recording`: same
+    contract, the recorder on the step's `route_recorder`."""
     if sparse_updates:
         assert augment_fn is None, (
             "sparse_updates has no augmentation hook "
@@ -178,11 +178,10 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
         return _make_quantized_train_step(
             optimizer, make_train_loss_fn(dims, **loss_kw), augment_fn,
             requant_fused, mesh)
-    routed = dims.encoder_type == "lfm2_moe"
 
     def jitted(staircase):
-        loss_and_route = _make_loss_and_route_fn(dims, staircase=staircase,
-                                                 **loss_kw)
+        loss_and_aux = _make_loss_and_aux_fn(dims, staircase=staircase,
+                                             **loss_kw)
 
         @functools.partial(jax.jit, donate_argnums=(0, 1))
         def step(params, opt_state, batch, rng):
@@ -190,18 +189,19 @@ def make_train_step(dims: ModelDims, optimizer: optax.GradientTransformation,
                 # a rename keeps PAD where it was: the staircase holds
                 rng, aug_rng = jax.random.split(rng)
                 batch = augment_fn(batch, aug_rng)
-            (loss, route), grads = jax.value_and_grad(
-                loss_and_route, has_aux=True)(params, batch, rng)
+            (loss, aux), grads = jax.value_and_grad(
+                loss_and_aux, has_aux=True)(params, batch, rng)
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = apply_updates(params, updates)
-            return params, opt_state, (loss, route) if routed else loss
+            return params, opt_state, loss if aux is None else (loss, aux)
 
         return step
 
     step = jitted(None)
     if staircase is not None:
         step = _by_fit(jitted(staircase), step)
-    return _recording_route(step) if routed else step
+    recorder = encoder_spec(dims.encoder_type).recorder
+    return step if recorder is None else _recording(step, recorder())
 
 
 def _by_fit(staircase_step: Callable, full_step: Callable) -> Callable:
@@ -218,21 +218,19 @@ def _by_fit(staircase_step: Callable, full_step: Callable) -> Callable:
     return step
 
 
-def _recording_route(step: Callable) -> Callable:
-    """A routed-experts train step behind the contract of the others,
-    `(params, opt_state, loss)` and `.lower`: the jitted step's loss is
-    `(loss, route counts)`, and the counts go to `obs.route`, which
-    reads them a step later without a wait. The recorder is the
-    returned function's `route_recorder` (the train loop hands it the
-    run's tracer and flushes it after its last sync)."""
-    from code2vec_tpu.obs.route import RouteRecorder
-
-    recorder = RouteRecorder()
+def _recording(step: Callable, recorder) -> Callable:
+    """A train step whose encoder hands it an `aux`, behind the contract
+    of the others, `(params, opt_state, loss)` and `.lower`: the jitted
+    step's loss is `(loss, aux)`, and `aux` goes to the spec's recorder
+    (obs/route.py: the routed experts' counts, read a step later without
+    a wait). The recorder is the returned function's `route_recorder`
+    (the name the benchmark reads; the train loop hands it the run's
+    tracer and flushes it after its last sync)."""
 
     def recorded(params, opt_state, batch, rng):
-        params, opt_state, (loss, counts) = step(params, opt_state, batch,
-                                                 rng)
-        recorder.push(counts)
+        params, opt_state, (loss, aux) = step(params, opt_state, batch,
+                                              rng)
+        recorder.push(aux)
         return params, opt_state, loss
 
     recorded.route_recorder = recorder
@@ -319,9 +317,9 @@ def make_eval_step(dims: ModelDims, *, top_k: int = 10,
     @jax.jit
     def step(params, batch):
         labels, src, pth, dst, mask, weights = batch
-        code, _attn = encode(params, src, pth, dst, mask,
-                             compute_dtype=compute_dtype,
-                             use_pallas=use_pallas)
+        code, _attn, _aux = encode(params, src, pth, dst, mask,
+                                   compute_dtype=compute_dtype,
+                                   use_pallas=use_pallas)
         logits = full_logits(params, code, dims.target_vocab_size)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
         # CE is mathematically >= 0; on TPU the logsumexp-minus-logit
@@ -350,9 +348,9 @@ def make_encode_step(dims: ModelDims, *,
     @jax.jit
     def step(params, batch):
         _labels, src, pth, dst, mask, _weights = batch
-        code, _attn = encode(params, src, pth, dst, mask,
-                             compute_dtype=compute_dtype,
-                             use_pallas=use_pallas)
+        code, _attn, _aux = encode(params, src, pth, dst, mask,
+                                   compute_dtype=compute_dtype,
+                                   use_pallas=use_pallas)
         return code.astype(jnp.float32)
 
     return step
@@ -371,9 +369,9 @@ def make_predict_step(dims: ModelDims, *, top_k: int = 10,
     @jax.jit
     def step(params, batch):
         _labels, src, pth, dst, mask, _weights = batch
-        code, attn = encode(params, src, pth, dst, mask,
-                            compute_dtype=compute_dtype,
-                            use_pallas=use_pallas)
+        code, attn, _aux = encode(params, src, pth, dst, mask,
+                                  compute_dtype=compute_dtype,
+                                  use_pallas=use_pallas)
         logits = full_logits(params, code, dims.target_vocab_size)
         probs = jax.nn.softmax(logits, axis=-1)
         topk_probs, topk_ids = jax.lax.top_k(probs, top_k)

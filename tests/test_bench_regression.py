@@ -403,3 +403,62 @@ def test_serving_repo_trajectory_accepted():
                    pattern="SERVING_r*.json")
     assert rc in (0, 2)
     assert all(r["status"] != "REGRESSION" for r in rows)
+
+
+# ---- the gate on a round file's phase_*_ms keys ----
+
+def test_bench_regression_catches_single_phase_2x():
+    """Acceptance: the injected single-phase 2x regression fixture
+    exits 1 under the default (phase-gated) metric set while the
+    headline-only check would have passed."""
+    from tools.bench_regression import DEFAULT_METRICS, run
+    fixture = os.path.join(REPO, "tests", "bench_fixtures",
+                           "phase_regress")
+    rc, rows = run(fixture, list(DEFAULT_METRICS), band=0.05,
+                   window=5, min_history=2, strict=False)
+    assert rc == 1
+    by = {r["metric"]: r for r in rows}
+    assert by["phase_backward_ms"]["status"] == "REGRESSION"
+    assert by["phase_backward_ms"]["lower_is_better"] is True
+    assert by["value"]["status"] == "ok"
+    # headline-only: the regression sails through — the reason the
+    # per-phase gate exists
+    rc2, _ = run(fixture, ["value", "sparse_pc_per_sec"], band=0.05,
+                 window=5, min_history=2, strict=False)
+    assert rc2 == 0
+
+
+def test_bench_regression_gates_unlisted_phase_keys(tmp_path):
+    """A phase key OUTSIDE the PHASE_MS_METRICS literals (a future
+    mesh capture's phase_allreduce_ms, the int8 backward_apply
+    remainder) is auto-discovered from the rounds and gated
+    lower-is-better — no phase escapes the gate the docs promise."""
+    from tools.bench_regression import run
+    base = {"metric": "path-contexts/sec/chip", "value": 6.6e6,
+            "phase_backward_apply_ms": 8.0}
+    for n in (1, 2, 3):
+        (tmp_path / f"BENCH_r0{n}.json").write_text(json.dumps(base))
+    bad = dict(base)
+    bad["phase_backward_apply_ms"] = 16.0  # 2x, headline steady
+    (tmp_path / "BENCH_r04.json").write_text(json.dumps(bad))
+    rc, rows = run(str(tmp_path), ["value"], band=0.05, window=5,
+                   min_history=2, strict=False, auto_phases=True)
+    assert rc == 1
+    by = {r["metric"]: r for r in rows}
+    assert by["phase_backward_apply_ms"]["status"] == "REGRESSION"
+    assert by["value"]["status"] == "ok"
+    # an explicit metric list is respected as given (the CLI passes
+    # auto_phases only for default-set runs)
+    rc2, _ = run(str(tmp_path), ["value"], band=0.05, window=5,
+                 min_history=2, strict=False)
+    assert rc2 == 0
+
+
+def test_bench_regression_phase_direction_is_lower_better():
+    from tools.bench_regression import (PHASE_MS_METRICS,
+                                        _lower_is_better)
+    for m in PHASE_MS_METRICS:
+        assert _lower_is_better(m)
+    assert _lower_is_better("recovery_seconds")
+    assert not _lower_is_better("value")
+    assert not _lower_is_better("phase_sum_bytes")

@@ -507,14 +507,6 @@ def test_baseline_refuses_serving_and_obs(tmp_path):
     bad_nondet_tr = Finding("nondeterminism",
                             "code2vec_tpu/training/sparse_update.py",
                             1, "m", "s")
-    # ISSUE 15 satellite: the phase-attribution plane joins the obs/
-    # fence from day one — a finding in the module whose whole job is
-    # honest measurement is a bug to fix, never debt to grandfather
-    bad_phases = Finding("host-sync-in-hot-path",
-                         "code2vec_tpu/obs/phases.py", 1, "m", "s")
-    bad_probes = Finding("retrace-hazard",
-                         "code2vec_tpu/training/phase_probes.py",
-                         1, "m", "s")
     # ISSUE 17 satellite: the fleet plane joins the obs/ fence from
     # day one — a leak or swallowed error in the cohort collector
     # (the thing that watches everyone else) is a bug to fix, never
@@ -540,13 +532,12 @@ def test_baseline_refuses_serving_and_obs(tmp_path):
     refused = baseline_mod.write(
         [bad, bad_training, bad_ops, bad_parallel, bad_resilience,
          bad_spmd, bad_spmd_par, bad_nondet, bad_nondet_tr,
-         bad_phases, bad_probes, bad_fleet, bad_frontend,
+         bad_fleet, bad_frontend,
          bad_replicas, bad_reload, bad_scaler, ok],
         path)
     assert refused == [bad, bad_training, bad_ops, bad_parallel,
                        bad_resilience, bad_spmd, bad_spmd_par,
-                       bad_nondet, bad_nondet_tr, bad_phases,
-                       bad_probes, bad_fleet, bad_frontend,
+                       bad_nondet, bad_nondet_tr, bad_fleet, bad_frontend,
                        bad_replicas, bad_reload, bad_scaler]
     assert [e["path"] for e in baseline_mod.load(path)] == ["tools/x.py"]
 

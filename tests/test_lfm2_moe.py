@@ -18,8 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from code2vec_tpu.models.encoder import (Lfm2Dims, ModelDims,
-                                         get_encode_fn, init_params)
+from code2vec_tpu.models.encoder import (ModelDims, get_encode_fn,
+                                         init_params)
+from code2vec_tpu.models.lfm2_moe_encoder import Lfm2Dims
 from code2vec_tpu.models import lfm2_moe_encoder as lfm
 from code2vec_tpu.ops import moe
 from tests.helpers import build_tiny_dataset
@@ -104,9 +105,9 @@ def test_reference_draws_the_programs_weights():
 def test_code_vector_matches_reference(dtype, tol):
     dims, params, _ = program_weights()
     _labels, src, pth, dst, mask, _w = batches()[0]
-    code, attn = get_encode_fn(dims)(params, src, pth, dst,
-                                     jnp.asarray(mask),
-                                     compute_dtype=jnp.dtype(dtype))
+    code, attn, _ = get_encode_fn(dims)(params, src, pth, dst,
+                                        jnp.asarray(mask),
+                                        compute_dtype=jnp.dtype(dtype))
     p, _ = ref_mod.make_weights(SEED, spec())
     c = jnp.concatenate([p["token_emb"][src], p["path_emb"][pth],
                          p["token_emb"][dst]], axis=-1)
@@ -212,10 +213,10 @@ def test_masked_contexts_do_not_affect_code():
     mask = mask.copy()
     mask[:, 5:] = 0.0
     enc = get_encode_fn(DIMS)
-    code1, attn = enc(params, src, pth, dst, jnp.asarray(mask))
+    code1, attn, _ = enc(params, src, pth, dst, jnp.asarray(mask))
     src2 = src.copy()
     src2[:, 5:] = (src2[:, 5:] + 7) % DIMS.token_vocab_size
-    code2, _ = enc(params, jnp.asarray(src2), pth, dst, jnp.asarray(mask))
+    code2, *_ = enc(params, jnp.asarray(src2), pth, dst, jnp.asarray(mask))
     np.testing.assert_allclose(np.asarray(code1), np.asarray(code2),
                                atol=1e-6)
     assert np.all(np.isfinite(np.asarray(code1)))
@@ -229,8 +230,8 @@ def test_order_of_contexts_matters():
     _labels, src, pth, dst, _mask, _w = batches()[0]
     ones = jnp.ones(src.shape, jnp.float32)
     enc = get_encode_fn(DIMS)
-    code1, _ = enc(params, src, pth, dst, ones)
-    code2, _ = enc(params, src[:, ::-1], pth[:, ::-1], dst[:, ::-1], ones)
+    code1, *_ = enc(params, src, pth, dst, ones)
+    code2, *_ = enc(params, src[:, ::-1], pth[:, ::-1], dst[:, ::-1], ones)
     assert float(jnp.max(jnp.abs(code1 - code2))) > 1e-3
 
 

@@ -65,3 +65,31 @@ def test_scalar_writer_warn_latch_suppresses_log_only():
     import code2vec_tpu.training.scalars as scalars_mod
     w = scalars_mod.ScalarWriter(None)
     assert w._writer is None
+
+
+# ---- tools/obs_top.py: a counter that went backward ----
+
+def _fake_metrics(steps, examples):
+    return (f"train_steps {steps}\ntrain_examples {examples}\n"
+            "train_max_contexts 16\n")
+
+
+def test_obs_top_counter_reset_clamps_and_annotates(monkeypatch):
+    import tools.obs_top as obs_top
+    feed = [_fake_metrics(1000, 32000), _fake_metrics(5, 160)]
+
+    def fake_scrape(endpoint, timeout_s=3.0):
+        return obs_top.parse_prometheus(feed.pop(0))
+
+    monkeypatch.setattr(obs_top, "scrape", fake_scrape)
+    st = obs_top.EndpointState("h:1")
+    st.poll(60.0)
+    row = st.poll(60.0)
+    # supervisor restart zeroed the counters: no negative rates, the
+    # row says why
+    assert row["steps_s"] is not None and row["steps_s"] >= 0
+    assert row["ex_s"] is not None and row["ex_s"] >= 0
+    assert "train_steps" in row["restarted"]
+    out = obs_top.render([row])
+    assert "RESTARTED" in out
+    assert "-" + "1" not in out.replace("|---", "")  # no negative cell

@@ -408,3 +408,58 @@ def test_span_end_is_idempotent_and_error_paths_close_roots(tmp_path):
     finally:
         server.close()
     tele2.close()
+
+
+# ---- tools/trace_report.py --merge ----
+
+def _write_run(d, manifest, events):
+    os.makedirs(d, exist_ok=True)
+    with open(os.path.join(d, "manifest.json"), "w") as f:
+        json.dump(manifest, f)
+    with open(os.path.join(d, "events.jsonl"), "w") as f:
+        for e in events:
+            f.write(json.dumps(e) + "\n")
+
+
+def test_trace_report_merge_cohort(tmp_path, capsys):
+    from tools.trace_report import main, write_chrome_trace
+    spans0 = [{"kind": "span", "trace": "t0", "span": "s0",
+               "name": "train/step_cycle", "t0": 100.0, "dur_ms": 5.0,
+               "tid": 1, "tname": "main", "attrs": {"step": 1}}]
+    spans1 = [{"kind": "span", "trace": "t1", "span": "s1",
+               "name": "train/step_cycle", "t0": 900.0, "dur_ms": 5.0,
+               "tid": 1, "tname": "main", "attrs": {"step": 1}}]
+    d0 = str(tmp_path / "r0")
+    d1 = str(tmp_path / "r1")
+    _write_run(d0, {"run_id": "run-p0", "component": "train",
+                    "process_index": 0, "process_count": 2,
+                    "created_unix": 1000.0}, spans0)
+    _write_run(d1, {"run_id": "run-p1", "component": "train",
+                    "process_index": 1, "process_count": 2,
+                    "created_unix": 1002.5}, spans1)
+    out = str(tmp_path / "merged.json")
+    write_chrome_trace([d0, d1], out, merge=True)
+    with open(out) as f:
+        trace = json.load(f)["traceEvents"]
+    names = [(e["name"], e.get("pid")) for e in trace]
+    assert ("process_name", 0) in names and ("process_name", 1) in names
+    # wall-clock alignment: p1's span starts ~2.5 s after p0's (each
+    # run's own monotonic base is meaningless across processes)
+    e0 = next(e for e in trace
+              if e["name"] == "train/step_cycle" and e["pid"] == 0)
+    e1 = next(e for e in trace
+              if e["name"] == "train/step_cycle" and e["pid"] == 1)
+    assert e1["ts"] - e0["ts"] == pytest.approx(2.5e6, abs=1.0)
+    notes = [e for e in trace if e["name"] == "clock_note"]
+    assert len(notes) == 2
+    assert "monotonic" in notes[0]["args"]["note"]
+    # unmerged export stays byte-compatible: no metadata injected
+    out2 = str(tmp_path / "flat.json")
+    write_chrome_trace([d0, d1], out2)
+    with open(out2) as f:
+        flat = json.load(f)["traceEvents"]
+    assert not [e for e in flat if e["name"] in ("process_name",
+                                                 "clock_note")]
+    # --merge without --chrome: usage error, not a silent non-merge
+    assert main(["--merge", d0, d1]) == 2
+    capsys.readouterr()

@@ -37,11 +37,11 @@ def test_masked_contexts_do_not_affect_code():
     labels, src, pth, dst, mask, _w = example_batch(3, DIMS, 4)
     mask = np.ones_like(mask)
     mask[:, 5:] = 0.0
-    code1, attn1 = enc(p, src, pth, dst, jnp.asarray(mask))
+    code1, attn1, _ = enc(p, src, pth, dst, jnp.asarray(mask))
     # change ids ONLY in masked positions
     src2 = src.copy()
     src2[:, 5:] = (src2[:, 5:] + 7) % DIMS.token_vocab_size
-    code2, attn2 = enc(p, jnp.asarray(src2), pth, dst, jnp.asarray(mask))
+    code2, attn2, _ = enc(p, jnp.asarray(src2), pth, dst, jnp.asarray(mask))
     np.testing.assert_allclose(np.asarray(code1), np.asarray(code2),
                                atol=1e-5)
     assert np.all(np.asarray(attn1)[:, 5:] < 1e-6)
@@ -54,9 +54,9 @@ def test_permutation_equivariance_of_code():
     enc = get_encode_fn(DIMS)
     labels, src, pth, dst, mask, _w = example_batch(4, DIMS, 4)
     perm = np.random.default_rng(0).permutation(DIMS.max_contexts)
-    code1, _ = enc(p, src, pth, dst, jnp.asarray(mask))
-    code2, _ = enc(p, jnp.asarray(src[:, perm]), jnp.asarray(pth[:, perm]),
-                   jnp.asarray(dst[:, perm]), jnp.asarray(mask[:, perm]))
+    code1, *_ = enc(p, src, pth, dst, jnp.asarray(mask))
+    code2, *_ = enc(p, jnp.asarray(src[:, perm]), jnp.asarray(pth[:, perm]),
+                    jnp.asarray(dst[:, perm]), jnp.asarray(mask[:, perm]))
     np.testing.assert_allclose(np.asarray(code1), np.asarray(code2),
                                atol=1e-4)
 
@@ -66,7 +66,7 @@ def test_all_pad_row_is_finite():
     enc = get_encode_fn(DIMS)
     labels, src, pth, dst, mask, _w = example_batch(5, DIMS, 2)
     mask = np.zeros_like(mask)
-    code, attn = enc(p, src, pth, dst, jnp.asarray(mask))
+    code, attn, _ = enc(p, src, pth, dst, jnp.asarray(mask))
     assert np.all(np.isfinite(np.asarray(code)))
     assert np.all(np.isfinite(np.asarray(attn)))
 

@@ -123,9 +123,9 @@ def test_encoder_pallas_path_matches_xla_path():
     dst = jnp.asarray(r.integers(0, 64, (B, C)), jnp.int32)
     mask = jnp.asarray((r.random((B, C)) > 0.2), jnp.float32)
 
-    code_xla, attn_xla = te.encode_transformer(
+    code_xla, attn_xla, _ = te.encode_transformer(
         params, src, pth, dst, mask, dims=dims)
-    code_pl, attn_pl = te.encode_transformer(
+    code_pl, attn_pl, _ = te.encode_transformer(
         params, src, pth, dst, mask, dims=dims, use_pallas=True)
     np.testing.assert_allclose(np.asarray(code_pl),
                                np.asarray(code_xla), atol=1e-4)

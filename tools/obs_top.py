@@ -21,8 +21,7 @@ and rates clamp to the new process's progress instead of rendering
 negative steps/s. path-contexts/s = examples-rate × the
 `train_max_contexts` gauge the train loop publishes. Health verdicts,
 firing alerts, stalled components and stale gauges (age > --stale_s)
-come straight off the same scrape; hosts running --phase_profile
-additionally get a per-phase p50 column set (ISSUE 15). Pure stdlib
+come straight off the same scrape. Pure stdlib
 (urllib + the shared obs/promtext parser, itself re-only) — runs on a
 laptop against a pod with nothing installed beyond this checkout.
 
@@ -57,19 +56,7 @@ from code2vec_tpu.obs.promtext import (CounterRates,  # noqa: E402
                                        scalar)
 
 __all__ = ["EndpointState", "labeled", "main", "parse_prometheus",
-           "render", "render_fleet", "render_phases", "scalar",
-           "scrape"]
-
-# canonical phase-column order: code2vec_tpu/obs/phases.py PHASE_ORDER
-# plus the trailing fused_step timer (kept literal here so this tool
-# stays runnable on a laptop with nothing installed; a test pins the
-# copy equal to the canonical tuple); unknown phases append
-# alphabetically
-_PHASE_ORDER = ("infeed_wait", "embed_gather", "concat_dense",
-                "forward_pool", "backward", "table_apply",
-                "backward_apply", "allreduce", "allreduce_exposed",
-                "fused_step")
-
+           "render", "render_fleet", "scalar", "scrape"]
 
 def scrape(endpoint: str, timeout_s: float = 3.0) -> Dict:
     url = endpoint if "://" in endpoint else f"http://{endpoint}"
@@ -116,14 +103,6 @@ class EndpointState:
         stale = [labels.get("gauge", "?")
                  for labels, v in metrics.get("gauge_age_seconds", ())
                  if v > stale_s]
-        # sampled per-phase p50s (--phase_profile, ISSUE 15): one
-        # column per train_phase_<name>_ms summary the host exports
-        phases = {}
-        for fam in metrics:
-            if fam.startswith("train_phase_") and fam.endswith("_ms"):
-                v = labeled(metrics, fam, quantile="0.5")
-                if v is not None:
-                    phases[fam[len("train_phase_"):-3]] = v
         return {
             "endpoint": self.endpoint,
             "steps": scalar(metrics, "train_steps"),
@@ -148,8 +127,6 @@ class EndpointState:
             "unhealthy": unhealthy,
             "stale_gauges": stale,
             "restarted": self.rates.restarted,
-            "phases": phases,
-            "phase_coverage": scalar(metrics, "health_phase_coverage"),
         }
 
 
@@ -207,32 +184,7 @@ def render(rows: List[Dict[str, Any]]) -> str:
             f"| {_f(r['req_s'])} | {_f(r['queue_depth'], 0)} "
             f"| {_f(r['loss'], 4)} "
             f"| {' '.join(bits) if bits else 'ok'} |")
-    phase_lines = render_phases(rows)
-    if phase_lines:
-        lines.append("")
-        lines.extend(phase_lines)
     return "\n".join(lines)
-
-
-def render_phases(rows: List[Dict[str, Any]]) -> List[str]:
-    """The per-phase column set (--phase_profile hosts): p50 device ms
-    per sampled phase, one row per host, columns in canonical phase
-    order — ROADMAP item 4's "where did the millisecond go" live.
-    Empty when no host exports train_phase_* summaries."""
-    with_phases = [r for r in rows if r.get("phases")]
-    if not with_phases:
-        return []
-    seen = {p for r in with_phases for p in r["phases"]}
-    cols = [p for p in _PHASE_ORDER if p in seen]
-    cols += sorted(seen - set(cols))
-    lines = ["| Host (phase p50 ms) | " + " | ".join(cols)
-             + " | coverage |",
-             "|---" * (len(cols) + 2) + "|"]
-    for r in with_phases:
-        vals = " | ".join(_f(r["phases"].get(c), 3) for c in cols)
-        lines.append(f"| {r['endpoint']} | {vals} "
-                     f"| {_f(r.get('phase_coverage'), 2)} |")
-    return lines
 
 
 def fetch_fleet(url: str, timeout_s: float = 3.0) -> Dict[str, Any]:
@@ -294,10 +246,6 @@ def render_fleet(agg: Dict[str, Any]) -> str:
             f"| {_f(r.get('loss'), 4)} | {score_bit} "
             f"| {_f(off * 1e3 if off is not None else None, 3)} "
             f"| {' '.join(bits) if bits else 'ok'} |")
-    phase_lines = render_phases(hosts)
-    if phase_lines:
-        lines.append("")
-        lines.extend(phase_lines)
     return "\n".join(lines)
 
 

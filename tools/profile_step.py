@@ -114,8 +114,8 @@ def main(argv=None) -> None:
 
     # ---- forward only ----
     def loss_fn(params, rng):
-        code, _ = encode(params, src, pth, dst, mask,
-                         compute_dtype=jnp.bfloat16)
+        code, _, _ = encode(params, src, pth, dst, mask,
+                            compute_dtype=jnp.bfloat16)
         loss, _ = sampled_softmax_loss(
             params["target_emb"], code, labels, rng, NUM_SAMPLED,
             example_weights=weights, vocab_size=TARGET_VOCAB)

@@ -13,13 +13,10 @@ Given `--telemetry_dir`'s root (or one run directory), prints
     vs-V100 ratio (bench.py's denominator), infeed-wait p95, and the
     run_id as the Source column;
   - per-run detail tables: every timer histogram (count / mean /
-    p50 / p95 / p99 / max), a phase-attribution table when the run
-    sampled phases (--phase_profile: per-phase device ms joined with
-    the analytic bytes gauges into GB/s and vs-ceiling utilization),
-    serving request percentiles, final loss, gauges, an epoch-boundary
-    table (save_blocked_ms / save_total_ms / eval_ms / save overlap
-    ratio, from the save / save_committed / eval events), and any
-    bench/profile events the run carried.
+    p50 / p95 / p99 / max), serving request percentiles, final loss,
+    gauges, an epoch-boundary table (save_blocked_ms / save_total_ms /
+    eval_ms / save overlap ratio, from the save / save_committed / eval
+    events), and any bench/profile events the run carried.
 
 Pure stdlib + the repo's own modules; reads only the manifest + events
 files, so it works on a laptop over a run dir scp'd from a pod.
@@ -159,53 +156,6 @@ def _timer_rows(events: List[Dict[str, Any]]) -> Dict[str, Dict]:
     return out
 
 
-# canonical phase order: obs/phases.PHASE_ORDER plus the trailing
-# fused_step timer (kept literal — this tool must stay runnable
-# without the repo's deps; a test pins the copy equal)
-_PHASE_ORDER = ("infeed_wait", "embed_gather", "concat_dense",
-                "forward_pool", "backward", "table_apply",
-                "backward_apply", "allreduce", "allreduce_exposed",
-                "fused_step")
-
-
-def phase_rows(events: List[Dict[str, Any]],
-               gauges: Dict[str, Any]) -> List[Dict[str, Any]]:
-    """Per-phase attribution rows from the sampled `phase` events
-    (--phase_profile, ISSUE 15): device-ms percentiles per phase,
-    joined with the static analytic-bytes gauges into achieved GB/s
-    and utilization vs the `train/phase_ceiling_gbps` ceiling — the
-    BENCH phase table shape, rebuilt from a live run's telemetry."""
-    samples: Dict[str, List[float]] = {}
-    for e in events:
-        if e.get("kind") != "phase":
-            continue
-        for k, v in e.items():
-            if not k.endswith("_ms") or not isinstance(v, (int, float)):
-                continue
-            name = "fused_step" if k == "fused_ms" else k[:-3]
-            if name in ("split_sum", "residual"):
-                continue
-            samples.setdefault(name, []).append(float(v))
-    ceiling = gauges.get("train/phase_ceiling_gbps")
-    ordered = [p for p in _PHASE_ORDER if p in samples]
-    ordered += sorted(set(samples) - set(ordered))
-    rows = []
-    for name in ordered:
-        vals = samples[name]
-        p50 = _pct(vals, 50)
-        row: Dict[str, Any] = {"phase": name, "n": len(vals),
-                               "p50_ms": p50,
-                               "p95_ms": _pct(vals, 95)}
-        nb = gauges.get(f"train/phase_bytes/{name}")
-        if isinstance(nb, (int, float)) and nb and p50 > 0:
-            row["bytes"] = int(nb)
-            row["gbps"] = nb / (p50 / 1e3) / 1e9
-            if isinstance(ceiling, (int, float)) and ceiling:
-                row["vs_ceiling"] = row["gbps"] / float(ceiling)
-        rows.append(row)
-    return rows
-
-
 def boundary_rows(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     """Epoch-boundary rows from the checkpoint/eval events: one row per
     `save` event (kind="save": loop-side blocked_ms), joined with its
@@ -309,21 +259,6 @@ def render(run_dirs: List[str]) -> str:
                 gauges[e.get("name")] = e.get("value")
             elif e.get("kind") == "summary" and e.get("gauges"):
                 gauges.update(e["gauges"])
-        # ---- sampled phase attribution (--phase_profile, ISSUE 15) ----
-        p_rows = phase_rows(events, gauges)
-        if p_rows:
-            lines.append("")
-            lines.append("| Phase | samples | p50 ms | p95 ms | bytes "
-                         "| GB/s | vs ceiling |")
-            lines.append("|---|---|---|---|---|---|---|")
-            for r in p_rows:
-                lines.append(
-                    f"| {r['phase']} | {r['n']} "
-                    f"| {_fmt(r['p50_ms'], 3)} "
-                    f"| {_fmt(r['p95_ms'], 3)} "
-                    f"| {_fmt(r.get('bytes'), 0)} "
-                    f"| {_fmt(r.get('gbps'), 1)} "
-                    f"| {_fmt(r.get('vs_ceiling'), 3)} |")
         if gauges:
             lines.append("")
             lines.append("gauges: " + ", ".join(

@@ -160,9 +160,9 @@ def main() -> None:
     # ---- encoder fwd / loss fwd / fwd+bwd / full step ----
     @jax.jit
     def enc_fn(params, src, pth, dst, mask):
-        code, _ = encode_transformer(params, src, pth, dst, mask,
-                                     dims=dims,
-                                     compute_dtype=jnp.bfloat16)
+        code, _, _ = encode_transformer(params, src, pth, dst, mask,
+                                        dims=dims,
+                                        compute_dtype=jnp.bfloat16)
         return code
 
     dt = time_fn(enc_fn, (params, src, pth, dst, mask), args.steps)
