@@ -70,7 +70,7 @@ from code2vec_tpu.models.registry import EncoderSpec
 from code2vec_tpu.models.transformer_encoder import (_rms_norm,
                                                      learned_query_pool,
                                                      padding_log_mask)
-from code2vec_tpu.ops.moe import held_experts_ffn, route
+from code2vec_tpu.ops.moe import held_experts_ffn, ran_at_bound, route
 
 # small beside the gaps between a token's top scores (about 0.016
 # between the fourth and the fifth of 64): it turns near-ties and leaves
@@ -281,8 +281,10 @@ def _routed_experts(h: jax.Array, mask: jax.Array, router: jax.Array,
                     bias: jax.Array, w1: jax.Array, w3: jax.Array,
                     w2: jax.Array, *, cfg: Lfm2Dims
                     ) -> Tuple[jax.Array, jax.Array]:
-    """(the held experts' output [B, C, H]; int32 [1, held + 1]: the
-    rows each held expert took, then the valid tokens)."""
+    """(the held experts' output [B, C, H]; int32 [1, held + 3]: the
+    rows each held expert took, the valid tokens, the rows the layer's
+    arrays may hold and whether it ran at that bound
+    (`moe.ran_at_bound`)."""
     B, C, H = h.shape
     tokens = h.reshape(B * C, H)
     valid = mask.reshape(B * C) > 0
@@ -290,8 +292,11 @@ def _routed_experts(h: jax.Array, mask: jax.Array, router: jax.Array,
         chosen, p = route(tokens, router, bias, cfg.num_experts_per_tok)
     with jax.named_scope("experts"):
         out, rows = held_experts_ffn(tokens, valid, chosen, p, w1, w3, w2,
-                                     cfg.first_expert)
-    counts = jnp.concatenate([rows, jnp.sum(valid, dtype=jnp.int32)[None]])
+                                     cfg.first_expert, cfg.routed)
+    bound, at_bound = ran_at_bound(rows, chosen.size, cfg.routed)
+    counts = jnp.concatenate([rows, jnp.stack([
+        jnp.sum(valid, dtype=jnp.int32), jnp.int32(bound),
+        at_bound.astype(jnp.int32)])])
     return out.reshape(B, C, H), counts[None]
 
 
@@ -307,9 +312,11 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The encode contract (registry.EncoderSpec): (code [B, 3E] in
     the compute dtype, pool attention [B, C] f32, aux), aux being int32
-    [expert layers, held + 1], per expert layer the rows each held
-    expert took and, last, the valid tokens (the train step hands it to
-    the spec's recorder, `obs.route`; the other steps let it fall).
+    [expert layers, held + 3], per expert layer the rows each held
+    expert took, the valid tokens, the layer's row bound and whether it
+    ran at the bound, each summed over the mesh's devices (the train
+    step hands it to the spec's recorder, `obs.route`; the other steps
+    let it fall).
     `use_pallas` is taken and not read: the
     grouped product is XLA's own kernel on the TPU, the attention XLA's
     on every backend."""
@@ -363,7 +370,7 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
                                           compute_dtype)
         code = pooled @ lfm["out_proj"].astype(compute_dtype)
     return code, attn, (jnp.stack(routes) if routes else jnp.zeros(
-        (0, cfg.num_experts + 1), jnp.int32))
+        (0, cfg.num_experts + 3), jnp.int32))
 
 
 # ---- the spec ------------------------------------------------------------

@@ -2,7 +2,8 @@
 
 A train step that runs routed experts (`--encoder lfm2_moe`) returns,
 beside the loss, one small int32 device array: per expert layer the rows
-each held expert took and, last, the valid tokens. `RouteRecorder.push`
+each held expert took, the valid tokens, the rows the layer's arrays may
+hold and whether the layer ran at that bound. `RouteRecorder.push`
 keeps it and starts its copy to the host; the record is written one or
 more steps later, when the array is ready, so the loop is never made to
 wait for a step it has only dispatched. `flush()` writes what is left
@@ -17,6 +18,13 @@ run's `--trace` JSONL (when that tracer is handed over as `tracer`):
          layers           per expert layer the rows of each held expert
          rows_here        their sum: the rows routed to experts held here
          valid_tokens     the step's valid tokens (each makes K choices)
+         row_bound        the rows an expert layer's arrays hold when its
+                          live rows fit (`ops/moe.row_bound`; every
+                          (token, choice) pair where the layer has one
+                          body only)
+         compact_layers   how many of the step's expert layers ran at
+                          that bound, the device's own decision
+                          (under a mesh both are sums over its devices)
 
 Stdlib-only, as all of `obs`: the array is used through `is_ready`,
 `copy_to_host_async` and `tolist` alone.
@@ -37,7 +45,7 @@ class RouteRecorder:
         self._seq = 0
 
     def push(self, counts) -> None:
-        """`counts`: the step's int32 [expert layers, held + 1] array,
+        """`counts`: the step's int32 [expert layers, held + 3] array,
         still on the device. It is kept; older ones are written as far
         as they are ready (a loop's run-ahead, which its own syncs
         bound, bounds what is kept)."""
@@ -54,9 +62,11 @@ class RouteRecorder:
 
     def _emit(self, seq: int, t_push: float, counts) -> None:
         table = counts.tolist()
-        attrs = dict(seq=seq, layers=[row[:-1] for row in table],
-                     rows_here=sum(sum(row[:-1]) for row in table),
-                     valid_tokens=table[0][-1] if table else 0)
+        attrs = dict(seq=seq, layers=[row[:-3] for row in table],
+                     rows_here=sum(sum(row[:-3]) for row in table),
+                     valid_tokens=table[0][-3] if table else 0,
+                     row_bound=table[0][-2] if table else 0,
+                     compact_layers=sum(row[-1] for row in table))
         now = time.monotonic()
         for tracer in (memory_tracer(), self.tracer):
             if tracer.enabled:

@@ -344,6 +344,225 @@ def test_masked_tokens_are_routed_nowhere():
                                   0.0)
 
 
+# ---- the row bound -------------------------------------------------------
+
+def _bound_case(live, n=96, E=64, first=16, held=8, K=4, H=32, F=24):
+    """A layer of `held` of E experts over n all-valid tokens whose
+    choices are set by hand: `live` of the n K pairs fall on held
+    experts (a token's on different ones), the others on experts held
+    elsewhere. At these sizes the bound is 128 of 384 pairs."""
+    k = jax.random.split(jax.random.PRNGKey(5), 5)
+    chosen = np.zeros((n, K), np.int32)
+    for t in range(n):
+        here = min(K, max(0, live - K * t))
+        for j in range(K):
+            chosen[t, j] = first + (t + j) % held if j < here \
+                else (first + held + (3 * t + j) % (E - held)) % E
+        chosen[t] = np.roll(chosen[t], t)
+    assert int(((chosen >= first) & (chosen < first + held)).sum()) == live
+    assert all(len(set(row)) == K for row in chosen.tolist())
+    p = jax.random.uniform(k[0], (n, K), minval=0.1, maxval=1.0)
+    return dict(
+        h=jax.random.normal(k[1], (n, H)), valid=jnp.ones(n, bool),
+        chosen=jnp.asarray(chosen), p=p / p.sum(-1, keepdims=True),
+        w1=0.2 * jax.random.normal(k[2], (held, H, F)),
+        w3=0.2 * jax.random.normal(k[3], (held, H, F)),
+        w2=0.2 * jax.random.normal(k[4], (held, F, H)), first=first, E=E)
+
+
+_FLOATS = ("h", "p", "w1", "w3", "w2")
+
+
+def _layer_and_grads(case, routed, wrap=lambda f: f):
+    """The layer's output, the rows, and the gradient of a weighted sum
+    of the output for h, p and the three weights."""
+    weight = jax.random.normal(jax.random.PRNGKey(9), case["h"].shape)
+
+    def run(*floats):
+        kw = dict(zip(_FLOATS, floats))
+        out, rows = moe.held_experts_ffn(
+            kw["h"], case["valid"], case["chosen"], kw["p"], kw["w1"],
+            kw["w3"], kw["w2"], case["first"], routed)
+        return jnp.sum(out * weight), (out, rows)
+
+    step = jax.jit(jax.grad(wrap(run), argnums=tuple(range(5)),
+                            has_aux=True))
+    grads, (out, rows) = step(*(case[name] for name in _FLOATS))
+    return out, rows, dict(zip(_FLOATS, grads))
+
+
+def test_row_bound_is_twice_the_even_share_up_to_a_tile():
+    # the cell: 128 methods x 200 slots, 4 choices, 8 of 64 held
+    assert moe.row_bound(25600 * 4, 8, 64) == 25600
+    assert moe.row_bound(384, 8, 64) == 128          # 96, up to the tile
+    assert moe.row_bound(1000 * 128, 8, 64) % moe.ROW_TILE == 0
+    # half or all of the experts here: nothing to leave out
+    assert moe.row_bound(192, 4, 8) == 192
+    assert moe.row_bound(102400, 64, 64) == 102400
+
+
+# 60 live pairs of 384, the bound itself, one more, and every pair
+@pytest.mark.parametrize("live,at_bound", [(60, True), (128, True),
+                                           (129, False), (384, False)])
+def test_the_bounded_layer_is_the_full_length_layer(live, at_bound,
+                                                    monkeypatch):
+    """Output, rows and the gradients for h, p, w1, w3, w2 with 8 of 64
+    experts held (arrays of 128 rows, or the overflow's 384) against the
+    one full-length body (`routed` None). The overflow runs that same
+    body: bit for bit. At the bound the products see the same rows at
+    the same offsets, so p's and the weights' gradients are the full
+    length's to the last bits of a float32 sum over rows; the output and
+    h's gradient add a token's rows in sorted order, not choice order:
+    float32 rounding."""
+    case = _bound_case(live)
+    want_out, want_rows, want = _layer_and_grads(case, None)
+    out, rows, got = _layer_and_grads(case, case["E"])
+    assert int(rows.sum()) == live
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(want_rows))
+    assert bool(moe.fits(rows, moe.row_bound(384, 8, 64))) == at_bound
+    if at_bound:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                                   rtol=1e-5, atol=1e-6)
+        for name in _FLOATS:
+            np.testing.assert_allclose(
+                np.asarray(got[name]), np.asarray(want[name]), rtol=1e-5,
+                atol=1e-6, err_msg=name)
+    else:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want_out))
+        for name in _FLOATS:
+            np.testing.assert_array_equal(
+                np.asarray(got[name]), np.asarray(want[name]), err_msg=name)
+    # which body ran: the other one, poisoned, changes nothing
+    other = "_every_pair" if at_bound else "_first_pairs"
+    monkeypatch.setattr(
+        moe, other, lambda h, *a, **kw: jnp.full_like(h, jnp.nan))
+    out2, _rows, got2 = _layer_and_grads(case, case["E"])
+    np.testing.assert_array_equal(np.asarray(out2), np.asarray(out))
+    np.testing.assert_array_equal(np.asarray(got2["w2"]),
+                                  np.asarray(got["w2"]))
+
+
+def test_the_products_live_rows_are_the_full_lengths_bit_for_bit():
+    case = _bound_case(100)
+    n, k = case["chosen"].shape
+    key = np.where((case["chosen"] >= 16) & (case["chosen"] < 24),
+                   case["chosen"] - 16, 8).reshape(-1)
+    order = jnp.argsort(jnp.asarray(key))
+    rows = jnp.bincount(jnp.asarray(key), length=9)[:8].astype(jnp.int32)
+    x = jnp.take(case["h"], order // k, axis=0)
+    weights = (case["p"], case["w1"], case["w3"], case["w2"], rows)
+    whole = moe._products(x, order, *weights)
+    head = moe._products(x[:128], order[:128], *weights)
+    np.testing.assert_array_equal(np.asarray(head), np.asarray(whole[:128]))
+    assert np.all(np.asarray(whole[100:]) == 0)
+    assert np.all(np.asarray(whole[:100]).any(axis=1))
+
+
+def test_all_experts_held_lowers_to_one_body_and_no_conditional():
+    case = _bound_case(60)
+
+    def text(routed):
+        return jax.jit(
+            lambda h: moe.held_experts_ffn(
+                h, case["valid"], case["chosen"], case["p"], case["w1"],
+                case["w3"], case["w2"], case["first"], routed)[0]
+        ).lower(case["h"]).as_text()
+
+    assert "stablehlo.case" in text(64)
+    for one_body in (text(None), text(8), text(16)):
+        assert "stablehlo.case" not in one_body
+        assert "stablehlo.if" not in one_body
+
+
+def test_gradients_under_remat_are_the_unrematted_ones():
+    """`encode_lfm2_moe` runs each layer under `jax.checkpoint` inside
+    the step's jit."""
+    case = _bound_case(90)
+    _out, _rows, plain = _layer_and_grads(case, case["E"])
+    _out, _rows, remat = _layer_and_grads(case, case["E"],
+                                          wrap=jax.checkpoint)
+    for name in _FLOATS:
+        np.testing.assert_array_equal(np.asarray(remat[name]),
+                                      np.asarray(plain[name]), err_msg=name)
+
+
+def _eight_of_64():
+    block = dict(BLOCK, num_experts=8, num_routed_experts=64,
+                 first_expert=16, num_experts_per_tok=4)
+    return dataclasses.replace(DIMS, lfm=Lfm2Dims.from_config(block))
+
+
+@pytest.mark.parametrize("all_here", [False, True])
+def test_encoder_counts_the_bound_and_the_layers_that_ran_at_it(all_here):
+    """aux, per expert layer: the held experts' rows, the valid tokens,
+    the row bound (8 methods x 12 slots x 4 choices = 384 pairs, 128
+    held) and whether the layer ran at it; not when every token's
+    choices fall on held experts (the overflow)."""
+    dims = _eight_of_64()
+    params = init_params(jax.random.PRNGKey(3), dims)
+    _labels, src, pth, dst, mask, _w = batches()[0]
+    if all_here:
+        mask = np.ones_like(mask)
+        for layer in params["lfm"]["layers"][1:]:
+            layer["expert_bias"] = layer["expert_bias"].at[16:20].set(10.0)
+    encode = jax.jit(
+        lambda p: get_encode_fn(dims)(p, src, pth, dst, jnp.asarray(mask)))
+    _code, _attn, aux = encode(params)
+    aux = np.asarray(aux)
+    assert aux.shape == (2, 8 + 3)
+    assert aux[:, -3].tolist() == [int(mask.sum())] * 2
+    assert aux[:, -2].tolist() == [128, 128]
+    if all_here:
+        assert aux[:, :8].sum(axis=1).tolist() == [384, 384]
+        assert aux[:, -1].tolist() == [0, 0]
+    else:
+        assert np.all(aux[:, :8].sum(axis=1) <= 128)
+        assert aux[:, -1].tolist() == [1, 1]
+
+
+def test_under_a_mesh_every_device_decides_for_its_own_rows():
+    """Four devices, 4 methods x 12 slots x 4 choices = 192 pairs and a
+    bound of 128 each: aux sums the devices' bounds and decisions, the
+    code vector and its gradient are the one-device encoder's."""
+    from code2vec_tpu.parallel.mesh import make_mesh
+
+    dims = _eight_of_64()
+    params = init_params(jax.random.PRNGKey(3), dims)
+    _labels, src, pth, dst, mask, _w = batches(n=16)[0]
+    mesh = make_mesh(0, 1, devices=jax.devices()[:4])
+
+    def run(mesh):
+        def loss(p):
+            code, _attn, aux = get_encode_fn(dims)(
+                p, src, pth, dst, jnp.asarray(mask), mesh=mesh)
+            return jnp.sum(code ** 2), (code, aux)
+        return jax.jit(jax.grad(loss, has_aux=True))
+
+    on_mesh, on_one = run(mesh), run(None)
+    grads, (code, aux) = on_mesh(params)
+    want_grads, (want_code, want_aux) = on_one(params)
+    aux, want_aux = np.asarray(aux), np.asarray(want_aux)
+    assert aux[:, -2:].tolist() == [[4 * 128, 4]] * 2
+    assert want_aux[:, -2:].tolist() == [[256, 1]] * 2
+    np.testing.assert_array_equal(aux[:, :-2], want_aux[:, :-2])
+    np.testing.assert_allclose(np.asarray(code), np.asarray(want_code),
+                               rtol=1e-4, atol=1e-5)
+    got, want = flat(grads["lfm"]), flat(want_grads["lfm"])
+    for name in ("layers/1/w1", "layers/2/w2", "layers/1/router"):
+        np.testing.assert_allclose(np.asarray(got[name]),
+                                   np.asarray(want[name]), rtol=1e-3,
+                                   atol=1e-5, err_msg=name)
+
+
+def test_encoder_with_half_the_experts_here_has_one_body():
+    _dims, params, _ = program_weights()
+    _labels, src, pth, dst, mask, _w = batches()[0]
+    _code, _attn, aux = get_encode_fn(DIMS)(params, src, pth, dst,
+                                            jnp.asarray(mask))
+    # 4 of 8 held: the bound is every pair (8 x 12 x 2), never "compact"
+    assert np.asarray(aux)[:, -2:].tolist() == [[192, 0]] * 2
+
+
 def test_compiles_stay_zero_across_batches_of_different_routing():
     import optax
 
@@ -450,15 +669,16 @@ def test_route_records_are_written_a_step_behind_and_never_waited_for(
     monkeypatch.setattr(trace, "_MEMORY_TRACER", trace.MemoryTracer())
     records = lambda: trace.memory_tracer().records("moe/route")  # noqa: E731
     rec = route.RouteRecorder()
-    first = _FakeCounts([[3, 1, 9], [2, 2, 9]])
+    first = _FakeCounts([[3, 1, 9, 128, 1], [2, 2, 9, 128, 0]])
     rec.push(first)
     assert first.copied and records() == []      # never the step just sent
-    slow = _FakeCounts([[1, 1, 5]], ready=False)
+    slow = _FakeCounts([[1, 1, 5, 128, 1]], ready=False)
     rec.push(slow)
     (only,) = records()
     assert only["attrs"] == {"seq": 0, "layers": [[3, 1], [2, 2]],
-                             "rows_here": 8, "valid_tokens": 9}
-    rec.push(_FakeCounts([[0, 0, 0]]))
+                             "rows_here": 8, "valid_tokens": 9,
+                             "row_bound": 128, "compact_layers": 1}
+    rec.push(_FakeCounts([[0, 0, 0, 128, 1]]))
     assert len(records()) == 1                   # the unready one is not awaited
     rec.flush()
     assert [r["attrs"]["seq"] for r in records()] == [0, 1, 2]
@@ -517,8 +737,14 @@ def test_model_trains_evaluates_predicts_and_reloads(tmp_path):
     assert (route["expert_layers"], route["held_experts"]) == (2, 4)
     assert 0 < route["rows_here"] <= 2 * 2 * route["valid_tokens"]
     assert route["imbalance"] >= 1.0
-    assert f"Routed experts: {route['rows_here']:,} rows" in render(
-        [({}, spans)])
+    report = render([({}, spans)])
+    assert f"Routed experts: {route['rows_here']:,} rows" in report
+    # 4 of 8 experts are held: one body over every pair (32 methods x
+    # 16 slots x 2 choices, summed over the 8 devices)
+    assert route["row_bound"] == 32 * 16 * 2
+    assert route["compact_layers"] == 0
+    assert f"row_bound {route['row_bound']:,}, compact_layers 0 of " \
+        f"{2 * model.step_num}" in report
     assert route_summary([s for s in spans if s["name"] != "moe/route"]) \
         is None
 

@@ -35,8 +35,8 @@ emits) and produces:
     (`gather_slots`: what the step chosen for each batch took table
     rows for, the staircase's area or every slot), and for a routed-experts
     encoder the `moe/route` records: rows routed to the experts held
-    here over the valid tokens' choices, and the fullest expert's rows
-    over the mean.
+    here over the valid tokens' choices, the fullest expert's rows
+    over the mean, and the spans' `row_bound` and `compact_layers`.
 
 Pure stdlib; reads only manifest + events files, so it works on a
 laptop over a run dir scp'd from a pod (same contract as
@@ -341,9 +341,11 @@ def route_summary(spans: Sequence[Dict[str, Any]]
                   ) -> Optional[Dict[str, Any]]:
     """The run's `moe/route` spans (one a train step of an encoder with
     routed experts; obs/route.py) summed: steps, rows routed to the
-    experts held here, valid tokens, expert layers, and the mean over
-    the steps of the fullest held expert's rows over the mean expert's,
-    by the worst layer. None when the run has none."""
+    experts held here, valid tokens, expert layers, the mean over the
+    steps of the fullest held expert's rows over the mean expert's, by
+    the worst layer, and, where the spans carry them, the rows an expert
+    layer's arrays hold (`row_bound`) and the layers that ran at that
+    bound over the run (`compact_layers`). None when the run has none."""
     routes = [s.get("attrs") or {} for s in spans
               if s["name"] == "moe/route"]
     routes = [a for a in routes if a.get("layers")]
@@ -352,12 +354,16 @@ def route_summary(spans: Sequence[Dict[str, Any]]
     worst = [max(max(rows) * len(rows) / sum(rows)
                  for rows in a["layers"] if sum(rows))
              for a in routes if a["rows_here"]]
-    return {"steps": len(routes),
-            "rows_here": sum(a["rows_here"] for a in routes),
-            "valid_tokens": sum(a["valid_tokens"] for a in routes),
-            "expert_layers": len(routes[0]["layers"]),
-            "held_experts": len(routes[0]["layers"][0]),
-            "imbalance": sum(worst) / len(worst) if worst else None}
+    out = {"steps": len(routes),
+           "rows_here": sum(a["rows_here"] for a in routes),
+           "valid_tokens": sum(a["valid_tokens"] for a in routes),
+           "expert_layers": len(routes[0]["layers"]),
+           "held_experts": len(routes[0]["layers"][0]),
+           "imbalance": sum(worst) / len(worst) if worst else None}
+    if all("row_bound" in a and "compact_layers" in a for a in routes):
+        out["row_bound"] = routes[0]["row_bound"]
+        out["compact_layers"] = sum(a["compact_layers"] for a in routes)
+    return out
 
 
 def save_breakdowns(spans: Sequence[Dict[str, Any]]
@@ -477,7 +483,11 @@ def render(loaded, limit: int = 10) -> str:
                 f"{route['held_experts']} experts held here in "
                 f"{route['expert_layers']} layers over {route['steps']} "
                 f"steps ({route['valid_tokens']:,} valid tokens), "
-                f"fullest expert {_fmt(route['imbalance'])}x the mean")
+                f"fullest expert {_fmt(route['imbalance'])}x the mean"
+                + (f"; row_bound {route['row_bound']:,}, compact_layers "
+                   f"{route['compact_layers']:,} of "
+                   f"{route['expert_layers'] * route['steps']:,}"
+                   if "row_bound" in route else ""))
         save_rows = save_breakdowns(spans)
         if save_rows:
             lines.append("")
