@@ -2,7 +2,7 @@
 """Chip smoke: drive training and serving once on the TPU, at java-large
 width, through the entry points a user calls.
 
-    python3 chip_smoke.py [--config bag|transformer|int8|sparse|lfm2_moe]
+    python3 chip_smoke.py [--config bag|transformer|int8|sparse|lfm2_moe|qwen3_next]
 
 One process, phases in order, the first failure ends the run with a
 non-zero exit and no result line:
@@ -60,7 +60,23 @@ LFM_BLOCK = {"layer_types": ["conv", "full_attention"],
              "num_experts": 8, "num_routed_experts": 64, "first_expert": 0,
              "num_experts_per_tok": 4, "conv_L_cache": 3, "norm_eps": 1e-5,
              "rope_parameters": {"rope_theta": 1000000}}
-CONFIG_BATCH = {"lfm2_moe": 128}
+# qwen3_next at Qwen3-Next-80B-A3B's published widths and a small depth
+# (one gated-DeltaNet layer, one gated-attention layer, each with 8 of
+# 512 routed experts beside the shared one): 112M float32 parameters
+QWEN_BLOCK = {"num_hidden_layers": 2, "full_attention_interval": 2,
+              "hidden_size": 2048, "num_attention_heads": 16,
+              "num_key_value_heads": 2, "head_dim": 256,
+              "partial_rotary_factor": 0.25, "rope_theta": 10000000,
+              "rms_norm_eps": 1e-6, "linear_conv_kernel_dim": 4,
+              "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+              "linear_num_key_heads": 16, "linear_num_value_heads": 32,
+              "num_experts": 8, "num_routed_experts": 512,
+              "first_expert": 0, "num_experts_per_tok": 10,
+              "moe_intermediate_size": 512,
+              "shared_expert_intermediate_size": 512}
+# the encoders that read their sizes from a file (--block_config)
+BLOCKS = {"lfm2_moe": LFM_BLOCK, "qwen3_next": QWEN_BLOCK}
+CONFIG_BATCH = {"lfm2_moe": 128, "qwen3_next": 128}
 
 CONFIG_FLAGS = {
     "bag": [],
@@ -69,6 +85,7 @@ CONFIG_FLAGS = {
     "sparse": ["--sparse_embeddings", "--embedding_optimizer", "adam",
                "--lr_schedule", "constant"],
     "lfm2_moe": ["--encoder", "lfm2_moe"],
+    "qwen3_next": ["--encoder", "qwen3_next"],
 }
 
 
@@ -232,11 +249,11 @@ def train_phase(out_dir: str, prefix: str, config: str,
             "--max_contexts", str(MAX_CONTEXTS), "--epochs", "1",
             "--backend", _BACKEND, "--telemetry_dir", tele_dir,
             *CONFIG_FLAGS[config]]
-    if config == "lfm2_moe":
-        block = os.path.join(out_dir, "lfm_block.json")
+    if config in BLOCKS:
+        block = os.path.join(out_dir, "block.json")
         with open(block, "w") as f:
-            json.dump(LFM_BLOCK, f)
-        argv += ["--lfm_config", block]
+            json.dump(BLOCKS[config], f)
+        argv += ["--block_config", block]
     print(f"chip_smoke: code2vec.main({' '.join(argv)})", flush=True)
     rc = code2vec.main(argv)
     check(rc == 0, "train", f"code2vec.main returned {rc}")
@@ -308,6 +325,7 @@ def serve_phase(ckpt_dir: str, val_lines, config: str) -> dict:
     from code2vec_tpu.serving.interactive_predict import \
         InteractivePredictor
 
+    block_encoder = config in BLOCKS
     config = Config.load_from_args(
         ["--load", ckpt_dir, "--predict", "--backend", _BACKEND])
     model = Code2VecModel(config)
@@ -338,9 +356,9 @@ def serve_phase(ckpt_dir: str, val_lines, config: str) -> dict:
         # Methods asked before come out of the cache, answered in batches
         # of other sizes; a 2048-wide encoder's matmuls round differently
         # from one batch shape to another and swap near-tied names, so
-        # lfm2_moe is asked methods not asked before: one batch of one
-        # size on both sides
-        first = at if config == "lfm2_moe" else 0
+        # a block encoder is asked methods not asked before: one batch of
+        # one size on both sides
+        first = at if block_encoder else 0
         lines = val_lines[first:first + REQUEST_SIZES[3]]
         direct = model.predict(lines)
         served = server.predict_lines(lines)

@@ -241,16 +241,20 @@ class Config:
     # ---- encoder architecture, a name of models/registry.py: "bag"
     # (reference parity), "transformer" (set transformer over the
     # contexts, models/transformer_encoder.py; BASELINE.json
-    # configs[4]) or "lfm2_moe" (the LFM2-MoE decoder block over the
-    # contexts in reader order, models/lfm2_moe_encoder.py). ----
+    # configs[4]), "lfm2_moe" (the LFM2-MoE decoder block over the
+    # contexts in reader order, models/lfm2_moe_encoder.py) or
+    # "qwen3_next" (Qwen3-Next's: gated DeltaNet and gated attention,
+    # routed experts beside a shared one,
+    # models/qwen3_next_encoder.py). ----
     ENCODER_TYPE: str = "bag"
-    # lfm2_moe: the block's sizes, a JSON file under the keys of the
-    # model's own config.json (layer_types, hidden_size, num_experts =
+    # lfm2_moe, qwen3_next: the block's sizes, a JSON file under the
+    # keys of the model's own config.json (hidden_size, num_experts =
     # the experts held HERE, num_routed_experts, first_expert, ...;
-    # models/lfm2_moe_encoder.Lfm2Dims).
+    # models/lfm2_moe_encoder.Lfm2Dims,
+    # models/qwen3_next_encoder.Qwen3NextDims).
     # benchmark/configs/java-large-lfm2moe.json is one chip's share of
-    # LFM2-24B-A2B.
-    LFM_CONFIG: Optional[str] = None
+    # LFM2-24B-A2B, java-large-qwen3next.json of Qwen3-Next-80B-A3B.
+    BLOCK_CONFIG: Optional[str] = None
     XF_LAYERS: int = 2
     # 3 heads -> head_dim = 384/3 = 128 = one MXU lane width: measured
     # 9% faster through the fused attention kernels at IDENTICAL
@@ -538,11 +542,14 @@ class Config:
         p.add_argument("--num_sampled", dest="num_sampled", type=int, default=None)
         p.add_argument("--encoder", dest="encoder", default=None,
                        choices=list(encoder_names()))
-        p.add_argument("--lfm_config", dest="lfm_config", default=None,
-                       help="--encoder lfm2_moe: JSON file with the "
-                            "block's sizes under the model's config.json "
-                            "keys (num_experts = experts held here, "
-                            "num_routed_experts, first_expert)")
+        p.add_argument("--block_config", "--lfm_config",
+                       dest="block_config", default=None,
+                       help="--encoder lfm2_moe | qwen3_next: JSON file "
+                            "with the block's sizes under the model's "
+                            "config.json keys (num_experts = experts "
+                            "held here, num_routed_experts, "
+                            "first_expert); --lfm_config is another "
+                            "spelling of the same option")
         p.add_argument("--xf_layers", dest="xf_layers", type=int,
                        default=None)
         p.add_argument("--xf_heads", dest="xf_heads", type=int,
@@ -811,8 +818,8 @@ class Config:
             cfg.NUM_SAMPLED_CLASSES = ns.num_sampled
         if ns.encoder is not None:
             cfg.ENCODER_TYPE = ns.encoder
-        if ns.lfm_config is not None:
-            cfg.LFM_CONFIG = ns.lfm_config
+        if ns.block_config is not None:
+            cfg.BLOCK_CONFIG = ns.block_config
         if ns.xf_layers is not None:
             cfg.XF_LAYERS = ns.xf_layers
         if ns.xf_heads is not None:

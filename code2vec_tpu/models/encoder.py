@@ -80,6 +80,8 @@ class ModelDims:
     ring_attention: bool = False
     # lfm2_moe's own sizes (models/lfm2_moe_encoder.Lfm2Dims)
     lfm: Optional[Any] = None
+    # qwen3_next's (models/qwen3_next_encoder.Qwen3NextDims)
+    qwen: Optional[Any] = None
 
     @property
     def context_vector_size(self) -> int:

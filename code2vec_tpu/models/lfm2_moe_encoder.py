@@ -52,6 +52,14 @@ runs without its exchange, and what absent experts would add is left
 out. Under a mesh every device routes its own rows of the batch
 (`shard_map`), the weights replicated. Each layer is rematerialised in
 the backward pass: at H = 2048 a layer's activations are the memory.
+
+What this block shares with `qwen3_next_encoder.py` lives in
+`models/seq_block.py`: the input and output projections and the pool
+(`run_block`), the rematerialised residual layer and its scopes
+(`residual_layer`), the rotary term, the grouped-query attention, the
+SwiGLU and the routed experts' wrapper that makes the counts. Here: the
+sizes, the weights, the short convolution, the sigmoid router's bias
+and which layer runs what.
 """
 
 from __future__ import annotations
@@ -65,12 +73,11 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from code2vec_tpu.models import seq_block
 from code2vec_tpu.models.encoder import ModelDims, embed_contexts
 from code2vec_tpu.models.registry import EncoderSpec
-from code2vec_tpu.models.transformer_encoder import (_rms_norm,
-                                                     learned_query_pool,
-                                                     padding_log_mask)
-from code2vec_tpu.ops.moe import held_experts_ffn, ran_at_bound, route
+from code2vec_tpu.models.transformer_encoder import _rms_norm
+from code2vec_tpu.ops.moe import route
 
 # small beside the gaps between a token's top scores (about 0.016
 # between the fourth and the fifth of 64): it turns near-ties and leaves
@@ -82,7 +89,7 @@ BIAS_SCALE = 0.005
 class Lfm2Dims:
     """The block's sizes, under the keys of the model's own
     `config.json` (`model_type` `lfm2_moe`); every one comes from the
-    file `--lfm_config` names.
+    file `--block_config` (also spelled `--lfm_config`) names.
     `num_experts` counts the experts whose weights THIS process holds,
     `first_expert` the first of them, and `num_routed_experts` the
     router's width (None: all are held here, as the published file
@@ -128,7 +135,8 @@ class Lfm2Dims:
         missing = [f.name for f in dataclasses.fields(cls)
                    if f.default is dataclasses.MISSING and f.name not in kw]
         if missing:
-            raise ValueError(f"lfm2_moe: the block's file lacks {missing}")
+            raise ValueError("lfm2_moe: the block's file (--block_config, "
+                             f"also spelled --lfm_config) lacks {missing}")
         kw["layer_types"] = tuple(kw["layer_types"])
         dims = cls(**kw)
         dims.check()
@@ -213,7 +221,7 @@ def init_lfm_params(rng: jax.Array, dims: ModelDims) -> Dict:
             "layers": layers}
 
 
-# ---- the two operators ---------------------------------------------------
+# ---- the operator of its own ---------------------------------------------
 
 def _short_conv(h: jax.Array, mask: jax.Array, layer: Dict) -> jax.Array:
     dtype, C = h.dtype, h.shape[1]
@@ -224,80 +232,6 @@ def _short_conv(h: jax.Array, mask: jax.Array, layer: Dict) -> jax.Array:
     padded = jnp.pad(v, ((0, 0), (taps - 1, 0), (0, 0)))
     w = sum(kernel[:, j] * padded[:, j:j + C, :] for j in range(taps))
     return (g * w) @ layer["conv_out"].astype(dtype)
-
-
-def _rotary(x: jax.Array, theta: float) -> jax.Array:
-    """x [B, heads, C, hd], position = index along C; the whole head
-    turns, pairs (i, i + hd/2) (rotate-half)."""
-    C, hd = x.shape[-2], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    angle = jnp.arange(C, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
-    x32 = x.astype(jnp.float32)
-    half = jnp.concatenate([-x32[..., hd // 2:], x32[..., :hd // 2]], -1)
-    return (x32 * cos + half * sin).astype(x.dtype)
-
-
-def _attention(h: jax.Array, mask: jax.Array, layer: Dict,
-               cfg: Lfm2Dims) -> jax.Array:
-    dtype = h.dtype
-    B, C, H = h.shape
-    n, n_kv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
-
-    def heads(t, count, scale=None):
-        t = t.reshape(B, C, count, hd)
-        if scale is not None:
-            t = _rms_norm(t, scale, cfg.norm_eps)
-        return t.transpose(0, 2, 1, 3)                 # [B, count, C, hd]
-
-    q = _rotary(heads(h @ layer["q"].astype(dtype), n, layer["q_norm"]),
-                cfg.rope_theta)
-    k = _rotary(heads(h @ layer["k"].astype(dtype), n_kv, layer["k_norm"]),
-                cfg.rope_theta)
-    v = heads(h @ layer["v"].astype(dtype), n_kv)
-    q = q.reshape(B, n_kv, n // n_kv, C, hd)
-    logits = jnp.einsum("bkgqd,bkcd->bkgqc", q, k).astype(jnp.float32) \
-        / math.sqrt(hd)
-    slot = jnp.arange(C)
-    seen = (slot[None, :] <= slot[:, None])[None] & (mask > 0)[:, None, :]
-    logits = jnp.where(seen[:, None, None], logits, -1e30)
-    att = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    out = jnp.einsum("bkgqc,bkcd->bkgqd", att, v)
-    out = out.reshape(B, n, C, hd).transpose(0, 2, 1, 3).reshape(B, C, H)
-    return out @ layer["o"].astype(dtype)
-
-
-# ---- the two feed-forwards -----------------------------------------------
-
-def _dense_mlp(h: jax.Array, layer: Dict) -> jax.Array:
-    dtype = h.dtype
-    return (jax.nn.silu(h @ layer["w1"].astype(dtype))
-            * (h @ layer["w3"].astype(dtype))) @ layer["w2"].astype(dtype)
-
-
-def _routed_experts(h: jax.Array, mask: jax.Array, router: jax.Array,
-                    bias: jax.Array, w1: jax.Array, w3: jax.Array,
-                    w2: jax.Array, *, cfg: Lfm2Dims
-                    ) -> Tuple[jax.Array, jax.Array]:
-    """(the held experts' output [B, C, H]; int32 [1, held + 3]: the
-    rows each held expert took, the valid tokens, the rows the layer's
-    arrays may hold and whether it ran at that bound
-    (`moe.ran_at_bound`)."""
-    B, C, H = h.shape
-    tokens = h.reshape(B * C, H)
-    valid = mask.reshape(B * C) > 0
-    with jax.named_scope("router"):
-        chosen, p = route(tokens, router, bias, cfg.num_experts_per_tok)
-    with jax.named_scope("experts"):
-        out, rows = held_experts_ffn(tokens, valid, chosen, p, w1, w3, w2,
-                                     cfg.first_expert, cfg.routed)
-    bound, at_bound = ran_at_bound(rows, chosen.size, cfg.routed)
-    counts = jnp.concatenate([rows, jnp.stack([
-        jnp.sum(valid, dtype=jnp.int32), jnp.int32(bound),
-        at_bound.astype(jnp.int32)])])
-    return out.reshape(B, C, H), counts[None]
 
 
 # ---- the encoder ---------------------------------------------------------
@@ -322,55 +256,51 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
     on every backend."""
     del use_pallas
     cfg, lfm = dims.lfm, params["lfm"]
-    eps = cfg.norm_eps
+    norm = functools.partial(_rms_norm, eps=cfg.norm_eps)
     emb = embed_contexts(params, source_ids, path_ids, target_ids,
                          dropout_rng, dropout_keep_rate, compute_dtype,
                          staircase, mesh)
-    experts = functools.partial(_routed_experts, cfg=cfg)
+
+    def _routed_experts(h, mask, router, bias, w1, w3, w2):
+        return seq_block.routed_experts(
+            h, mask, lambda tokens: route(tokens, router, bias,
+                                          cfg.num_experts_per_tok),
+            w1, w3, w2, first_expert=cfg.first_expert, routed=cfg.routed)
+
+    experts = _routed_experts
     if mesh is not None:
         # each device routes its own rows of the batch
         from code2vec_tpu.parallel.sharding import shard_map_over_batch
         experts = shard_map_over_batch(experts, mesh,
                                        (True, True) + (False,) * 5)
 
+    def mixer(h, layer):
+        if "conv_k" in layer:
+            return _short_conv(h, mask, layer)
+        return seq_block.attention(
+            h, mask, layer, heads=cfg.num_attention_heads,
+            kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+            theta=cfg.rope_theta, norm=norm)
+
+    def dense(h, layer):
+        return seq_block.swiglu(h, layer["w1"], layer["w3"],
+                                layer["w2"]), None
+
+    def routed(h, layer):
+        return experts(h, mask, layer["router"], layer["expert_bias"],
+                       layer["w1"], layer["w3"], layer["w2"])
+
     def layer_fn(i: int):
         conv = cfg.layer_types[i] == "conv"
+        moe = _is_moe(cfg, i)
+        return seq_block.residual_layer(
+            i, norm=norm, mixer_scope="conv" if conv else "attn",
+            mixer=mixer, ff=routed if moe else dense,
+            ff_scope=None if moe else "mlp")
 
-        def run(x, layer):
-            h = _rms_norm(x, layer["op_norm"], eps)
-            with jax.named_scope(f"c2v/blk_{i}/{'conv' if conv else 'attn'}"):
-                x = x + (_short_conv(h, mask, layer) if conv
-                         else _attention(h, mask, layer, cfg))
-            h = _rms_norm(x, layer["ff_norm"], eps)
-            if not _is_moe(cfg, i):
-                with jax.named_scope(f"c2v/blk_{i}/mlp"):
-                    return x + _dense_mlp(h, layer), None
-            with jax.named_scope(f"c2v/blk_{i}"):
-                ff, counts = experts(h, mask, layer["router"],
-                                     layer["expert_bias"], layer["w1"],
-                                     layer["w3"], layer["w2"])
-            return x + ff, jnp.sum(counts, axis=0)
-
-        return jax.checkpoint(run)
-
-    routes = []
-    with jax.named_scope("c2v/encode"):
-        # masked slots enter as zeros
-        x = (emb @ lfm["in_proj"].astype(compute_dtype)) \
-            * mask[..., None].astype(compute_dtype)
-        for i, layer in enumerate(lfm["layers"]):
-            x, counts = layer_fn(i)(x, layer)
-            if counts is not None:
-                routes.append(counts)
-
-    with jax.named_scope("c2v/pool"):
-        x = _rms_norm(x, lfm["ln_f_scale"], eps)
-        pooled, attn = learned_query_pool(x, lfm["pool_query"],
-                                          padding_log_mask(mask),
-                                          compute_dtype)
-        code = pooled @ lfm["out_proj"].astype(compute_dtype)
-    return code, attn, (jnp.stack(routes) if routes else jnp.zeros(
-        (0, cfg.num_experts + 3), jnp.int32))
+    return seq_block.run_block(lfm, emb, mask, compute_dtype,
+                               layer_fn=layer_fn, norm=norm,
+                               counts_width=cfg.num_experts + 3)
 
 
 # ---- the spec ------------------------------------------------------------
@@ -380,8 +310,9 @@ def _init(rng: jax.Array, dims: ModelDims) -> Dict:
 
 
 def _sizes_from_config(cfg) -> Dict:
-    """`--lfm_config`'s file (`check_config` has seen that it is named)."""
-    with open(cfg.LFM_CONFIG) as f:
+    """`--block_config`'s file (`check_config` has seen that it is
+    named; `--lfm_config` is the same option)."""
+    with open(cfg.BLOCK_CONFIG) as f:
         return {"lfm": Lfm2Dims.from_config(json.load(f))}
 
 
@@ -390,15 +321,8 @@ def _sizes_from_manifest(manifest: dict) -> Dict:
 
 
 def _check_config(cfg) -> None:
-    if cfg.RING_ATTENTION or cfg.MESH_CONTEXT_AXIS > 1:
-        raise ValueError(
-            "--encoder lfm2_moe has no ring attention and no "
-            "context-parallel layout (its causal convolution and "
-            "mask run over whole sequences).")
-    if not cfg.LFM_CONFIG and not cfg.is_loading:
-        raise ValueError(
-            "--encoder lfm2_moe needs --lfm_config <json> (the "
-            "block's sizes; a checkpoint carries its own).")
+    seq_block.refuse_context_parallel(cfg, "lfm2_moe")
+    seq_block.require_block_config(cfg, "lfm2_moe")
 
 
 def _recorder():

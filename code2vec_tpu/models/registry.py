@@ -65,6 +65,7 @@ _MODULES = {
     "bag": "code2vec_tpu.models.encoder",
     "transformer": "code2vec_tpu.models.transformer_encoder",
     "lfm2_moe": "code2vec_tpu.models.lfm2_moe_encoder",
+    "qwen3_next": "code2vec_tpu.models.qwen3_next_encoder",
 }
 
 

@@ -7,6 +7,11 @@ this process holds.
   p_e    = s_e / (sum of the K chosen s + 1e-6)
   FF(h)  = sum over chosen e held here of p_e (silu(h W1_e) * (h W3_e)) W2_e
 
+or, the second router (`score="softmax"`, `model_type` `qwen3_next`):
+
+  s      = softmax(h W_r) over all E           float32, no bias
+  chosen = top K of s ;  p_e = s_e / (sum of the K chosen s)
+
 The layer is told the first expert it holds and how many; it routes over
 all E and computes its own part. What the absent experts would add is
 left out, and nothing stands in for the exchange that would bring other
@@ -51,14 +56,22 @@ import jax.numpy as jnp
 ROUTE_EPS = 1e-6
 
 
-def route(h: jax.Array, router: jax.Array, bias: jax.Array,
-          top_k: int) -> Tuple[jax.Array, jax.Array]:
+def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array],
+          top_k: int, score: str = "sigmoid"
+          ) -> Tuple[jax.Array, jax.Array]:
     """(chosen experts [N, K] int32, their weights p [N, K] float32).
     The scores are taken in float32 at the highest matmul precision:
     2 H E operations a token, and who is chosen should not hang on a
-    bfloat16 product."""
+    bfloat16 product. `score` "softmax" takes no bias."""
     logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        assert bias is None
+        s_chosen, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                         top_k)
+        return chosen.astype(jnp.int32), \
+            s_chosen / jnp.sum(s_chosen, axis=-1, keepdims=True)
+    assert score == "sigmoid", score
     s = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     s_chosen = jnp.take_along_axis(s, chosen, axis=-1)
@@ -160,30 +173,60 @@ def _every_pair(h, p, w1, w3, w2, order, rows):
     return _gather_back(y, order, inverse, k)
 
 
-def _first_pairs(h, p, w1, w3, w2, order, rows, bound: int):
-    """The body over the first `bound` sorted pairs: right when `fits`."""
+def _take_back_head(y, order, k: int):
+    """[R, H] -> [N, H], `_sum_back_head` with no scatter: each of the
+    N K pairs takes the row at its place in the sorted order, a zero row
+    where that place is past R, and a token's K rows are summed. It
+    reads N K rows where the scatter adds R, so it is the sum back of
+    the programs that take no gradient (`_bounded`)."""
+    padded = jnp.concatenate([y, jnp.zeros((1, y.shape[-1]), y.dtype)])
+    place = jnp.minimum(jnp.argsort(order), y.shape[0])
+    rows = jnp.take(padded, place, axis=0)
+    return jnp.sum(rows.reshape(-1, k, y.shape[-1]), axis=1)
+
+
+def _first_pairs(h, p, w1, w3, w2, order, rows, bound: int,
+                 scatter: bool = True):
+    """The body over the first `bound` sorted pairs: right when `fits`.
+    `scatter` False: the rows go back by `_take_back_head`."""
+    k = p.shape[1]
     head = order[:bound]
-    token = head // p.shape[1]
+    token = head // k
     y = _products(_spread_head(h, token), head, p, w1, w3, w2, rows)
+    if not scatter:
+        return _take_back_head(y, order, k)
     return _sum_back_head(y, token, h.shape[0])
 
 
-def _bodies(bound: int):
+def _bodies(bound: int, scatter: bool = True):
     """(the body where the live pairs fit `bound`, the body where not)"""
-    return functools.partial(_first_pairs, bound=bound), _every_pair
+    return (functools.partial(_first_pairs, bound=bound, scatter=scatter),
+            _every_pair)
+
+
+def _differentiated(h, p, w1, w3, w2, order, rows, bound: int):
+    return jax.lax.cond(fits(rows, bound), *_bodies(bound),
+                        h, p, w1, w3, w2, order, rows)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _bounded(h, p, w1, w3, w2, order, rows, bound: int):
     """`_first_pairs` where the layer's live pairs fit `bound`,
-    `_every_pair` where not. The backward keeps the inputs alone and
-    takes, behind the same test, the vjp of the body that ran."""
-    return jax.lax.cond(fits(rows, bound), *_bodies(bound),
+    `_every_pair` where not. Under differentiation the forward is
+    `_differentiated`, whose rows go back by a scatter-add of R rows,
+    and the backward keeps the inputs alone and takes, behind the same
+    test, the vjp of the body that ran. This, the function itself, is
+    what evaluation, prediction and serving run, and it holds no
+    scatter: on the v5e XLA's scatter-add of 512 rows into
+    [1600, 2048], as it stands inside the qwen3_next predict step of 8
+    methods, does not return (PERF.md section 6, PR 32), so the
+    programs that have to answer add nothing by index."""
+    return jax.lax.cond(fits(rows, bound), *_bodies(bound, scatter=False),
                         h, p, w1, w3, w2, order, rows)
 
 
 def _bounded_fwd(h, p, w1, w3, w2, order, rows, bound):
-    return (_bounded(h, p, w1, w3, w2, order, rows, bound),
+    return (_differentiated(h, p, w1, w3, w2, order, rows, bound),
             (h, p, w1, w3, w2, order, rows))
 
 

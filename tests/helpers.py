@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import os
 import random
+import re
 
 from code2vec_tpu.data import binarize as binarize_mod
 from code2vec_tpu.data import preprocess as preprocess_mod
@@ -36,6 +37,14 @@ def make_raw_lines(n: int, seed: int = 0, max_ctx: int = 12):
             ctxs.append(f"{tok_a},{path},{tok_b}")
         lines.append(target + " " + " ".join(ctxs))
     return lines
+
+
+def float_scatters(text: str):
+    """The result types of a lowered program's scatters over floats (a
+    count by `bincount` scatters integers)."""
+    types = re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(.*?\) -> '
+                       r'(tensor<[^>]*>)', text, flags=re.DOTALL)
+    return [t for t in types if not re.search(r"x[su]?i\d+>", t)]
 
 
 def example_batch(seed: int, dims, batch: int):

@@ -21,6 +21,7 @@ from code2vec_tpu.models import registry
 from code2vec_tpu.models.encoder import (ModelDims, get_encode_fn,
                                          init_params)
 from code2vec_tpu.models.lfm2_moe_encoder import Lfm2Dims
+from code2vec_tpu.models.qwen3_next_encoder import Qwen3NextDims
 from code2vec_tpu.parallel.mesh import make_mesh
 from code2vec_tpu.parallel.sharding import (param_pspecs, shard_opt_state,
                                             shard_params)
@@ -41,9 +42,20 @@ BLOCK = dict(layer_types=["conv", "full_attention"], num_dense_layers=1,
              num_routed_experts=4, first_expert=1, num_experts_per_tok=2,
              conv_L_cache=3, norm_eps=1e-5,
              rope_parameters={"rope_theta": 1e6})
+QWEN_BLOCK = dict(num_hidden_layers=2, full_attention_interval=2,
+                  hidden_size=32, num_attention_heads=4,
+                  num_key_value_heads=2, head_dim=8,
+                  partial_rotary_factor=0.5, rope_theta=1e7,
+                  rms_norm_eps=1e-6, linear_conv_kernel_dim=4,
+                  linear_key_head_dim=8, linear_value_head_dim=8,
+                  linear_num_key_heads=1, linear_num_value_heads=2,
+                  num_experts=2, num_routed_experts=4, first_expert=1,
+                  num_experts_per_tok=2, moe_intermediate_size=24,
+                  shared_expert_intermediate_size=16)
 # an encoder's own sizes, as ModelDims keywords (none: the defaults)
 SIZES = {"transformer": dict(xf_layers=2, xf_heads=4, xf_remat=True),
-         "lfm2_moe": dict(lfm=Lfm2Dims.from_config(BLOCK))}
+         "lfm2_moe": dict(lfm=Lfm2Dims.from_config(BLOCK)),
+         "qwen3_next": dict(qwen=Qwen3NextDims.from_config(QWEN_BLOCK))}
 
 
 def dims_of(name: str, **kw) -> ModelDims:

@@ -23,7 +23,7 @@ from code2vec_tpu.models.encoder import (ModelDims, get_encode_fn,
 from code2vec_tpu.models.lfm2_moe_encoder import Lfm2Dims
 from code2vec_tpu.models import lfm2_moe_encoder as lfm
 from code2vec_tpu.ops import moe
-from tests.helpers import build_tiny_dataset
+from tests.helpers import build_tiny_dataset, float_scatters
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
@@ -474,6 +474,37 @@ def test_all_experts_held_lowers_to_one_body_and_no_conditional():
         assert "stablehlo.if" not in one_body
 
 
+@pytest.mark.parametrize("live", [60, 128, 129])
+def test_a_program_that_takes_no_gradient_adds_no_rows_by_index(live):
+    """Evaluation, prediction and serving run `_bounded` itself, whose
+    rows go back through a gather (`_take_back_head`): the same output
+    as the differentiated forward's scatter-add, to float32 rounding at
+    the bound and bit for bit in the overflow (one body), and no scatter
+    of floats in the lowered text, where the differentiated program has
+    one (on the v5e XLA's scatter-add of 512 rows did not return inside
+    the qwen3_next predict step of 8 methods: PERF.md section 6, PR
+    32)."""
+    case = _bound_case(live)
+
+    def layer(h):
+        return moe.held_experts_ffn(
+            h, case["valid"], case["chosen"], case["p"], case["w1"],
+            case["w3"], case["w2"], case["first"], case["E"])[0]
+
+    forward = jax.jit(layer)
+    trained = jax.jit(lambda h: jax.value_and_grad(
+        lambda h: jnp.sum(layer(h)))(h))
+    assert not float_scatters(forward.lower(case["h"]).as_text())
+    assert float_scatters(trained.lower(case["h"]).as_text())
+    want = _layer_and_grads(case, case["E"])[0]
+    got = forward(case["h"])
+    if live <= 128:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_gradients_under_remat_are_the_unrematted_ones():
     """`encode_lfm2_moe` runs each layer under `jax.checkpoint` inside
     the step's jit."""
@@ -716,7 +747,7 @@ def test_model_trains_evaluates_predicts_and_reloads(tmp_path):
     prefix = build_tiny_dataset(str(tmp_path), n_train=256, n_val=32,
                                 n_test=64, max_contexts=16)
     cfg = tiny_config(prefix, ENCODER_TYPE="lfm2_moe",
-                      LFM_CONFIG=_lfm_config_file(tmp_path),
+                      BLOCK_CONFIG=_lfm_config_file(tmp_path),
                       NUM_TRAIN_EPOCHS=6, LEARNING_RATE=0.003,
                       TELEMETRY_DIR=str(tmp_path / "tele"), TRACE=True)
     ckpt_dir = str(tmp_path / "ckpt")

@@ -106,14 +106,16 @@ class ConfigModel:
             if not (isinstance(node, ast.Call) and getattr(
                     node.func, "attr", "") == "add_argument"):
                 continue
-            long_flag = None
-            for a in node.args:
-                if isinstance(a, ast.Constant) and isinstance(
-                        a.value, str) and a.value.startswith("--"):
-                    long_flag = a.value
-            if long_flag is None:
+            # every spelling of the option is a flag argparse accepts
+            # (`--block_config`, `--lfm_config`); its dest is the
+            # first's, as argparse has it
+            long_flags = [a.value for a in node.args
+                          if isinstance(a, ast.Constant) and isinstance(
+                              a.value, str) and a.value.startswith("--")]
+            if not long_flags:
                 continue  # short-only options have no doc contract
-            self.flags.append((long_flag, node.lineno))
+            long_flag = long_flags[0]
+            self.flags.extend((f, node.lineno) for f in long_flags)
             dest = None
             for kw in node.keywords:
                 if kw.arg == "dest" and isinstance(

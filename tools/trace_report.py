@@ -366,6 +366,20 @@ def route_summary(spans: Sequence[Dict[str, Any]]
     return out
 
 
+def scan_summary(spans: Sequence[Dict[str, Any]]
+                 ) -> Optional[Dict[str, Any]]:
+    """The run's `gdn/scan` spans (one a train step of an encoder whose
+    layers scan a state along the context axis; obs/route.py) summed:
+    steps, slots a chunk, the chunks the scans ran over and those of
+    them that held a valid slot. None when the run has none."""
+    scans = [s.get("attrs") or {} for s in spans if s["name"] == "gdn/scan"]
+    if not scans:
+        return None
+    return {"steps": len(scans), "chunk": scans[0]["chunk"],
+            "chunks": sum(a["chunks"] for a in scans),
+            "live_chunks": sum(a["live_chunks"] for a in scans)}
+
+
 def save_breakdowns(spans: Sequence[Dict[str, Any]]
                     ) -> List[Dict[str, Any]]:
     rows = []
@@ -488,6 +502,14 @@ def render(loaded, limit: int = 10) -> str:
                    f"{route['compact_layers']:,} of "
                    f"{route['expert_layers'] * route['steps']:,}"
                    if "row_bound" in route else ""))
+        scan = scan_summary(spans)
+        if scan:
+            lines.append(
+                f"Scanned state: {scan['chunks']:,} chunks of "
+                f"{scan['chunk']} slots over {scan['steps']} steps, "
+                f"{scan['live_chunks']:,} of them with a valid slot "
+                f"({100.0 * scan['live_chunks'] / max(scan['chunks'], 1):.1f}"
+                "%)")
         save_rows = save_breakdowns(spans)
         if save_rows:
             lines.append("")
