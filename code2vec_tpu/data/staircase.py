@@ -114,6 +114,14 @@ def area(stairs: Stairs, max_contexts: int) -> int:
                for k, (_, kept) in enumerate(stairs))
 
 
+def rows_kept(stairs: Stairs, column: int) -> int:
+    """Rows the rectangle that holds slot column `column` keeps.
+    `from_lengths`' rectangles keep fewer rows the further right they
+    stand, so in a batch that `fits` the rows from there down are PAD
+    in that column and in every later one."""
+    return [kept for first, kept in stairs if first <= column][-1]
+
+
 def fits(stairs: Stairs, id_arrays, groups: int = 1) -> bool:
     """Whether every id outside the rectangles is PAD (0), in each of
     the `groups` contiguous blocks of rows of the batch's `[B, C]` id

@@ -85,12 +85,14 @@ pass. What this block shares with `lfm2_moe_encoder.py` is
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from code2vec_tpu.data.staircase import rows_kept
 from code2vec_tpu.models import seq_block
 from code2vec_tpu.models.encoder import ModelDims, embed_contexts
 from code2vec_tpu.models.registry import EncoderSpec
@@ -336,7 +338,13 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
     those of them with a valid slot (zeros on an attention layer). The
     train step hands it to the spec's recorder (`obs.route`); the other
     steps let it fall. `use_pallas` is taken and not read: the rule is
-    plain JAX, the grouped product XLA's own kernel on the TPU."""
+    plain JAX, the grouped product XLA's own kernel on the TPU.
+
+    `staircase` (training only; `embed_contexts` has who checks it)
+    also bounds the scans: chunk n runs over the rows its first slot's
+    rectangle keeps, on each device its own, and "the chunks its scan
+    ran over" is that bound's sum over the devices; with none it is
+    every method's every chunk."""
     del use_pallas
     cfg, sub = dims.qwen, params["qwen"]
 
@@ -354,17 +362,26 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
                                           score="softmax"),
             w1, w3, w2, first_expert=cfg.first_expert, routed=cfg.routed)
 
-    scan = delta_rule.gated_delta_rule
+    B, C = mask.shape
+    L, chunks = delta_rule.chunk_len(C), delta_rule.chunks_of(C)
+    # the staircase is one device's rows, longest bag first: from a
+    # chunk's first slot on, the rows its rectangle leaves out are PAD
+    bound = None if staircase is None else tuple(
+        rows_kept(staircase, n * L) for n in range(chunks))
+    scan = functools.partial(delta_rule.gated_delta_rule, rows=bound)
+    devices = 1
     if mesh is not None:
         # each device routes and scans its own rows of the batch
+        from code2vec_tpu.parallel.mesh import DATA_AXIS, DCN_AXIS
         from code2vec_tpu.parallel.sharding import shard_map_over_batch
         experts = shard_map_over_batch(experts, mesh,
                                        (True, True) + (False,) * 4)
         scan = shard_map_over_batch(scan, mesh, (True,) * 6)
+        devices = mesh.shape[DCN_AXIS] * mesh.shape[DATA_AXIS]
 
-    B, C = mask.shape
-    scanned = jnp.stack([jnp.int32(delta_rule.chunk_len(C)),
-                         jnp.int32(B * delta_rule.chunks_of(C)),
+    scanned = jnp.stack([jnp.int32(L),
+                         jnp.int32(B * chunks if bound is None
+                                   else devices * sum(bound)),
                          delta_rule.live_chunks(mask)])
 
     def mixer(h, layer):

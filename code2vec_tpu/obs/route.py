@@ -36,8 +36,10 @@ also writes
   name   gdn/scan         t0, t1 as above
   attrs  seq              the step, as the route's
          chunk            slots a chunk (`ops/delta_rule.chunk_len`)
-         chunks           methods x chunks a method x layers that scan:
-                          what the step's scans ran over
+         chunks           what the step's scans ran over, summed over
+                          the layers that scan: methods x chunks a
+                          method, or the rows each chunk kept where the
+                          step scans under its staircase's bound
          live_chunks      those of them with at least one valid slot,
                           counted on the device from the mask
 

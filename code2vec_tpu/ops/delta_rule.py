@@ -38,7 +38,12 @@ backward is the inverse's own, -T^T dT T^T, so no doubling stage is
 kept. The chunks run in a Python loop (C / L of them, four at 200
 slots), each rematerialised in the backward pass from its inputs and the
 state that entered it: every product carries the chunk's or the state's
-shape in the trace.
+shape in the trace. Each chunk may run over fewer rows than the one
+before (`rows`): a training batch ordered longest bag first is all
+padding below its staircase, a chunk of padding computes zeros and an
+unchanged state at full price, and a row that has left never comes back,
+so the state is cut down with the rows and the rows left out read 0.
+With no bound every chunk runs over every row, as it always did.
 
 The chunk length is the implementation's and not the model's: for any L
 and any C, multiple of L or not, the chunked rule equals the recurrence
@@ -46,6 +51,8 @@ to float32 rounding, forward and in every gradient.
 """
 
 from __future__ import annotations
+
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -111,33 +118,63 @@ unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
-                     g: jax.Array, beta: jax.Array,
-                     mask: jax.Array) -> jax.Array:
+                     g: jax.Array, beta: jax.Array, mask: jax.Array,
+                     rows: Optional[Tuple[int, ...]] = None) -> jax.Array:
     """The rule in chunks of `chunk_len(C)` slots. q, k [B, C, n_k, d_k]
     (L2-normalised and scaled by the caller), v [B, C, n_v, d_v], g and
     beta [B, C, n_v] float32, mask [B, C]; key head j serves value heads
     j r .. j r + r - 1, r = n_v / n_k. Returns o [B, C, n_v, d_v]
-    float32."""
+    float32.
+
+    `rows` (static; None: every chunk runs over all B rows) is the
+    caller's word that row `rows[n]` and every row after it hold no
+    valid slot from chunk n on: `chunks_of(C)` counts that never rise,
+    which a batch ordered longest bag first has from its staircase
+    (data/staircase.py). Chunk n then runs over its first `rows[n]`
+    rows and the state is cut down with it; the rows left out read
+    o = 0, as masked slots do. Nothing here looks at the mask to check
+    the word: a valid slot outside the bound would read 0."""
     B, C, n_k, d_k = k.shape
     n_v, d_v = v.shape[2], v.shape[3]
     r = n_v // n_k
     L = chunk_len(C)
     N = -(-C // L)
+    if rows is None:
+        rows = (B,) * N
+    if len(rows) != N or any(a < b for a, b in zip((B,) + rows, rows)) \
+            or rows[-1] < 1:
+        raise ValueError(f"gated_delta_rule: rows {rows} for {N} chunks "
+                         f"of {B} rows: one count a chunk, none over the "
+                         "one before, none under 1")
     f32, dtype = jnp.float32, v.dtype
     live = mask.astype(f32)[..., None]
     g, beta = g.astype(f32) * live, beta.astype(f32) * live
 
     def split(t, grouped: bool):
-        """[B, C, heads, *tail] -> [B, N, n_k, (r,) L, *tail]; the slots
-        added to fill the last chunk are masked ones (g = beta = 0)."""
-        tail = t.shape[3:]
-        t = jnp.pad(t, ((0, 0), (0, N * L - C)) + ((0, 0),) * (t.ndim - 2))
-        t = t.reshape(B, N, L, *((n_k, r) if grouped else (n_k,)), *tail)
+        """[b, c, heads, *tail] -> [b, c / L, n_k, (r,) L, *tail]; the
+        slots added to fill the last chunk are masked ones (g = beta =
+        0)."""
+        b, c, tail = t.shape[0], t.shape[1], t.shape[3:]
+        n = -(-c // L)
+        t = jnp.pad(t, ((0, 0), (0, n * L - c)) + ((0, 0),) * (t.ndim - 2))
+        t = t.reshape(b, n, L, *((n_k, r) if grouped else (n_k,)), *tail)
         return jnp.moveaxis(t, 2, -1 - len(tail))
 
-    q, k = split(q, False), split(k, False)          # [B, N, n_k, L, d_k]
-    v = split(v, True)                               # [B, N, n_k, r, L, d_v]
-    g, beta = split(g, True), split(beta, True)      # [B, N, n_k, r, L]
+    grouped = (False, False, True, True, True)
+    if rows[-1] == B:
+        # no bound: the chunks are laid side by side once, the program
+        # this always was. q, k [B, N, n_k, L, d_k]; v [B, N, n_k, r, L,
+        # d_v]; g, beta [B, N, n_k, r, L]
+        whole = [split(t, gr) for t, gr in zip((q, k, v, g, beta), grouped)]
+
+        def chunk_inputs(n):
+            return [t[:, n] for t in whole]
+    else:
+        # cut to the chunk's rows before the chunks are laid side by
+        # side: the layout's copies are then the bound's size too
+        def chunk_inputs(n):
+            return [split(t[:rows[n], n * L:(n + 1) * L], gr)[:, 0]
+                    for t, gr in zip((q, k, v, g, beta), grouped)]
 
     i = jnp.arange(L)
 
@@ -179,10 +216,14 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     # the backward pass: kept for every chunk they are the layer's memory
     # (4 GB of the 9.7 a layer's backward held at the cell's sizes)
     one_chunk = jax.checkpoint(one_chunk)
-    S = jnp.zeros((B, n_k, r, d_k, d_v), f32)
+    S = jnp.zeros((rows[0], n_k, r, d_k, d_v), f32)
     out = []
     for n in range(N):
-        S, o = one_chunk(S, q[:, n], k[:, n], v[:, n], g[:, n], beta[:, n])
+        if rows[n] < S.shape[0]:
+            S = S[:rows[n]]     # a row that leaves never comes back
+        S, o = one_chunk(S, *chunk_inputs(n))
+        if rows[n] < B:
+            o = jnp.pad(o, ((0, B - rows[n]),) + ((0, 0),) * 4)
         out.append(o)
     o = jnp.stack(out, axis=1)                       # [B, N, n_k, r, L, d_v]
     o = jnp.moveaxis(o, -2, 2).reshape(B, N * L, n_v, d_v)[:, :C]

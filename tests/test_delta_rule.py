@@ -3,7 +3,9 @@ rule against the token-by-token recurrence, output and every gradient,
 for lengths that are and are not multiples of the chunk and with masked
 tails; what a masked slot leaves alone; the triangular inverse and its
 backward; the grouping of value heads under key heads; the count of live
-chunks."""
+chunks; the rule over a per-chunk bound on the rows (ISSUE 33): the
+recurrence and the unbounded rule on batches ordered longest first, zeros
+outside the bound, and with no bound the program it always lowered to."""
 
 import functools
 from unittest import mock
@@ -61,13 +63,13 @@ def _recurrence(q, k, v, g, beta, mask):
     return jnp.moveaxis(o, 0, 1) * live[..., None]
 
 
-def rule_in_chunks_of(chunk):
+def rule_in_chunks_of(chunk, rows=None):
     """`dr.gated_delta_rule` traced with the module's `CHUNK` at `chunk`
     (None: as it stands): the chunk length is the module's, not an
-    argument."""
+    argument. `rows`: the rows each chunk runs over."""
     def rule(*args):
         with mock.patch.object(dr, "CHUNK", chunk or dr.CHUNK):
-            return dr.gated_delta_rule(*args)
+            return dr.gated_delta_rule(*args, rows=rows)
     return rule
 
 
@@ -230,6 +232,191 @@ def test_bfloat16_inputs_keep_a_float32_state():
     assert got.dtype == jnp.float32
     gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     assert gap < 0.02, gap
+
+
+# ---- the rows a chunk runs over (ISSUE 33) -------------------------------
+
+def ordered_case(B, C, chunk, rows, dtype=jnp.float32):
+    """`case` with bags ordered longest first that the bound `rows`
+    holds: row b is valid up to the first slot of the first chunk
+    that leaves it out (some rows to the very slot), and no further; a
+    row the bound never holds is an empty bag."""
+    allowed = np.array([chunk * sum(b < kept for kept in rows)
+                        for b in range(B)])
+    lens = np.sort(np.clip(allowed - 3 * (np.arange(B) % 3),
+                           np.minimum(allowed, 1), C))[::-1]
+    q, k, v, g, beta, mask = case(B=B, C=C, lens=lens)
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta,
+            mask)
+
+
+BF16 = jnp.bfloat16
+BOUNDED = {
+    "the cell's 200 slots, a falling bound": (8, 200, 64, (8, 5, 3, 2)),
+    "slots no multiple of the chunk": (6, 23, 8, (6, 4, 2)),
+    "one chunk": (3, 16, 16, (3,)),
+    "every chunk over every row": (4, 24, 8, (4, 4, 4)),
+    "down to a single row": (5, 24, 8, (5, 1, 1)),
+    "fewer rows than the batch from chunk 0": (5, 16, 8, (4, 2)),
+    "bfloat16 inputs": (6, 40, 16, (6, 4, 3), BF16),
+}
+
+
+@pytest.mark.parametrize("name", BOUNDED)
+def test_bounded_rule_is_the_recurrence_and_the_unbounded_rule(name):
+    B, C, chunk, rows, *dtype = BOUNDED[name]
+    args = ordered_case(B, C, chunk, rows, *dtype)
+    mask = np.asarray(args[5])
+    # the bound holds the batch: no valid slot from chunk n on at or
+    # below row rows[n] (and the first case cuts into every chunk)
+    for n, kept in enumerate(rows):
+        assert not mask[kept:, n * chunk:].any()
+    want, want_grads = output_and_gradients(_recurrence, args)
+    full, full_grads = output_and_gradients(rule_in_chunks_of(chunk), args)
+    got, got_grads = output_and_gradients(rule_in_chunks_of(chunk, rows), args)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    # float32: the recurrence to its rounding, the unbounded rule to a
+    # unit or two (the same products on the same operands, row by row);
+    # bfloat16 operands: the unbounded rule's own distance
+    exact = not dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(full),
+                               atol=1e-6 if exact else 1e-2)
+    if exact:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=5e-6)
+    else:
+        gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+        assert gap < 0.02, gap
+    for name, a, f, b in zip("q k v g beta".split(), want_grads,
+                             full_grads, got_grads):
+        assert b.dtype == a.dtype and b.shape == a.shape
+        a, f, b = (np.asarray(t.astype(jnp.float32)) for t in (a, f, b))
+        scale = np.abs(a).max() + 1e-9
+        np.testing.assert_allclose(b / scale, f / scale,
+                                   atol=2e-6 if exact else 2e-2,
+                                   err_msg=name)
+        np.testing.assert_allclose(b / scale, a / scale,
+                                   atol=2e-5 if exact else 5e-2,
+                                   err_msg=name)
+
+
+def test_rows_outside_the_bound_read_zero_and_get_zero_gradient():
+    """The rule does not look at the mask to check the bound: with
+    every slot valid, what the bound leaves out reads exactly 0 and
+    hands exactly nothing back, and what it keeps is the rule over
+    those rows alone."""
+    B, C, chunk, rows = 5, 24, 8, (5, 3, 1)
+    args = case(B=B, C=C, lens=[C] * B)
+    out, grads = output_and_gradients(rule_in_chunks_of(chunk, rows), args)
+    for n, kept in enumerate(rows):
+        slots = slice(n * chunk, (n + 1) * chunk)
+        np.testing.assert_array_equal(np.asarray(out[kept:, slots]), 0.0)
+        for name, grad in zip("q k v g beta".split(), grads):
+            np.testing.assert_array_equal(np.asarray(grad[kept:, slots]),
+                                          0.0, err_msg=name)
+        assert np.asarray(out[:kept, slots]).any()
+    # row 2 runs two chunks and stops: the rule over its first 16 slots
+    short = chunked(*(t[2:3, :16] for t in args), chunk=chunk)
+    np.testing.assert_allclose(np.asarray(out[2:3, :16]), np.asarray(short),
+                               atol=2e-6)
+
+
+@pytest.mark.parametrize("rows,why", [
+    ((4, 4), "a count a chunk"), ((4, 2, 3), "never rising"),
+    ((5, 4, 4), "at most the batch"), ((4, 2, 0), "at least a row"),
+])
+def test_a_bound_that_is_no_bound_is_refused(rows, why):
+    args = case(B=4, C=24, lens=[24, 16, 8, 8])
+    with pytest.raises(ValueError, match="rows"):
+        rule_in_chunks_of(8, rows)(*args)
+
+
+def _rule_before_the_bound(q, k, v, g, beta, mask):
+    """`dr.gated_delta_rule` as it stood before ISSUE 33 (its comments
+    left out): what a caller that passes no bound still lowers to."""
+    B, C, n_k, d_k = k.shape
+    n_v, d_v = v.shape[2], v.shape[3]
+    r = n_v // n_k
+    L = dr.chunk_len(C)
+    N = -(-C // L)
+    f32, dtype = jnp.float32, v.dtype
+    live = mask.astype(f32)[..., None]
+    g, beta = g.astype(f32) * live, beta.astype(f32) * live
+
+    def split(t, grouped: bool):
+        tail = t.shape[3:]
+        t = jnp.pad(t, ((0, 0), (0, N * L - C)) + ((0, 0),) * (t.ndim - 2))
+        t = t.reshape(B, N, L, *((n_k, r) if grouped else (n_k,)), *tail)
+        return jnp.moveaxis(t, 2, -1 - len(tail))
+
+    q, k = split(q, False), split(k, False)
+    v = split(v, True)
+    g, beta = split(g, True), split(beta, True)
+    i = jnp.arange(L)
+
+    def one_chunk(S, q, k, v, g, beta):
+        G = jnp.cumsum(g, axis=-1)
+        decay = jnp.exp(jnp.where(i[:, None] >= i[None, :],
+                                  G[..., :, None] - G[..., None, :],
+                                  -jnp.inf))
+        kk = jnp.einsum("bhid,bhjd->bhij", k, k, preferred_element_type=f32)
+        qk = jnp.einsum("bhid,bhjd->bhij", q, k, preferred_element_type=f32)
+        a = jnp.where(i[:, None] > i[None, :],
+                      beta[..., None] * kk[:, :, None] * decay, 0.0)
+        t_u = dr.unit_lower_inverse(a) * beta[..., None, :]
+        t_w = (t_u * jnp.exp(G)[..., None, :]).astype(dtype)
+        u = jnp.matmul(t_u.astype(dtype), v, preferred_element_type=f32)
+        w = jnp.einsum("bhrij,bhjd->bhrid", t_w, k)
+        s_in = S.astype(dtype)
+        written = u - jnp.matmul(w, s_in, preferred_element_type=f32)
+        read = jnp.einsum("bhid,bhrdv->bhriv", q, s_in,
+                          preferred_element_type=f32)
+        o = jnp.exp(G)[..., None] * read + jnp.matmul(
+            (qk[:, :, None] * decay).astype(dtype), written.astype(dtype),
+            preferred_element_type=f32)
+        to_end = jnp.exp(G[..., -1:] - G)[..., None]
+        S = S * jnp.exp(G[..., -1])[..., None, None] + jnp.einsum(
+            "bhid,bhriv->bhrdv", k, (to_end * written).astype(dtype),
+            preferred_element_type=f32)
+        return S, o
+
+    one_chunk = jax.checkpoint(one_chunk)
+    S = jnp.zeros((B, n_k, r, d_k, d_v), f32)
+    out = []
+    for n in range(N):
+        S, o = one_chunk(S, q[:, n], k[:, n], v[:, n], g[:, n], beta[:, n])
+        out.append(o)
+    o = jnp.stack(out, axis=1)
+    o = jnp.moveaxis(o, -2, 2).reshape(B, N * L, n_v, d_v)[:, :C]
+    return o * live[..., None]
+
+
+@pytest.mark.parametrize("rows", [None, (3, 3, 3, 3)])
+def test_with_no_bound_the_lowered_program_is_the_old_one(rows):
+    """Evaluation, prediction, serving and every batch that does not
+    fit its staircase pass no bound: forward and backward, what they
+    lower to does not know the argument exists (and a bound that keeps
+    every row is no bound)."""
+    args = case(C=200)
+    args = tuple(t.astype(BF16) for t in args[:3]) + args[3:]
+
+    def old(*a):
+        return _rule_before_the_bound(*a)
+
+    def new(*a):
+        return dr.gated_delta_rule(*a) if rows is None \
+            else dr.gated_delta_rule(*a, rows=rows)
+
+    def texts(fn):
+        grad = jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2, 3, 4))
+        return [jax.jit(f).lower(*args).as_text().replace("jit_new",
+                                                          "jit_old")
+                for f in (fn, grad)]
+
+    assert texts(new) == texts(old)
+    bounded = jax.jit(lambda *a: dr.gated_delta_rule(
+        *a, rows=(3, 2, 1, 1))).lower(*args).as_text()
+    assert bounded != texts(new)[0]
 
 
 def test_live_chunks_is_a_numpy_count():

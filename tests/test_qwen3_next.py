@@ -7,8 +7,9 @@ expert-parallel shares and the shared expert adding up to the whole
 layer; the partial rotary term; the two gates; the softmax router; no
 dropped row; no recompilation across routings; what `Config.verify()`
 and `Qwen3NextDims.from_config` refuse; the option's two spellings; the
-`gdn/scan` record; the step's named scopes; the model class end to end
-under the tests' 8-device mesh, saved and resumed."""
+`gdn/scan` record; the step's named scopes; the scan under the training
+staircase (ISSUE 33); the model class end to end under the tests' 8-device
+mesh, saved and resumed."""
 
 import dataclasses
 import json
@@ -575,6 +576,97 @@ def test_under_a_mesh_every_device_routes_and_scans_its_own_rows():
         gap = float(jnp.linalg.norm(got[name] - want[name])
                     / jnp.linalg.norm(want[name]))
         assert gap < 1e-3, (name, gap)
+
+
+# ---- the scan under the training staircase (ISSUE 33) --------------------
+
+SLOTS = 160                 # chunks of 64, 64 and 32 slots a bag
+# 16 rows a device; the chunks start inside the first, second and third
+# rectangle and run over 16, 10 and 6 rows: 32 of 48 method-chunks
+STAIRS = ((0, 16), (48, 10), (96, 6), (144, 3))
+BOUND = (16, 10, 6)
+FITS = [160, 150, 145, 140, 120, 100, 96, 80, 70, 50, 48, 30, 20, 10, 5, 1]
+ONE_TOO_LONG = FITS[:10] + [49] + FITS[11:]
+
+
+def ordered_batch(lengths, devices, seed=5):
+    """A training batch of bags of `lengths` for every device, ordered
+    as the training reader orders it (`length_order`, a block a
+    device)."""
+    from code2vec_tpu.data.staircase import length_order
+    lengths = np.repeat(np.asarray(lengths), devices)
+    lengths = lengths[length_order(lengths, devices)]
+    r = np.random.default_rng(seed)
+    n = len(lengths)
+    mask = np.arange(SLOTS)[None, :] < lengths[:, None]
+
+    def ids(v):
+        return (r.integers(2, v + 2, (n, SLOTS)) * mask).astype(np.int32)
+
+    return (r.integers(2, SIZES["targets"] + 2, n).astype(np.int32),
+            ids(SIZES["tokens"]), ids(SIZES["paths"]), ids(SIZES["tokens"]),
+            mask.astype(np.float32), np.ones(n, np.float32))
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+def test_the_staircase_step_scans_under_its_bound_and_is_the_full_step(
+        devices):
+    """A batch that fits runs the scan over the bound's rows, chunk by
+    chunk and device by device, and is the full step's loss and
+    gradients (one SGD step) on the same batch; a batch that does not
+    fit runs the full step; the record says which ran."""
+    import optax
+
+    from code2vec_tpu.data import staircase as st
+    from code2vec_tpu.obs import memory_tracer
+    from code2vec_tpu.ops import delta_rule
+    from code2vec_tpu.parallel.mesh import make_mesh
+    from code2vec_tpu.training.steps import TrainBatch, make_train_step
+
+    assert BOUND == tuple(st.rows_kept(STAIRS, n * delta_rule.CHUNK)
+                          for n in range(delta_rule.chunks_of(SLOTS)))
+    dims = dataclasses.replace(DIMS, max_contexts=SLOTS)
+    mesh = None if devices == 1 else make_mesh(
+        devices, 1, devices=jax.devices()[:devices])
+    opt = optax.sgd(0.1)
+    kw = dict(use_sampled_softmax=True, num_sampled=16, mesh=mesh)
+    both = make_train_step(dims, opt, staircase=STAIRS, **kw)
+    full = make_train_step(dims, opt, **kw)
+    key = jax.random.PRNGKey(4)
+
+    def run(step, batch):
+        """(loss, the parameters after the step, the scan's record)"""
+        _dims, params, _ = program_weights()
+        new, _state, loss = step(params, opt.init(params), batch, key)
+        step.route_recorder.flush()
+        return (float(loss), flat(new),
+                memory_tracer().records("gdn/scan")[-1]["attrs"])
+
+    rows = 16 * devices
+    for lengths, fits in ((FITS, True), (ONE_TOO_LONG, False)):
+        batch = ordered_batch(lengths, devices)
+        assert st.fits(STAIRS, batch[1:4], devices) == fits
+        loss, after, scan = run(both, TrainBatch(batch, fits, 0))
+        want_loss, want_after, want_scan = run(full, batch)
+        live = 3 * int(np.ceil(batch[4].sum(axis=1) / 64).sum())
+        assert (want_scan["chunks"], want_scan["live_chunks"]) == \
+            (rows * 3 * 3, live)
+        assert scan["chunk"] == want_scan["chunk"] == 64
+        assert scan["live_chunks"] == live
+        assert scan["chunks"] == (devices * sum(BOUND) * 3 if fits
+                                  else rows * 3 * 3)
+        assert loss == pytest.approx(want_loss, rel=1e-5)
+        _dims, start, _ = program_weights()
+        start = flat(start)
+        moved = {k: np.asarray(want_after[k]) - np.asarray(start[k])
+                 for k in start}
+        norms = {k: float(np.linalg.norm(v)) for k, v in moved.items()}
+        median = float(np.median(list(norms.values())))
+        assert norms["qwen/layers/0/A_log"] > 0
+        for k, change in moved.items():
+            got = np.asarray(after[k]) - np.asarray(start[k])
+            assert float(np.linalg.norm(got - change)) <= \
+                2e-4 * max(norms[k], median), k
 
 
 # ---- configuration -------------------------------------------------------

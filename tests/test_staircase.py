@@ -9,7 +9,10 @@
    and nothing else is ever ordered;
 3. the staircase is a function of the shard's multiset of lengths;
 4. the train step runs the staircase program exactly when the check
-   says the batch fits.
+   says the batch fits;
+5. `rows_kept` (ISSUE 33): the rows a column's rectangle keeps bound,
+   in every batch that fits, the rows with a valid slot from that
+   column on.
 """
 
 import json
@@ -325,16 +328,20 @@ def test_full_bags_give_the_whole_rectangle():
 
 
 def test_java_large_lengths_give_six_rectangles_of_half_the_slots():
-    """The benchmark corpus's law (lognormal, median 60, sigma 1,
-    clipped to 200) at 8,192 rows a device."""
-    z = np.random.default_rng(0).standard_normal(200_000)
-    lengths = np.clip(np.rint(60 * np.exp(z)), 1, 200)
-    stairs = st.from_lengths(lengths, 8192, 200)
+    """The benchmark corpus's law at 8,192 rows a device."""
+    stairs = st.from_lengths(java_large_lengths(), 8192, 200)
     assert [first for first, _ in stairs] == [0, 32, 64, 96, 128, 160]
     kept = [k for _, k in stairs]
     assert kept[0] == 8192 and all(k % 256 == 0 for k in kept)
     assert kept == sorted(kept, reverse=True)
     assert 0.45 < st.area(stairs, 200) / (8192 * 200) < 0.55
+
+
+def java_large_lengths(n=200_000):
+    """The benchmark corpus's law: lognormal, median 60, sigma 1,
+    clipped to 1-200."""
+    z = np.random.default_rng(0).standard_normal(n)
+    return np.clip(np.rint(60 * np.exp(z)), 1, 200)
 
 
 # ---- 4. the choice of the step ----------------------------------------------
@@ -520,3 +527,58 @@ def test_a_text_corpus_gets_no_staircase(tmp_path):
     assert not os.path.exists(prefix + ".train.bin.json")
     model = model_of(prefix, 1)
     assert model._staircase is None
+
+
+# ---- 5. the rows a column keeps ---------------------------------------------
+
+@pytest.mark.parametrize("column,kept,where", [
+    (0, 24, "the first column"), (7, 24, "inside the first rectangle"),
+    (8, 16, "on a boundary"), (11, 16, "inside"), (12, 4, "on the last"),
+    (15, 4, "the last column: the last rectangle runs to the end"),
+])
+def test_rows_kept_is_the_rectangle_that_holds_the_column(column, kept,
+                                                          where):
+    assert st.rows_kept(((0, 24), (8, 16), (12, 4)), column) == kept, where
+    assert st.rows_kept(((0, 24),), column) == 24
+
+
+def test_the_cells_law_at_128_rows_bounds_the_chunks_at_128_88_48_40():
+    """`qwen3next-train-corpus`: 128 methods of 200 slots a device,
+    chunks of 64 slots (ops/delta_rule.CHUNK) from slots 0, 64, 128
+    and 192: 304 of 512 method-chunks."""
+    stairs = st.from_lengths(java_large_lengths(), 128, 200)
+    assert stairs == ((0, 128), (32, 120), (64, 88), (96, 64), (128, 48),
+                      (160, 40))
+    assert [st.rows_kept(stairs, c) for c in (0, 64, 128, 192)] == \
+        [128, 88, 48, 40]
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_batch_that_fits_has_no_valid_slot_under_a_chunks_bound(seed):
+    """What the bounded scan rests on: whenever the producer's check
+    passes, the mask the reader makes (`pth != 0`) is all PAD from each
+    chunk's first column on, in every row from that column's bound
+    down; a batch with such a slot does not fit."""
+    rng = np.random.default_rng(seed)
+    population = np.clip(np.rint(rng.lognormal(np.log(5), 1.0, 400)), 1, C)
+    rows, chunk = 32, 4 + seed % 3          # chunks on and off boundaries
+    stairs = st.from_lengths(population, rows, C)
+    bound = [st.rows_kept(stairs, first) for first in range(0, C, chunk)]
+    assert bound == sorted(bound, reverse=True) and bound[0] <= rows
+    fitted = 0
+    for _ in range(40):
+        lengths = rng.choice(population, rows).astype(int)
+        lengths = lengths[st.length_order(lengths)]
+        ids = batch_of(lengths)
+        mask = ids[1] != 0
+        clear = all(not mask[kept:, n * chunk:].any()
+                    for n, kept in enumerate(bound))
+        if st.fits(stairs, ids):
+            fitted += 1
+            assert clear
+        # one valid slot just under a bound: no fit
+        n = int(rng.integers(len(bound)))
+        if bound[n] < rows:
+            ids[1][bound[n], n * chunk] = 7
+            assert not st.fits(stairs, ids)
+    assert fitted >= 20
