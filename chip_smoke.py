@@ -2,7 +2,7 @@
 """Chip smoke: drive training and serving once on the TPU, at java-large
 width, through the entry points a user calls.
 
-    python3 chip_smoke.py [--config bag|transformer|int8|sparse|lfm2_moe|qwen3_next]
+    python3 chip_smoke.py [--config bag|transformer|int8|sparse|lfm2_moe|qwen3_next|joyai_flash]
 
 One process, phases in order, the first failure ends the run with a
 non-zero exit and no result line:
@@ -74,9 +74,24 @@ QWEN_BLOCK = {"num_hidden_layers": 2, "full_attention_interval": 2,
               "first_expert": 0, "num_experts_per_tok": 10,
               "moe_intermediate_size": 512,
               "shared_expert_intermediate_size": 512}
+# joyai_flash at JoyAI-LLM-Flash's published widths and a small depth
+# (the leading dense layer and one expert layer with 16 of 256 routed
+# experts beside the shared one, latent attention in both): 177M float32
+# parameters
+JOYAI_BLOCK = {"num_hidden_layers": 2, "hidden_size": 2048,
+               "num_attention_heads": 32, "q_lora_rank": 1536,
+               "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+               "qk_rope_head_dim": 64, "v_head_dim": 128,
+               "intermediate_size": 7168, "moe_intermediate_size": 768,
+               "n_routed_experts": 16, "num_routed_experts": 256,
+               "first_expert": 0, "n_shared_experts": 1,
+               "num_experts_per_tok": 8, "first_k_dense_replace": 1,
+               "routed_scaling_factor": 2.5, "rope_theta": 32000000,
+               "rms_norm_eps": 1e-6}
 # the encoders that read their sizes from a file (--block_config)
-BLOCKS = {"lfm2_moe": LFM_BLOCK, "qwen3_next": QWEN_BLOCK}
-CONFIG_BATCH = {"lfm2_moe": 128, "qwen3_next": 128}
+BLOCKS = {"lfm2_moe": LFM_BLOCK, "qwen3_next": QWEN_BLOCK,
+          "joyai_flash": JOYAI_BLOCK}
+CONFIG_BATCH = {"lfm2_moe": 128, "qwen3_next": 128, "joyai_flash": 128}
 
 CONFIG_FLAGS = {
     "bag": [],
@@ -86,6 +101,7 @@ CONFIG_FLAGS = {
                "--lr_schedule", "constant"],
     "lfm2_moe": ["--encoder", "lfm2_moe"],
     "qwen3_next": ["--encoder", "qwen3_next"],
+    "joyai_flash": ["--encoder", "joyai_flash"],
 }
 
 

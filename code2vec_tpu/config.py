@@ -242,18 +242,24 @@ class Config:
     # (reference parity), "transformer" (set transformer over the
     # contexts, models/transformer_encoder.py; BASELINE.json
     # configs[4]), "lfm2_moe" (the LFM2-MoE decoder block over the
-    # contexts in reader order, models/lfm2_moe_encoder.py) or
+    # contexts in reader order, models/lfm2_moe_encoder.py),
     # "qwen3_next" (Qwen3-Next's: gated DeltaNet and gated attention,
     # routed experts beside a shared one,
-    # models/qwen3_next_encoder.py). ----
+    # models/qwen3_next_encoder.py) or "joyai_flash"
+    # (JoyAI-LLM-Flash's: latent attention in every layer, a scaled
+    # sigmoid router beside a shared expert,
+    # models/joyai_flash_encoder.py). ----
     ENCODER_TYPE: str = "bag"
-    # lfm2_moe, qwen3_next: the block's sizes, a JSON file under the
-    # keys of the model's own config.json (hidden_size, num_experts =
-    # the experts held HERE, num_routed_experts, first_expert, ...;
+    # lfm2_moe, qwen3_next, joyai_flash: the block's sizes, a JSON file
+    # under the keys of the model's own config.json (hidden_size,
+    # num_experts or n_routed_experts = the experts held HERE,
+    # num_routed_experts, first_expert, ...;
     # models/lfm2_moe_encoder.Lfm2Dims,
-    # models/qwen3_next_encoder.Qwen3NextDims).
+    # models/qwen3_next_encoder.Qwen3NextDims,
+    # models/joyai_flash_encoder.JoyaiDims).
     # benchmark/configs/java-large-lfm2moe.json is one chip's share of
-    # LFM2-24B-A2B, java-large-qwen3next.json of Qwen3-Next-80B-A3B.
+    # LFM2-24B-A2B, java-large-qwen3next.json of Qwen3-Next-80B-A3B,
+    # java-large-joyai.json of JoyAI-LLM-Flash.
     BLOCK_CONFIG: Optional[str] = None
     XF_LAYERS: int = 2
     # 3 heads -> head_dim = 384/3 = 128 = one MXU lane width: measured
@@ -544,7 +550,8 @@ class Config:
                        choices=list(encoder_names()))
         p.add_argument("--block_config", "--lfm_config",
                        dest="block_config", default=None,
-                       help="--encoder lfm2_moe | qwen3_next: JSON file "
+                       help="--encoder lfm2_moe | qwen3_next | "
+                            "joyai_flash: JSON file "
                             "with the block's sizes under the model's "
                             "config.json keys (num_experts = experts "
                             "held here, num_routed_experts, "

@@ -82,6 +82,8 @@ class ModelDims:
     lfm: Optional[Any] = None
     # qwen3_next's (models/qwen3_next_encoder.Qwen3NextDims)
     qwen: Optional[Any] = None
+    # joyai_flash's (models/joyai_flash_encoder.JoyaiDims)
+    joyai: Optional[Any] = None
 
     @property
     def context_vector_size(self) -> int:

@@ -76,13 +76,9 @@ import jax.numpy as jnp
 from code2vec_tpu.models import seq_block
 from code2vec_tpu.models.encoder import ModelDims, embed_contexts
 from code2vec_tpu.models.registry import EncoderSpec
+from code2vec_tpu.models.seq_block import BIAS_SCALE
 from code2vec_tpu.models.transformer_encoder import _rms_norm
 from code2vec_tpu.ops.moe import route
-
-# small beside the gaps between a token's top scores (about 0.016
-# between the fourth and the fifth of 64): it turns near-ties and leaves
-# the load on the experts even, as the trained buffer's job is
-BIAS_SCALE = 0.005
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,8 +122,13 @@ class Lfm2Dims:
                  "conv_bias": False, "routed_scaling_factor": 1}
         for k, want in fixed.items():
             if config.get(k, want) != want:
-                raise ValueError(f"lfm2_moe implements {k}={want!r} only "
-                                 f"(the file gives {config[k]!r})")
+                raise ValueError(
+                    f"lfm2_moe implements {k}={want!r} only (the file "
+                    f"gives {config[k]!r})" + (
+                        "; a block that weighs the routed sum hands "
+                        "ops/moe.route its `scale`, as "
+                        "models/joyai_flash_encoder.py does"
+                        if k == "routed_scaling_factor" else ""))
         kw = {f.name: config[f.name] for f in dataclasses.fields(cls)
               if f.name in config}
         if "rope_parameters" in config:

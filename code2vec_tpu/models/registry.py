@@ -66,6 +66,7 @@ _MODULES = {
     "transformer": "code2vec_tpu.models.transformer_encoder",
     "lfm2_moe": "code2vec_tpu.models.lfm2_moe_encoder",
     "qwen3_next": "code2vec_tpu.models.qwen3_next_encoder",
+    "joyai_flash": "code2vec_tpu.models.joyai_flash_encoder",
 }
 
 

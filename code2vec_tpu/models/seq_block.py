@@ -1,5 +1,5 @@
 """What the decoder-block encoders share (`lfm2_moe_encoder.py`,
-`qwen3_next_encoder.py`): a stack of residual layers run over a method's
+`qwen3_next_encoder.py`, `joyai_flash_encoder.py`): a stack of residual layers run over a method's
 path-contexts in reader order, position = slot index, valid contexts
 filling from the left. `x` is [B, C, H], `m` the context mask.
 
@@ -12,11 +12,16 @@ filling from the left. `x` is [B, C, H], `m` the context mask.
            width H ; code = pooled W_out2          H -> 3E (`run_block`)
 
 and the operators more than one block has: rotary over the whole head
-or its first part (`rotary`), causal grouped-query attention with the
-head's width, the q/k norm and an optional output gate as arguments
-(`attention`), the SwiGLU (`swiglu`), and the routed experts' wrapper
-that makes the counts which leave the step (`routed_experts`). Which
-norm, which mixers and which router a block has is its own module's.
+or its first part (`rotary`), the masked causal softmax
+(`causal_softmax`) under the two softmax mixers, causal grouped-query
+attention with the head's width, the q/k norm and an optional output
+gate as arguments (`attention`) and multi-head latent attention
+(`latent_attention`: norms on two low-rank latents, a key of two parts
+of which one is a single rotary head shared by every query head), the
+SwiGLU (`swiglu`), the sigmoid routers' fixed selection bias
+(`BIAS_SCALE`), and the routed experts' wrapper that makes the counts
+which leave the step (`routed_experts`). Which norm, which mixers and
+which router a block has is its own module's.
 """
 
 from __future__ import annotations
@@ -30,6 +35,13 @@ import jax.numpy as jnp
 from code2vec_tpu.models.transformer_encoder import (learned_query_pool,
                                                      padding_log_mask)
 from code2vec_tpu.ops.moe import held_experts_ffn, ran_at_bound
+
+# the sigmoid routers' selection bias is a seeded buffer of this scale
+# x normal, held fixed: small beside the gaps between a token's top
+# scores (about 0.016 between the fourth and the fifth of 64), it turns
+# near-ties and leaves the load on the experts even, as the trained
+# buffer's job is
+BIAS_SCALE = 0.005
 
 
 def rotary(x: jax.Array, theta: float,
@@ -49,6 +61,18 @@ def rotary(x: jax.Array, theta: float,
     x32 = x.astype(jnp.float32)
     half = jnp.concatenate([-x32[..., hd // 2:], x32[..., :hd // 2]], -1)
     return (x32 * cos + half * sin).astype(x.dtype)
+
+
+def causal_softmax(logits: jax.Array, mask: jax.Array,
+                   dtype) -> jax.Array:
+    """Scores [B, ..heads.., C queries, C keys] in float32 to attention
+    weights in `dtype`: query t sees the valid slots up to t, the
+    softmax runs in float32."""
+    slot = jnp.arange(logits.shape[-1])
+    seen = (slot[None, :] <= slot[:, None])[None] & (mask > 0)[:, None, :]
+    heads = (None,) * (logits.ndim - 3)
+    logits = jnp.where(seen[(slice(None),) + heads], logits, -1e30)
+    return jax.nn.softmax(logits, axis=-1).astype(dtype)
 
 
 def attention(h: jax.Array, mask: jax.Array, layer: Dict, *, heads: int,
@@ -84,15 +108,73 @@ def attention(h: jax.Array, mask: jax.Array, layer: Dict, *, heads: int,
     q = q.reshape(B, n_kv, n // n_kv, C, hd)
     logits = jnp.einsum("bkgqd,bkcd->bkgqc", q, k).astype(jnp.float32) \
         / math.sqrt(hd)
-    slot = jnp.arange(C)
-    seen = (slot[None, :] <= slot[:, None])[None] & (mask > 0)[:, None, :]
-    logits = jnp.where(seen[:, None, None], logits, -1e30)
-    att = jax.nn.softmax(logits, axis=-1).astype(dtype)
+    att = causal_softmax(logits, mask, dtype)
     out = jnp.einsum("bkgqc,bkcd->bkgqd", att, v)
     out = out.reshape(B, n, C, hd).transpose(0, 2, 1, 3)
     if gated:
         out = out * jax.nn.sigmoid(gate)
     return out.reshape(B, C, n * hd) @ layer["o"].astype(dtype)
+
+
+def deinterleaved(x: jax.Array) -> jax.Array:
+    """The last axis' pairs (2i, 2i + 1) laid out as (i, i + n/2): what
+    `rotary` turns after this is what an interleaved rotary turns
+    before it, and a product of two heads so laid out is the product of
+    the heads."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def latent_attention(h: jax.Array, mask: jax.Array, layer: Dict, *,
+                     heads: int, nope: int, rope: int, v_dim: int,
+                     theta: float, norm: Callable) -> jax.Array:
+    """Causal multi-head latent attention over h [B, C, H]. `layer`
+    holds q_a [H, r_q], q_a_norm, q_b [r_q, heads (nope + rope)], kv_a
+    [H, r_kv + rope], kv_a_norm, kv_b [r_kv, heads (nope + v_dim)] and
+    o [heads v_dim, H]; `norm(t, scale)` runs over each latent:
+
+      c_q = norm(h q_a) ; a head of q = [q_nope | q_rope] = c_q q_b
+      [c_kv | k_rope] = h kv_a ; c_kv = norm(c_kv)
+      a head of [k_nope | v] = c_kv kv_b ; k_rope is ONE head, every
+      query head's
+      q_rope and k_rope turn, pairs (2i, 2i + 1) (interleaved), theta
+      scores = (q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)
+
+    under the causal and the padding mask, softmax in float32. The
+    scores are one einsum over the concatenated head, summed in
+    float32, the shared rotary key broadcast beside each head's k_nope
+    (105 MB in bfloat16 at the published widths): on the v5e that is
+    10.5 ms a layer cheaper, forward and backward, than a second einsum
+    for the rotary part, whose float32 scores XLA writes and copies a
+    second time (PERF.md section 6, PR 34)."""
+    dtype = h.dtype
+    B, C, _ = h.shape
+
+    def turned(t):                              # [B, C, n, rope]
+        return rotary(deinterleaved(t).transpose(0, 2, 1, 3),
+                      theta).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("q_lora"):
+        c_q = norm(h @ layer["q_a"].astype(dtype), layer["q_a_norm"])
+        q = (c_q @ layer["q_b"].astype(dtype)).reshape(B, C, heads,
+                                                       nope + rope)
+    with jax.named_scope("kv_lora"):
+        c_kv, k_rope = jnp.split(h @ layer["kv_a"].astype(dtype),
+                                 [layer["kv_a"].shape[1] - rope], axis=-1)
+        c_kv = norm(c_kv, layer["kv_a_norm"])
+        kv = (c_kv @ layer["kv_b"].astype(dtype)).reshape(B, C, heads,
+                                                          nope + v_dim)
+    with jax.named_scope("core"):
+        q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])], axis=-1)
+        k_rope = jnp.broadcast_to(turned(k_rope[:, :, None, :]),
+                                  (B, C, heads, rope))
+        k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+        logits = jnp.einsum("bqnd,bcnd->bnqc", q, k,
+                            preferred_element_type=jnp.float32) \
+            / math.sqrt(nope + rope)
+        att = causal_softmax(logits, mask, dtype)
+        out = jnp.einsum("bnqc,bcnd->bqnd", att, kv[..., nope:])
+    with jax.named_scope("o"):
+        return out.reshape(B, C, heads * v_dim) @ layer["o"].astype(dtype)
 
 
 def swiglu(h: jax.Array, w1: jax.Array, w3: jax.Array,

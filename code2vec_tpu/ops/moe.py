@@ -12,6 +12,11 @@ or, the second router (`score="softmax"`, `model_type` `qwen3_next`):
   s      = softmax(h W_r) over all E           float32, no bias
   chosen = top K of s ;  p_e = s_e / (sum of the K chosen s)
 
+A block whose router weighs the sum (`routed_scaling_factor`) or guards
+its division otherwise hands `route` its `scale` and `eps`
+(`model_type` `joyai_llm_flash`: p_e = 2.5 s_e / (sum + 1e-20)); it is
+the same router, not a third.
+
 The layer is told the first expert it holds and how many; it routes over
 all E and computes its own part. What the absent experts would add is
 left out, and nothing stands in for the exchange that would bring other
@@ -57,26 +62,30 @@ ROUTE_EPS = 1e-6
 
 
 def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array],
-          top_k: int, score: str = "sigmoid"
-          ) -> Tuple[jax.Array, jax.Array]:
+          top_k: int, score: str = "sigmoid", *, scale: float = 1.0,
+          eps: float = ROUTE_EPS) -> Tuple[jax.Array, jax.Array]:
     """(chosen experts [N, K] int32, their weights p [N, K] float32).
     The scores are taken in float32 at the highest matmul precision:
     2 H E operations a token, and who is chosen should not hang on a
-    bfloat16 product. `score` "softmax" takes no bias."""
+    bfloat16 product. `score` "softmax" takes no bias and no `eps`.
+    p = `scale` s / (sum of the chosen s + `eps`); a scale of 1 is not
+    multiplied in."""
     logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if score == "softmax":
         assert bias is None
         s_chosen, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
                                          top_k)
-        return chosen.astype(jnp.int32), \
-            s_chosen / jnp.sum(s_chosen, axis=-1, keepdims=True)
-    assert score == "sigmoid", score
-    s = jax.nn.sigmoid(logits)
-    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
-    s_chosen = jnp.take_along_axis(s, chosen, axis=-1)
-    p = s_chosen / (jnp.sum(s_chosen, axis=-1, keepdims=True) + ROUTE_EPS)
-    return chosen.astype(jnp.int32), p
+        chosen = chosen.astype(jnp.int32)
+        p = s_chosen / jnp.sum(s_chosen, axis=-1, keepdims=True)
+    else:
+        assert score == "sigmoid", score
+        s = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        s_chosen = jnp.take_along_axis(s, chosen, axis=-1)
+        p = s_chosen / (jnp.sum(s_chosen, axis=-1, keepdims=True) + eps)
+        chosen = chosen.astype(jnp.int32)
+    return chosen, (p if scale == 1.0 else scale * p)
 
 
 # the arrays between the sort and the sum back hold this many times the

@@ -137,6 +137,7 @@ def _build_manifest(step: int, dims: ModelDims,
         "ring_attention": dims.ring_attention,
         "lfm": dataclasses.asdict(dims.lfm) if dims.lfm else None,
         "qwen": dataclasses.asdict(dims.qwen) if dims.qwen else None,
+        "joyai": dataclasses.asdict(dims.joyai) if dims.joyai else None,
         "step": step,
     }
     if extra_manifest:
@@ -605,7 +606,7 @@ def load_dims(ckpt_dir: str) -> ModelDims:
         xf_mlp_ratio=m.get("xf_mlp_ratio", 4),
         xf_remat=m.get("xf_remat", False),
         ring_attention=m.get("ring_attention", False),
-        # the encoder's own sizes (`lfm`, `qwen`), read by its spec
+        # the encoder's own sizes (`lfm`, `qwen`, `joyai`), read by its spec
         **encoder_spec(m.get("encoder_type", "bag")).sizes_from_manifest(m),
     )
 

@@ -20,6 +20,7 @@ from code2vec_tpu.data import staircase as st
 from code2vec_tpu.models import registry
 from code2vec_tpu.models.encoder import (ModelDims, get_encode_fn,
                                          init_params)
+from code2vec_tpu.models.joyai_flash_encoder import JoyaiDims
 from code2vec_tpu.models.lfm2_moe_encoder import Lfm2Dims
 from code2vec_tpu.models.qwen3_next_encoder import Qwen3NextDims
 from code2vec_tpu.parallel.mesh import make_mesh
@@ -52,10 +53,19 @@ QWEN_BLOCK = dict(num_hidden_layers=2, full_attention_interval=2,
                   num_experts=2, num_routed_experts=4, first_expert=1,
                   num_experts_per_tok=2, moe_intermediate_size=24,
                   shared_expert_intermediate_size=16)
+JOYAI_BLOCK = dict(num_hidden_layers=2, hidden_size=32,
+                   num_attention_heads=4, q_lora_rank=12, kv_lora_rank=8,
+                   qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+                   intermediate_size=48, moe_intermediate_size=24,
+                   n_routed_experts=2, num_routed_experts=4, first_expert=1,
+                   n_shared_experts=1, num_experts_per_tok=2,
+                   first_k_dense_replace=1, routed_scaling_factor=2.5,
+                   rope_theta=32e6, rms_norm_eps=1e-6)
 # an encoder's own sizes, as ModelDims keywords (none: the defaults)
 SIZES = {"transformer": dict(xf_layers=2, xf_heads=4, xf_remat=True),
          "lfm2_moe": dict(lfm=Lfm2Dims.from_config(BLOCK)),
-         "qwen3_next": dict(qwen=Qwen3NextDims.from_config(QWEN_BLOCK))}
+         "qwen3_next": dict(qwen=Qwen3NextDims.from_config(QWEN_BLOCK)),
+         "joyai_flash": dict(joyai=JoyaiDims.from_config(JOYAI_BLOCK))}
 
 
 def dims_of(name: str, **kw) -> ModelDims:

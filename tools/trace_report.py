@@ -34,7 +34,7 @@ emits) and produces:
     embedding gather spreads a PAD read) beside their gathered slots
     (`gather_slots`: what the step chosen for each batch took table
     rows for, the staircase's area or every slot), and for a routed-experts
-    encoder the `moe/route` records: rows routed to the experts held
+    encoder (lfm2_moe, qwen3_next, joyai_flash) the `moe/route` records: rows routed to the experts held
     here over the valid tokens' choices, the fullest expert's rows
     over the mean, and the spans' `row_bound` and `compact_layers`.
 
