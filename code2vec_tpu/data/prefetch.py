@@ -42,7 +42,15 @@ profiler's trace on the device's clock):
                               for the batch takes table rows for, the
                               staircase's area when the batch fits it
                               and rows x max_contexts when it does
-                              not; data/staircase.py)
+                              not; data/staircase.py; `attn_pairs`
+                              beside it for an encoder whose softmax
+                              mixers' core runs by query block over
+                              the staircase, `models/seq_block.py`:
+                              the query-key pairs a head of one
+                              softmax layer of the chosen step scores,
+                              `staircase.attn_pairs` when the batch
+                              fits and rows x max_contexts^2 when
+                              not)
   infeed/blocked   producer   the bounded put into the queue: the
                               producer's slack (`seq`)
   infeed/pop_wait  consumer   `q.get()` (`seq` of the batch it popped;
@@ -76,19 +84,19 @@ _BATCH_SEQ = itertools.count()
 
 class BatchRecord:
     """One produced batch: its sequence number, rows, PAD slots,
-    gathered slots and bytes, and on the recorder's clock where its
-    read started and its transfer ended. Rides the queue item the
+    gathered slots, scored pairs and bytes, and on the recorder's clock
+    where its read started and its transfer ended. Rides the queue item the
     producer builds; `on_produced` (the `--trace` hook) gets it after
     the transfer."""
 
-    __slots__ = ("seq", "rows", "pad_slots", "gather_slots", "bytes",
-                 "read_start", "transfer_end")
+    __slots__ = ("seq", "rows", "pad_slots", "gather_slots", "attn_pairs",
+                 "bytes", "read_start", "transfer_end")
 
     def __init__(self, seq: int, rows, pad_slots, read_start: float):
         self.seq = seq
         self.rows = rows
         self.pad_slots = pad_slots
-        self.gather_slots = None
+        self.gather_slots = self.attn_pairs = None
         self.bytes = 0
         self.read_start = read_start
         self.transfer_end = None
@@ -131,13 +139,16 @@ def _read_batches(batches: Iterable, recorder
 def _transfer(fn: Callable, b, record: BatchRecord, recorder,
               on_produced: Optional[Callable]):
     """`fn(b)` under `infeed/transfer`; the bytes are those of what it
-    returns, and the gathered slots what it says of itself."""
+    returns, and the gathered slots and scored pairs what it says of
+    itself."""
     with recorder.start_span("infeed/transfer", seq=record.seq) as span:
         out = fn(b)
         record.bytes = span.attrs["bytes"] = _nbytes(out)
-        record.gather_slots = getattr(out, "gather_slots", None)
-        if record.gather_slots is not None:
-            span.attrs["gather_slots"] = record.gather_slots
+        for count in ("gather_slots", "attn_pairs"):
+            value = getattr(out, count, None)
+            setattr(record, count, value)
+            if value is not None:
+                span.attrs[count] = value
     record.transfer_end = span.interval[1]
     if on_produced is not None:
         on_produced(record)

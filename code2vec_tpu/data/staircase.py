@@ -45,6 +45,11 @@ Stairs = Tuple[Tuple[int, int], ...]
 _WHOLE_SHARD_ROWS = 1 << 21
 _SAMPLE_ROWS = 1 << 16
 _SCAN_ROWS = 1 << 16       # rows of the memmap looked at at a time
+# the least width of a query block of the softmax mixers' core, chosen
+# on the chip from times (PERF.md section 6, PR 35: at 128 rows x 200
+# slots two to six blocks cost a mixer the same within 2%, and every
+# block is a shape more in the step's executable)
+_BLOCK_SLOTS = 64
 
 
 def length_order(lengths: np.ndarray, groups: int = 1) -> np.ndarray:
@@ -120,6 +125,33 @@ def rows_kept(stairs: Stairs, column: int) -> int:
     stand, so in a batch that `fits` the rows from there down are PAD
     in that column and in every later one."""
     return [kept for first, kept in stairs if first <= column][-1]
+
+
+def query_blocks(stairs: Stairs, max_contexts: int
+                 ) -> Tuple[Tuple[int, int, int], ...]:
+    """(first slot, end slot, rows) of the query blocks the softmax
+    mixers' core runs over (`models/seq_block.causal_core`): each
+    rectangle a block, or neighbours joined under the first one's rows
+    until a block is `_BLOCK_SLOTS` wide (a last, narrower one joins
+    the block before it). A block's queries see the keys of its rows
+    up to its end slot and no others."""
+    firsts = [first for first, _ in stairs] + [max_contexts]
+    blocks = []
+    for k, (first, kept) in enumerate(stairs):
+        if blocks and blocks[-1][1] - blocks[-1][0] < _BLOCK_SLOTS:
+            blocks[-1][1] = firsts[k + 1]
+        else:
+            blocks.append([first, firsts[k + 1], kept])
+    if len(blocks) > 1 and blocks[-1][1] - blocks[-1][0] < _BLOCK_SLOTS:
+        end = blocks.pop()[1]
+        blocks[-1][1] = end
+    return tuple(tuple(b) for b in blocks)
+
+
+def attn_pairs(blocks: Tuple[Tuple[int, int, int], ...]) -> int:
+    """Query-key pairs a head of one softmax layer scores over the
+    query blocks: rows x queries x keys up to the block's end."""
+    return sum(kept * (end - first) * end for first, end, kept in blocks)
 
 
 def fits(stairs: Stairs, id_arrays, groups: int = 1) -> bool:

@@ -364,22 +364,37 @@ class Code2VecModel(Code2VecModelBase):
         """`_device_batch` for the training infeed, with the producer's
         answer on it: whether every id of a whole batch outside the
         staircase is PAD (`staircase.fits`; an ordered batch of the
-        shard the staircase was sized from does, nearly always), and
-        the slots the step it chooses takes table rows for."""
+        shard the staircase was sized from does, nearly always), the
+        slots the step it chooses takes table rows for and, where the
+        encoder's softmax mixers run over the staircase, the pairs a
+        head of one of them scores."""
         stairs, groups = self._staircase, self._stair_groups
         whole = b.num_valid_examples == b.target_index.shape[0]
         fits = stairs is not None and whole and staircase.fits(
             stairs, (b.path_source_token_indices, b.path_indices,
                      b.path_target_token_indices), groups)
+        contexts = self.dims.max_contexts
         if fits:
-            slots = groups * staircase.area(stairs, self.dims.max_contexts)
+            slots = groups * staircase.area(stairs, contexts)
         else:
-            slots = b.num_valid_examples * self.dims.max_contexts
+            slots = b.num_valid_examples * contexts
             if stairs is not None and whole and not self._full_step_logged:
                 self._full_step_logged = True
                 self.log("a whole batch does not fit the staircase: it "
                          "runs the full step (compiled at its first use)")
-        return TrainBatch(self._device_batch(b), fits, slots)
+        batch = TrainBatch(self._device_batch(b), fits, slots)
+        if encoder_spec(self.dims.encoder_type).scores_by_staircase:
+            # beside `gather_slots` on the batch's `infeed/transfer`
+            # span (data/prefetch.py): what the step `_by_fit` chooses
+            # for this batch was compiled to score, by the function the
+            # encoder compiled it by; the host's word, not the device's
+            from code2vec_tpu.models.seq_block import core_blocks
+            blocks = core_blocks(stairs if fits else None, self.mesh,
+                                 contexts)
+            batch.attn_pairs = (
+                b.num_valid_examples * contexts ** 2 if blocks is None
+                else staircase.attn_pairs(blocks))
+        return batch
 
     def _train_infeed(self, reader, instrument=None, heartbeat=None):
         from code2vec_tpu.data.prefetch import build_train_infeed

@@ -292,7 +292,9 @@ def encode_joyai_flash(params: Dict, source_ids: jax.Array,
     (the train step hands it to the spec's recorder, `obs.route`; the
     other steps let it fall). `use_pallas` is taken and not read: the
     grouped product is XLA's own kernel on the TPU, the attention XLA's
-    on every backend."""
+    on every backend. `staircase` (training only; `embed_contexts` has
+    who checks it): the mixers' core also runs by the query blocks
+    `seq_block.core_blocks` makes of it."""
     del use_pallas
     cfg, sub = dims.joyai, params["joyai"]
     norm = functools.partial(_rms_norm, eps=cfg.rms_norm_eps)
@@ -314,11 +316,14 @@ def encode_joyai_flash(params: Dict, source_ids: jax.Array,
         experts = shard_map_over_batch(experts, mesh,
                                        (True, True) + (False,) * 5)
 
+    blocks = seq_block.core_blocks(staircase, mesh, mask.shape[1])
+
     def mixer(h, layer):
         return seq_block.latent_attention(
             h, mask, layer, heads=cfg.num_attention_heads,
             nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
-            v_dim=cfg.v_head_dim, theta=cfg.rope_theta, norm=norm)
+            v_dim=cfg.v_head_dim, theta=cfg.rope_theta, norm=norm,
+            blocks=blocks)
 
     def dense(h, layer):
         return seq_block.swiglu(h, layer["w1"], layer["w3"],
@@ -375,4 +380,5 @@ SPEC = EncoderSpec(
     encode=encode_joyai_flash, params_key="joyai", init=_init,
     sizes_from_config=_sizes_from_config,
     sizes_from_manifest=_sizes_from_manifest, check_config=_check_config,
-    eval_batch_at_most_train=True, recorder=_recorder)
+    eval_batch_at_most_train=True, scores_by_staircase=True,
+    recorder=_recorder)

@@ -254,7 +254,9 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
     let it fall).
     `use_pallas` is taken and not read: the
     grouped product is XLA's own kernel on the TPU, the attention XLA's
-    on every backend."""
+    on every backend. `staircase` (training only; `embed_contexts` has
+    who checks it): the attention layers' core also runs by the query
+    blocks `seq_block.core_blocks` makes of it."""
     del use_pallas
     cfg, lfm = dims.lfm, params["lfm"]
     norm = functools.partial(_rms_norm, eps=cfg.norm_eps)
@@ -275,13 +277,15 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
         experts = shard_map_over_batch(experts, mesh,
                                        (True, True) + (False,) * 5)
 
+    blocks = seq_block.core_blocks(staircase, mesh, mask.shape[1])
+
     def mixer(h, layer):
         if "conv_k" in layer:
             return _short_conv(h, mask, layer)
         return seq_block.attention(
             h, mask, layer, heads=cfg.num_attention_heads,
             kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
-            theta=cfg.rope_theta, norm=norm)
+            theta=cfg.rope_theta, norm=norm, blocks=blocks)
 
     def dense(h, layer):
         return seq_block.swiglu(h, layer["w1"], layer["w3"],
@@ -335,4 +339,5 @@ SPEC = EncoderSpec(
     encode=encode_lfm2_moe, params_key="lfm", init=_init,
     sizes_from_config=_sizes_from_config,
     sizes_from_manifest=_sizes_from_manifest, check_config=_check_config,
-    eval_batch_at_most_train=True, recorder=_recorder)
+    eval_batch_at_most_train=True, scores_by_staircase=True,
+    recorder=_recorder)

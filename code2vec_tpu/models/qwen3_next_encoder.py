@@ -344,7 +344,8 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
     also bounds the scans: chunk n runs over the rows its first slot's
     rectangle keeps, on each device its own, and "the chunks its scan
     ran over" is that bound's sum over the devices; with none it is
-    every method's every chunk."""
+    every method's every chunk. The attention layers' core runs by the
+    query blocks `seq_block.core_blocks` makes of it."""
     del use_pallas
     cfg, sub = dims.qwen, params["qwen"]
 
@@ -369,20 +370,19 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
     bound = None if staircase is None else tuple(
         rows_kept(staircase, n * L) for n in range(chunks))
     scan = functools.partial(delta_rule.gated_delta_rule, rows=bound)
-    devices = 1
+    devices = seq_block.batch_devices(mesh)
     if mesh is not None:
         # each device routes and scans its own rows of the batch
-        from code2vec_tpu.parallel.mesh import DATA_AXIS, DCN_AXIS
         from code2vec_tpu.parallel.sharding import shard_map_over_batch
         experts = shard_map_over_batch(experts, mesh,
                                        (True, True) + (False,) * 4)
         scan = shard_map_over_batch(scan, mesh, (True,) * 6)
-        devices = mesh.shape[DCN_AXIS] * mesh.shape[DATA_AXIS]
 
     scanned = jnp.stack([jnp.int32(L),
                          jnp.int32(B * chunks if bound is None
                                    else devices * sum(bound)),
                          delta_rule.live_chunks(mask)])
+    blocks = seq_block.core_blocks(staircase, mesh, C)
 
     def mixer(h, layer):
         if "in_qkvz" in layer:
@@ -391,7 +391,7 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
             h, mask, layer, heads=cfg.num_attention_heads,
             kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
             theta=cfg.rope_theta, norm=norm, turned=cfg.rotary_dim,
-            gated=True)
+            gated=True, blocks=blocks)
 
     def ff(h, layer):
         out, counts = experts(h, mask, layer["router"], layer["w1"],
@@ -448,4 +448,5 @@ SPEC = EncoderSpec(
     encode=encode_qwen3_next, params_key="qwen", init=_init,
     sizes_from_config=_sizes_from_config,
     sizes_from_manifest=_sizes_from_manifest, check_config=_check_config,
-    eval_batch_at_most_train=True, recorder=_recorder)
+    eval_batch_at_most_train=True, scores_by_staircase=True,
+    recorder=_recorder)

@@ -13,7 +13,8 @@ filling from the left. `x` is [B, C, H], `m` the context mask.
 
 and the operators more than one block has: rotary over the whole head
 or its first part (`rotary`), the masked causal softmax
-(`causal_softmax`) under the two softmax mixers, causal grouped-query
+(`causal_softmax`) and the core around it (`causal_core`: scores,
+softmax, values) under the two softmax mixers, causal grouped-query
 attention with the head's width, the q/k norm and an optional output
 gate as arguments (`attention`) and multi-head latent attention
 (`latent_attention`: norms on two low-rank latents, a key of two parts
@@ -22,6 +23,19 @@ SwiGLU (`swiglu`), the sigmoid routers' fixed selection bias
 (`BIAS_SCALE`), and the routed experts' wrapper that makes the counts
 which leave the step (`routed_experts`). Which norm, which mixers and
 which router a block has is its own module's.
+
+Who passes the training staircase (`data/staircase.py`) to what:
+`training/steps.make_train_step` compiles its staircase step with one,
+`encode_lfm2_moe`, `encode_qwen3_next` and `encode_joyai_flash` hand it
+to `embed_contexts` (any mesh) and ask `core_blocks` here for the query
+blocks it gives their softmax mixers (`attention(blocks=)`,
+`latent_attention(blocks=)`), which hand them to `causal_core`: the
+core then runs by query block. `core_blocks` is the one place that
+says whether it does (a staircase, and the batch's rows on one device):
+the producer's count of the pairs a step scores
+(`Code2VecModel._train_device_batch`) asks it too. Every other program
+(the full step, evaluation, prediction, serving, rows dealt to several
+devices) gets None and lowers to the one whole core.
 """
 
 from __future__ import annotations
@@ -32,6 +46,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from code2vec_tpu.data.staircase import Stairs, query_blocks
 from code2vec_tpu.models.transformer_encoder import (learned_query_pool,
                                                      padding_log_mask)
 from code2vec_tpu.ops.moe import held_experts_ffn, ran_at_bound
@@ -44,18 +59,20 @@ from code2vec_tpu.ops.moe import held_experts_ffn, ran_at_bound
 BIAS_SCALE = 0.005
 
 
-def rotary(x: jax.Array, theta: float,
-           turned: Optional[int] = None) -> jax.Array:
-    """x [B, heads, C, hd], position = index along C; the first `turned`
-    of each head turn (None: the whole head), pairs (i, i + turned/2)
-    (rotate-half); the rest pass as they are."""
+def rotary(x: jax.Array, theta: float, turned: Optional[int] = None,
+           first: int = 0) -> jax.Array:
+    """x [B, heads, C, hd], position = `first` + index along C; the
+    first `turned` of each head turn (None: the whole head), pairs (i,
+    i + turned/2) (rotate-half); the rest pass as they are."""
     hd = x.shape[-1]
     if turned is not None and turned < hd:
         return jnp.concatenate(
-            [rotary(x[..., :turned], theta), x[..., turned:]], axis=-1)
+            [rotary(x[..., :turned], theta, first=first), x[..., turned:]],
+            axis=-1)
     C = x.shape[-2]
     inv = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    angle = jnp.arange(C, dtype=jnp.float32)[:, None] * inv[None, :]
+    slot = jnp.arange(C, dtype=jnp.float32)
+    angle = (first + slot if first else slot)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
     x32 = x.astype(jnp.float32)
@@ -65,27 +82,89 @@ def rotary(x: jax.Array, theta: float,
 
 def causal_softmax(logits: jax.Array, mask: jax.Array,
                    dtype) -> jax.Array:
-    """Scores [B, ..heads.., C queries, C keys] in float32 to attention
-    weights in `dtype`: query t sees the valid slots up to t, the
-    softmax runs in float32."""
-    slot = jnp.arange(logits.shape[-1])
-    seen = (slot[None, :] <= slot[:, None])[None] & (mask > 0)[:, None, :]
+    """Scores [B, ..heads.., Q queries, C keys] in float32 to attention
+    weights in `dtype`: the queries are the last Q of the C slots,
+    query t sees the valid slots up to t, the softmax runs in
+    float32."""
+    queries, keys = logits.shape[-2:]
+    slot = jnp.arange(keys)
+    at = slot if queries == keys else slot[keys - queries:]
+    seen = (slot[None, :] <= at[:, None])[None] & (mask > 0)[:, None, :]
     heads = (None,) * (logits.ndim - 3)
     logits = jnp.where(seen[(slice(None),) + heads], logits, -1e30)
     return jax.nn.softmax(logits, axis=-1).astype(dtype)
 
 
+# a query block (`core_blocks`): (first slot, end slot, rows); its
+# queries are its rows' slots first .. end, and what causality lets them
+# see is those rows' slots 0 .. end
+Block = Tuple[int, int, int]
+Span = Optional[Tuple[int, int]]
+
+
+def cut(t: jax.Array, *spans: Span) -> jax.Array:
+    """`t[start:end]` along each axis that `spans` gives a (start, end)
+    for (None, or none given: the whole axis), in ONE slice of `t`: cut
+    in two steps, rows and then slots, XLA lays the rows' every slot out
+    again before it cuts (PERF.md section 6, PR 35). `t` itself where
+    every span is whole."""
+    whole = [(0, size) for size in t.shape]
+    spans = [span or axis for span, axis in
+             zip(spans + (None,) * (t.ndim - len(spans)), whole)]
+    if spans == whole:
+        return t
+    return jax.lax.slice(t, *zip(*spans))
+
+
+def causal_core(logits: Callable, v: Callable, mask: jax.Array, dtype, *,
+                values: str, slot_axis: int,
+                blocks: Optional[Tuple[Block, ...]] = None) -> jax.Array:
+    """What the two softmax mixers share between their projections: the
+    causal and the padding mask, the softmax in float32 and `values` (an
+    einsum of the weights in `dtype` and v). The mixer's own are
+    `logits(block)`, its scores [rows, ..heads.., queries, keys] in
+    float32 and already scaled, and `v(block)`, each made from the
+    block's `cut` of its projections (v is asked for after the softmax,
+    where it always was taken). The result holds its slots on
+    `slot_axis`, its rows on axis 0.
+
+    `blocks` (`core_blocks`; None: the one core over every row and slot
+    that this always was) is the caller's word that the batch is ordered
+    longest bag first and PAD outside the blocks (`embed_contexts` has
+    who checks it). The core then runs once a query block: the block's
+    rows and slots of q against the same rows of k and v up to the
+    block's last slot. A valid query's result differs from the whole
+    core's by the order of its sums alone; a PAD query outside the
+    blocks, which nothing reads, gets zeros."""
+    B, C = mask.shape
+
+    def core(block: Block) -> jax.Array:
+        _, end, kept = block
+        att = causal_softmax(logits(block), cut(mask, (0, kept), (0, end)),
+                             dtype)
+        return jnp.einsum(values, att, v(block))
+
+    if blocks is None:
+        return core((0, C, B))
+    out = []
+    for block in blocks:
+        o = core(block)
+        out.append(jnp.pad(o, ((0, B - block[2]),)
+                           + ((0, 0),) * (o.ndim - 1)))
+    return jnp.concatenate(out, axis=slot_axis)
+
+
 def attention(h: jax.Array, mask: jax.Array, layer: Dict, *, heads: int,
               kv_heads: int, head_dim: int, theta: float, norm: Callable,
-              turned: Optional[int] = None, gated: bool = False
-              ) -> jax.Array:
+              turned: Optional[int] = None, gated: bool = False,
+              blocks: Optional[Tuple[Block, ...]] = None) -> jax.Array:
     """Causal grouped-query attention over h [B, C, H]: `layer` holds q,
     k, v, o and the q/k norms' q_norm, k_norm (`norm(t, scale)` runs
     over each head); kv head j serves query heads j n/n_kv ..; scores
     over sqrt(head_dim) under the causal and the padding mask, softmax
     in float32. `gated`: q's projection is twice as wide, a head's
     second half a gate, and the heads' output is multiplied by its
-    sigmoid before o."""
+    sigmoid before o. `blocks`: `causal_core`."""
     dtype = h.dtype
     B, C, _ = h.shape
     n, n_kv, hd = heads, kv_heads, head_dim
@@ -101,15 +180,28 @@ def attention(h: jax.Array, mask: jax.Array, layer: Dict, *, heads: int,
 
     q, gate = split(h @ layer["q"].astype(dtype), n, layer["q_norm"])
     assert (gate is not None) == gated
-    q = rotary(q, theta, turned)
+    if blocks is None:      # the whole core turns q where it always did
+        q = rotary(q, theta, turned)
     k = rotary(split(h @ layer["k"].astype(dtype), n_kv,
                      layer["k_norm"])[0], theta, turned)
     v, _ = split(h @ layer["v"].astype(dtype), n_kv)
-    q = q.reshape(B, n_kv, n // n_kv, C, hd)
-    logits = jnp.einsum("bkgqd,bkcd->bkgqc", q, k).astype(jnp.float32) \
-        / math.sqrt(hd)
-    att = causal_softmax(logits, mask, dtype)
-    out = jnp.einsum("bkgqc,bkcd->bkgqd", att, v)
+
+    def logits(block):
+        """By block q turns on the block's rows, from its first slot on
+        (on the v5e the whole q turned and then cut costs the layer a
+        pass more: PERF.md section 6, PR 35)."""
+        first, end, kept = block
+        q_b = cut(q, (0, kept), None, (first, end))
+        if blocks is not None:
+            q_b = rotary(q_b, theta, turned, first=first)
+        q_b = q_b.reshape(-1, n_kv, n // n_kv, end - first, hd)
+        return jnp.einsum("bkgqd,bkcd->bkgqc", q_b,
+                          cut(k, (0, kept), None, (0, end))
+                          ).astype(jnp.float32) / math.sqrt(hd)
+
+    out = causal_core(
+        logits, lambda block: cut(v, (0, block[2]), None, (0, block[1])),
+        mask, dtype, values="bkgqc,bkcd->bkgqd", slot_axis=-2, blocks=blocks)
     out = out.reshape(B, n, C, hd).transpose(0, 2, 1, 3)
     if gated:
         out = out * jax.nn.sigmoid(gate)
@@ -126,7 +218,9 @@ def deinterleaved(x: jax.Array) -> jax.Array:
 
 def latent_attention(h: jax.Array, mask: jax.Array, layer: Dict, *,
                      heads: int, nope: int, rope: int, v_dim: int,
-                     theta: float, norm: Callable) -> jax.Array:
+                     theta: float, norm: Callable,
+                     blocks: Optional[Tuple[Block, ...]] = None
+                     ) -> jax.Array:
     """Causal multi-head latent attention over h [B, C, H]. `layer`
     holds q_a [H, r_q], q_a_norm, q_b [r_q, heads (nope + rope)], kv_a
     [H, r_kv + rope], kv_a_norm, kv_b [r_kv, heads (nope + v_dim)] and
@@ -145,13 +239,13 @@ def latent_attention(h: jax.Array, mask: jax.Array, layer: Dict, *,
     (105 MB in bfloat16 at the published widths): on the v5e that is
     10.5 ms a layer cheaper, forward and backward, than a second einsum
     for the rotary part, whose float32 scores XLA writes and copies a
-    second time (PERF.md section 6, PR 34)."""
+    second time (PERF.md section 6, PR 34). `blocks`: `causal_core`."""
     dtype = h.dtype
     B, C, _ = h.shape
 
-    def turned(t):                              # [B, C, n, rope]
-        return rotary(deinterleaved(t).transpose(0, 2, 1, 3),
-                      theta).transpose(0, 2, 1, 3)
+    def turned(t, first=0):                     # [B, C, n, rope]
+        return rotary(deinterleaved(t).transpose(0, 2, 1, 3), theta,
+                      first=first).transpose(0, 2, 1, 3)
 
     with jax.named_scope("q_lora"):
         c_q = norm(h @ layer["q_a"].astype(dtype), layer["q_a_norm"])
@@ -163,16 +257,33 @@ def latent_attention(h: jax.Array, mask: jax.Array, layer: Dict, *,
         c_kv = norm(c_kv, layer["kv_a_norm"])
         kv = (c_kv @ layer["kv_b"].astype(dtype)).reshape(B, C, heads,
                                                           nope + v_dim)
-    with jax.named_scope("core"):
-        q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])], axis=-1)
-        k_rope = jnp.broadcast_to(turned(k_rope[:, :, None, :]),
-                                  (B, C, heads, rope))
-        k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
-        logits = jnp.einsum("bqnd,bcnd->bnqc", q, k,
-                            preferred_element_type=jnp.float32) \
+
+    def logits(block):
+        """q and k of a query block are made from its rows and slots of
+        the projections: q's rotary part turned from the block's first
+        slot on, the one rotary key turned and laid out under every
+        head's k_nope."""
+        first, end, kept = block
+        rows, queries, keys = (0, kept), (first, end), (0, end)
+        q_b = jnp.concatenate(
+            [cut(q, rows, queries, None, (0, nope)),
+             turned(cut(q, rows, queries, None, (nope, nope + rope)),
+                    first)], axis=-1)
+        k_r = turned(cut(k_rope, rows, keys)[:, :, None, :])
+        k_r = jnp.broadcast_to(k_r, k_r.shape[:2] + (heads, rope))
+        k_b = jnp.concatenate(
+            [cut(kv, rows, keys, None, (0, nope)), k_r], axis=-1)
+        return jnp.einsum("bqnd,bcnd->bnqc", q_b, k_b,
+                          preferred_element_type=jnp.float32) \
             / math.sqrt(nope + rope)
-        att = causal_softmax(logits, mask, dtype)
-        out = jnp.einsum("bnqc,bcnd->bqnd", att, kv[..., nope:])
+
+    def v(block):
+        return cut(kv, (0, block[2]), (0, block[1]), None,
+                   (nope, nope + v_dim))
+
+    with jax.named_scope("core"):
+        out = causal_core(logits, v, mask, dtype, values="bnqc,bcnd->bqnd",
+                          slot_axis=1, blocks=blocks)
     with jax.named_scope("o"):
         return out.reshape(B, C, heads * v_dim) @ layer["o"].astype(dtype)
 
@@ -258,6 +369,28 @@ def run_block(sub: Dict, emb: jax.Array, mask: jax.Array, compute_dtype,
         code = pooled @ sub["out_proj"].astype(compute_dtype)
     return code, attn, (jnp.stack(counted) if counted else jnp.zeros(
         (0, counts_width), jnp.int32))
+
+
+def batch_devices(mesh) -> int:
+    """The devices a batch's rows are dealt to (1 with no mesh)."""
+    if mesh is None:
+        return 1
+    from code2vec_tpu.parallel.mesh import DATA_AXIS, DCN_AXIS
+    return mesh.shape[DCN_AXIS] * mesh.shape[DATA_AXIS]
+
+
+def core_blocks(staircase: Optional[Stairs], mesh, max_contexts: int
+                ) -> Optional[Tuple[Block, ...]]:
+    """The query blocks the softmax mixers' core runs over in a step
+    compiled for `staircase` on `mesh` (`staircase.query_blocks`), or
+    None where it runs whole: with no staircase, and with the batch's
+    rows dealt to several devices (the staircase is one device's rows,
+    and no cell runs a block over several). Both who compiles the step
+    (the block encoders) and who counts what it scores (the producer's
+    `attn_pairs`, `Code2VecModel._train_device_batch`) ask here."""
+    if staircase is None or batch_devices(mesh) != 1:
+        return None
+    return query_blocks(staircase, max_contexts)
 
 
 def refuse_context_parallel(cfg, name: str) -> None:

@@ -67,7 +67,8 @@ def infeed_produce_instrument(tracer: Tracer,
             "infeed/produce", record.read_start, record.transfer_end,
             seq=record.seq, rows=record.rows,
             pad_slots=record.pad_slots,
-            gather_slots=record.gather_slots, bytes=record.bytes))
+            gather_slots=record.gather_slots,
+            attn_pairs=record.attn_pairs, bytes=record.bytes))
     return on_produced
 
 

@@ -112,3 +112,91 @@ def sharded_eval_setup(dir_path: str):
     cfg.train_data_path = prefix
     cfg.test_data_path = prefix + ".train.c2v"
     return cfg
+
+
+# ---- the softmax mixers' core over a staircase (ISSUE 35) ----------------
+
+# staircases of 8 rows x 20 slots: rectangles of uneven rows, the whole
+# rectangle, and a last rectangle narrower than the others
+STAIR_CASES = {
+    "uneven": ((0, 8), (4, 7), (8, 5), (12, 3), (16, 2)),
+    "one_rectangle": ((0, 8),),
+    "narrow_last": ((0, 8), (8, 5), (16, 2)),
+}
+
+
+def staircase_mask(stairs, rows: int = 8, slots: int = 20):
+    """The mask [rows, slots] float32 of a batch that fits `stairs`,
+    longest bag first, the first bag of every rectangle filling it and
+    the last bag one context long."""
+    import numpy as np
+
+    firsts = [first for first, _ in stairs] + [slots]
+    inside = np.zeros((rows, slots), bool)
+    for k, (first, kept) in enumerate(stairs):
+        inside[:kept, first:firsts[k + 1]] = True
+    reach = inside.sum(axis=1)
+    lengths = np.maximum(1, reach - np.arange(rows) % 3)
+    lengths[-1] = 1
+    mask = (np.arange(slots)[None, :] < lengths[:, None]) & inside
+    return mask.astype(np.float32)
+
+
+def assert_staircase_mixer_is_the_whole(mixer, h, layer, stairs):
+    """`mixer(h, mask, layer, stairs)` over a batch that fits `stairs`
+    against `stairs=None`, float32: the output at every valid slot, zeros
+    at the slots outside the query blocks (the rectangles, or the joined
+    ones), and the gradients of `h` and of every leaf of `layer` under a
+    loss that reads the valid slots."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from code2vec_tpu.data.staircase import query_blocks
+
+    mask = staircase_mask(stairs, *h.shape[:2])
+    inside = np.zeros(mask.shape, bool)
+    for first, end, kept in query_blocks(stairs, mask.shape[1]):
+        inside[:kept, first:end] = True
+    w = jax.random.normal(jax.random.PRNGKey(11), h.shape) * mask[..., None]
+
+    def run(stairs):
+        def loss(h, layer):
+            out = mixer(h, jnp.asarray(mask), layer, stairs)
+            return jnp.sum(out * w), out
+        grad = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))
+        return grad(h, layer)
+
+    (g_h, g_layer), out = run(stairs)
+    (want_h, want_layer), want = run(None)
+    valid = mask > 0
+    np.testing.assert_allclose(np.asarray(out)[valid],
+                               np.asarray(want)[valid], rtol=1e-4,
+                               atol=1e-5)
+    assert not np.asarray(out)[~inside].any()
+    if not inside.all():
+        assert np.asarray(want)[~inside].any()
+    np.testing.assert_allclose(np.asarray(g_h), np.asarray(want_h),
+                               rtol=1e-4, atol=1e-5)
+    assert set(g_layer) == set(want_layer)
+    for name in want_layer:
+        scale = float(jnp.max(jnp.abs(want_layer[name])))
+        assert scale > 0, name
+        np.testing.assert_allclose(
+            np.asarray(g_layer[name]), np.asarray(want_layer[name]),
+            rtol=1e-4, atol=1e-5 * max(1.0, scale), err_msg=name)
+
+
+def lowered_texts(fn, *args):
+    """The lowered text of `fn` and of the gradient of its sum with
+    respect to every argument (under one name whatever `fn` is called,
+    so that two functions' texts can be compared)."""
+    import jax
+    import jax.numpy as jnp
+
+    def mixer(*a):
+        return fn(*a)
+
+    grad = jax.grad(lambda *a: jnp.sum(fn(*a)),
+                    argnums=tuple(range(len(args))))
+    return [jax.jit(f).lower(*args).as_text() for f in (mixer, grad)]

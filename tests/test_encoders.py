@@ -220,13 +220,25 @@ def test_staircase_step_equals_full_step(name):
 
     stairs_params, _, stairs_loss = run(True)
     full_params, _, full_loss = run(False)
-    assert float(stairs_loss) == float(full_loss)
+    # an encoder whose softmax mixers score by query block over the
+    # staircase sums a valid query's keys in another order (ISSUE 35):
+    # its forward is the full step's to float32 rounding, the others'
+    # bit for bit
+    by_block = registry.spec(name).scores_by_staircase
+    if by_block:
+        assert float(stairs_loss) == pytest.approx(float(full_loss),
+                                                   rel=1e-6)
+    else:
+        assert float(stairs_loss) == float(full_loss)
     tables = {"token_emb", "path_emb"}
     for k in full_params:
         got, want = stairs_params[k], full_params[k]
         for a, b in zip(jax.tree_util.tree_leaves(got),
                         jax.tree_util.tree_leaves(want)):
-            if k in tables:
+            if by_block:
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=1e-4, atol=1e-6)
+            elif k in tables:
                 # the gathers' scatters add the same updates in
                 # another order
                 np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -372,6 +372,35 @@ def test_infeed_transfer_carries_the_gathered_slots_a_batch_names():
                for r in rec.records("infeed/transfer"))
 
 
+def test_infeed_transfer_carries_the_scored_pairs_a_batch_names():
+    """`attn_pairs` beside `gather_slots` (ISSUE 35): on the transfer's
+    span and on the `--trace` span where the transferred batch says it
+    of itself (the model's training batches of an encoder whose softmax
+    mixers score by query block do), on neither where it does not."""
+    from code2vec_tpu.obs import SpanChannel, infeed_produce_instrument
+    from code2vec_tpu.training.steps import TrainBatch
+
+    def put(b):
+        out = TrainBatch((np.zeros(4),), True, 100 + b.i)
+        if b.i != 1:
+            out.attn_pairs = 5000 + b.i
+        return out
+
+    clock, tele = FakeClock(), _Events()
+    infeed = _SyncInfeed(FakeReader(clock, 3), put)
+    rec = infeed._recorder = MemoryTracer(clock=clock)
+    infeed._on_produced = infeed_produce_instrument(Tracer.create(tele),
+                                                    SpanChannel())
+    list(infeed)
+    assert [r["attrs"].get("attn_pairs")
+            for r in rec.records("infeed/transfer")] == [5000, None, 5002]
+    assert "attn_pairs" not in rec.records("infeed/transfer")[1]["attrs"]
+    assert [r["attrs"]["gather_slots"]
+            for r in rec.records("infeed/transfer")] == [100, 101, 102]
+    assert [s["attrs"].get("attn_pairs") for s in tele.spans] == [
+        5000, None, 5002]
+
+
 # ---- the production record, on its threads ------------------------------
 
 @pytest.mark.parametrize("kind", ["per_batch", "chunked"])

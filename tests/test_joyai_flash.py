@@ -25,7 +25,9 @@ from code2vec_tpu.models.encoder import (ModelDims, get_encode_fn,
                                          init_params)
 from code2vec_tpu.models.joyai_flash_encoder import JoyaiDims
 from code2vec_tpu.ops import moe
-from tests.helpers import build_tiny_dataset
+from tests.helpers import (STAIR_CASES, assert_staircase_mixer_is_the_whole,
+                           build_tiny_dataset, lowered_texts,
+                           staircase_mask)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
@@ -369,6 +371,150 @@ def test_latent_attention_is_causal_and_its_rotary_key_is_every_heads():
         np.asarray(out), atol=1e-3)
     # the scores run over nope + rope = 12, the values over 6
     assert out.shape == (2, 9, 32)
+
+
+# ---- the core over the training staircase (ISSUE 35) ---------------------
+
+def _latent_attention_before_the_blocks(h, mask, layer, *, heads, nope, rope,
+                                        v_dim, theta, norm):
+    """`seq_block.latent_attention` as it stood before ISSUE 35 (its
+    comments left out): what a caller that passes no staircase still
+    lowers to."""
+    import math
+
+    dtype = h.dtype
+    B, C, _ = h.shape
+
+    def turned(t):
+        return seq_block.rotary(
+            seq_block.deinterleaved(t).transpose(0, 2, 1, 3),
+            theta).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("q_lora"):
+        c_q = norm(h @ layer["q_a"].astype(dtype), layer["q_a_norm"])
+        q = (c_q @ layer["q_b"].astype(dtype)).reshape(B, C, heads,
+                                                       nope + rope)
+    with jax.named_scope("kv_lora"):
+        c_kv, k_rope = jnp.split(h @ layer["kv_a"].astype(dtype),
+                                 [layer["kv_a"].shape[1] - rope], axis=-1)
+        c_kv = norm(c_kv, layer["kv_a_norm"])
+        kv = (c_kv @ layer["kv_b"].astype(dtype)).reshape(B, C, heads,
+                                                          nope + v_dim)
+    with jax.named_scope("core"):
+        q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])], axis=-1)
+        k_rope = jnp.broadcast_to(turned(k_rope[:, :, None, :]),
+                                  (B, C, heads, rope))
+        k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+        logits = jnp.einsum("bqnd,bcnd->bnqc", q, k,
+                            preferred_element_type=jnp.float32) \
+            / math.sqrt(nope + rope)
+        slot = jnp.arange(logits.shape[-1])
+        seen = (slot[None, :] <= slot[:, None])[None] \
+            & (mask > 0)[:, None, :]
+        logits = jnp.where(seen[:, None], logits, -1e30)
+        att = jax.nn.softmax(logits, axis=-1).astype(dtype)
+        out = jnp.einsum("bnqc,bcnd->bqnd", att, kv[..., nope:])
+    with jax.named_scope("o"):
+        return out.reshape(B, C, heads * v_dim) @ layer["o"].astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_with_no_staircase_latent_attention_lowers_to_the_old_program(
+        dtype):
+    """Evaluation, prediction, serving and every batch that does not
+    fit its staircase pass none: forward and backward, what they lower
+    to does not know the argument exists."""
+    layer, _, kw = _mla_layer()
+    h = jax.random.normal(jax.random.PRNGKey(3), (8, 20, 32)).astype(dtype)
+    mask = jnp.asarray(staircase_mask(STAIR_CASES["uneven"]))
+
+    def new(h, layer):
+        return seq_block.latent_attention(h, mask, layer, **kw)
+
+    def old(h, layer):
+        return _latent_attention_before_the_blocks(h, mask, layer, **kw)
+
+    assert lowered_texts(new, h, layer) == lowered_texts(old, h, layer)
+    by_block = lowered_texts(
+        lambda h, layer: seq_block.latent_attention(
+            h, mask, layer, blocks=seq_block.core_blocks(
+                STAIR_CASES["uneven"], None, 20), **kw), h, layer)
+    assert by_block[0] != lowered_texts(new, h, layer)[0]
+
+
+@pytest.mark.parametrize("block_slots", [1, 8])
+@pytest.mark.parametrize("case", list(STAIR_CASES))
+def test_latent_attention_over_a_staircase_is_the_whole_core(
+        case, block_slots, monkeypatch):
+    """Each rectangle a query block, and neighbours joined to blocks of
+    8 slots: the valid slots' output and every gradient are the whole
+    core's to float32 rounding, the slots outside the rectangles read
+    zero."""
+    from code2vec_tpu.data import staircase
+
+    monkeypatch.setattr(staircase, "_BLOCK_SLOTS", block_slots)
+    layer, _, kw = _mla_layer()
+    h = jax.random.normal(jax.random.PRNGKey(3), (8, 20, 32))
+    assert_staircase_mixer_is_the_whole(
+        lambda h, mask, layer, stairs: seq_block.latent_attention(
+            h, mask, layer,
+            blocks=seq_block.core_blocks(stairs, None, h.shape[1]), **kw),
+        h, layer, STAIR_CASES[case])
+
+
+def test_under_a_mesh_of_two_the_core_stays_whole(monkeypatch):
+    """The staircase is one device's rows: dealt to two devices the
+    embedding gather takes it (each device its own rows' rectangles)
+    and the mixers' core stays today's, so the encoder gives what it
+    gives with no staircase and no mesh, and its text holds no block's
+    scores."""
+    from code2vec_tpu.data import staircase
+    from code2vec_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(staircase, "_BLOCK_SLOTS", 1)
+
+    stairs = ((0, 8), (4, 6), (8, 3))
+    _dims, params, _ = program_weights()
+    mask = np.tile(staircase_mask(stairs, 8, 12), (2, 1))
+    r = np.random.default_rng(9)
+
+    def ids(v):
+        return jnp.asarray((r.integers(2, v + 2, mask.shape)
+                            * mask).astype(np.int32))
+
+    src, pth, dst = (ids(SIZES["tokens"]), ids(SIZES["paths"]),
+                     ids(SIZES["tokens"]))
+    mesh = make_mesh(0, 1, devices=jax.devices()[:2])
+
+    def run(mesh, stairs):
+        def loss(p):
+            code, _attn, _aux = get_encode_fn(DIMS)(
+                p, src, pth, dst, jnp.asarray(mask), mesh=mesh,
+                staircase=stairs)
+            return jnp.sum(code ** 2), code
+        grad = jax.jit(jax.grad(loss, has_aux=True))
+        return grad(params)
+
+    def text(mesh, stairs):
+        return jax.jit(lambda p: get_encode_fn(DIMS)(
+            p, src, pth, dst, jnp.asarray(mask), mesh=mesh,
+            staircase=stairs)[0]).lower(params).as_text()
+
+    assert "x4x12x12xf32>" in text(mesh, stairs)
+    assert "tensor<6x4x4x8xf32>" not in text(mesh, stairs)
+    assert "tensor<6x4x4x8xf32>" in text(None, stairs)
+    grads, code = run(mesh, stairs)
+    want_grads, want_code = run(None, None)
+    np.testing.assert_allclose(np.asarray(code), np.asarray(want_code),
+                               rtol=1e-4, atol=5e-5)
+    got, want = flat(grads["joyai"]), flat(want_grads["joyai"])
+    for name in want:
+        norm = float(jnp.linalg.norm(want[name]))
+        if "expert_bias" in name:
+            continue            # selects only: no gradient reaches it
+        assert norm > 0, name
+        gap = float(jnp.linalg.norm(got[name] - want[name])) / norm
+        assert gap < 1e-3, (name, gap)
 
 
 # ---- the router ----------------------------------------------------------

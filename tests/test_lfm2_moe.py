@@ -22,8 +22,11 @@ from code2vec_tpu.models.encoder import (ModelDims, get_encode_fn,
                                          init_params)
 from code2vec_tpu.models.lfm2_moe_encoder import Lfm2Dims
 from code2vec_tpu.models import lfm2_moe_encoder as lfm
+from code2vec_tpu.models import seq_block
 from code2vec_tpu.ops import moe
-from tests.helpers import build_tiny_dataset, float_scatters
+from tests.helpers import (STAIR_CASES, assert_staircase_mixer_is_the_whole,
+                           build_tiny_dataset, float_scatters,
+                           lowered_texts, staircase_mask)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
@@ -242,6 +245,140 @@ def _router_case(n=64, H=16, E=16):
     return (jax.random.normal(k[0], (n, H)),
             0.5 * jax.random.normal(k[1], (H, E)),
             0.3 * jax.random.normal(k[2], (E,)))
+
+
+# ---- attention's core over the training staircase (ISSUE 35) -------------
+
+def attention_before_the_blocks(h, mask, layer, *, heads, kv_heads, head_dim,
+                                theta, norm, turned=None, gated=False):
+    """`seq_block.attention` as it stood before ISSUE 35 (its comments
+    left out): what a caller that passes no staircase still lowers
+    to."""
+    import math
+
+    dtype = h.dtype
+    B, C, _ = h.shape
+    n, n_kv, hd = heads, kv_heads, head_dim
+
+    def split(t, count, scale=None):
+        t = t.reshape(B, C, count, -1)
+        gate = None
+        if t.shape[-1] != hd:
+            t, gate = t[..., :hd], t[..., hd:]
+        if scale is not None:
+            t = norm(t, scale)
+        return t.transpose(0, 2, 1, 3), gate
+
+    q, gate = split(h @ layer["q"].astype(dtype), n, layer["q_norm"])
+    q = seq_block.rotary(q, theta, turned)
+    k = seq_block.rotary(split(h @ layer["k"].astype(dtype), n_kv,
+                               layer["k_norm"])[0], theta, turned)
+    v, _ = split(h @ layer["v"].astype(dtype), n_kv)
+    q = q.reshape(B, n_kv, n // n_kv, C, hd)
+    logits = jnp.einsum("bkgqd,bkcd->bkgqc", q, k).astype(jnp.float32) \
+        / math.sqrt(hd)
+    slot = jnp.arange(logits.shape[-1])
+    seen = (slot[None, :] <= slot[:, None])[None] & (mask > 0)[:, None, :]
+    logits = jnp.where(seen[:, None, None], logits, -1e30)
+    att = jax.nn.softmax(logits, axis=-1).astype(dtype)
+    out = jnp.einsum("bkgqc,bkcd->bkgqd", att, v)
+    out = out.reshape(B, n, C, hd).transpose(0, 2, 1, 3)
+    if gated:
+        out = out * jax.nn.sigmoid(gate)
+    return out.reshape(B, C, n * hd) @ layer["o"].astype(dtype)
+
+
+def attention_case(gated: bool, H=32, n=4, n_kv=2, hd=8, turned=None):
+    """(layer, h [8, 20, H], the mixer's keywords): four query heads on
+    two key heads."""
+    k = jax.random.split(jax.random.PRNGKey(21), 5)
+    w = lambda key, shape: 0.3 * jax.random.normal(key, shape)  # noqa: E731
+    layer = {"q": w(k[0], (H, n * hd * (2 if gated else 1))),
+             "k": w(k[1], (H, n_kv * hd)), "v": w(k[2], (H, n_kv * hd)),
+             "o": w(k[3], (n * hd, H)),
+             "q_norm": 1.0 + 0.1 * jnp.arange(hd, dtype=jnp.float32),
+             "k_norm": jnp.ones((hd,))}
+    kw = dict(heads=n, kv_heads=n_kv, head_dim=hd, theta=1e4, turned=turned,
+              gated=gated, norm=lambda t, s: (
+                  t * jax.lax.rsqrt(jnp.mean(t * t, -1, keepdims=True)
+                                    + 1e-6) * s).astype(t.dtype))
+    return layer, jax.random.normal(k[4], (8, 20, H)), kw
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_with_no_staircase_attention_lowers_to_the_old_program(dtype):
+    """Evaluation, prediction, serving and every batch that does not
+    fit its staircase pass none: forward and backward, what they lower
+    to does not know the argument exists."""
+    layer, h, kw = attention_case(gated=False)
+    h = h.astype(dtype)
+    mask = jnp.asarray(staircase_mask(STAIR_CASES["uneven"]))
+
+    def new(h, layer):
+        return seq_block.attention(h, mask, layer, **kw)
+
+    def old(h, layer):
+        return attention_before_the_blocks(h, mask, layer, **kw)
+
+    assert lowered_texts(new, h, layer) == lowered_texts(old, h, layer)
+    by_block = lowered_texts(
+        lambda h, layer: seq_block.attention(
+            h, mask, layer, blocks=seq_block.core_blocks(
+                STAIR_CASES["uneven"], None, 20), **kw), h, layer)
+    assert by_block[0] != lowered_texts(new, h, layer)[0]
+
+
+@pytest.mark.parametrize("block_slots", [1, 8])
+@pytest.mark.parametrize("case", list(STAIR_CASES))
+def test_attention_over_a_staircase_is_the_whole_core(case, block_slots,
+                                                      monkeypatch):
+    """Grouped heads, no gate: each rectangle a query block, and
+    neighbours joined to blocks of 8 slots."""
+    from code2vec_tpu.data import staircase
+
+    monkeypatch.setattr(staircase, "_BLOCK_SLOTS", block_slots)
+    layer, h, kw = attention_case(gated=False)
+    assert_staircase_mixer_is_the_whole(
+        lambda h, mask, layer, stairs: seq_block.attention(
+            h, mask, layer,
+            blocks=seq_block.core_blocks(stairs, None, h.shape[1]), **kw),
+        h, layer, STAIR_CASES[case])
+
+
+def test_the_encoder_hands_its_staircase_to_its_attention_layers(
+        monkeypatch):
+    """With a staircase the encoder's text holds the blocks' scores (a
+    [rows, 2, 2, slots, keys] product a block, each rectangle one) and
+    not the whole layer's; the code vector is the one it gives with
+    none."""
+    from code2vec_tpu.data import staircase
+
+    monkeypatch.setattr(staircase, "_BLOCK_SLOTS", 1)
+    stairs = ((0, 8), (4, 6), (8, 3))
+    _dims, params, _ = program_weights()
+    mask = staircase_mask(stairs, 8, 12)
+    r = np.random.default_rng(9)
+    src, pth, dst = (jnp.asarray((r.integers(2, v + 2, mask.shape)
+                                  * mask).astype(np.int32))
+                     for v in (SIZES["tokens"], SIZES["paths"],
+                               SIZES["tokens"]))
+
+    def encode(stairs):
+        return jax.jit(lambda p: get_encode_fn(DIMS)(
+            p, src, pth, dst, jnp.asarray(mask), staircase=stairs)[0])
+
+    text = encode(stairs).lower(params).as_text()
+    assert "tensor<8x2x2x12x12xf32>" not in text
+    for rows, queries, keys in ((8, 4, 4), (6, 4, 8), (3, 4, 12)):
+        assert f"tensor<{rows}x2x2x{queries}x{keys}xf32>" in text
+    assert "tensor<8x2x2x12x12xf32>" in encode(None).lower(params).as_text()
+    np.testing.assert_allclose(np.asarray(encode(stairs)(params)),
+                               np.asarray(encode(None)(params)),
+                               rtol=1e-4, atol=1e-5)
+    # `core_blocks` alone says whether the core runs by block
+    monkeypatch.setattr(seq_block, "core_blocks", lambda *a: None)
+    assert "tensor<8x2x2x12x12xf32>" in encode(stairs).lower(
+        params).as_text()
 
 
 def test_router_bias_changes_who_is_chosen_and_not_p():

@@ -27,7 +27,9 @@ from code2vec_tpu.models.encoder import (ModelDims, get_encode_fn,
                                          init_params)
 from code2vec_tpu.models.qwen3_next_encoder import Qwen3NextDims
 from code2vec_tpu.ops import moe
-from tests.helpers import build_tiny_dataset, float_scatters
+from tests.helpers import (STAIR_CASES, assert_staircase_mixer_is_the_whole,
+                           build_tiny_dataset, float_scatters,
+                           lowered_texts, staircase_mask)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
@@ -667,6 +669,64 @@ def test_the_staircase_step_scans_under_its_bound_and_is_the_full_step(
             got = np.asarray(after[k]) - np.asarray(start[k])
             assert float(np.linalg.norm(got - change)) <= \
                 2e-4 * max(norms[k], median), k
+
+
+# ---- the gated attention's core under the staircase (ISSUE 35) -----------
+
+@pytest.mark.parametrize("block_slots", [1, 8])
+@pytest.mark.parametrize("case", list(STAIR_CASES))
+def test_gated_attention_over_a_staircase_is_the_whole_core(
+        case, block_slots, monkeypatch):
+    """Grouped heads with the output gate and a quarter of each head
+    turned: each rectangle a query block, and neighbours joined to
+    blocks of 8 slots."""
+    from code2vec_tpu.data import staircase
+    from tests.test_lfm2_moe import attention_case
+
+    monkeypatch.setattr(staircase, "_BLOCK_SLOTS", block_slots)
+    layer, h, kw = attention_case(gated=True, turned=2)
+    assert_staircase_mixer_is_the_whole(
+        lambda h, mask, layer, stairs: seq_block.attention(
+            h, mask, layer,
+            blocks=seq_block.core_blocks(stairs, None, h.shape[1]), **kw),
+        h, layer, STAIR_CASES[case])
+
+
+def test_with_no_staircase_gated_attention_lowers_to_the_old_program():
+    from tests.test_lfm2_moe import (attention_before_the_blocks,
+                                     attention_case)
+
+    layer, h, kw = attention_case(gated=True, turned=2)
+    mask = jnp.asarray(staircase_mask(STAIR_CASES["uneven"]))
+    assert lowered_texts(
+        lambda h, layer: seq_block.attention(h, mask, layer, **kw),
+        h, layer) == lowered_texts(
+        lambda h, layer: attention_before_the_blocks(h, mask, layer, **kw),
+        h, layer)
+
+
+def test_the_encoder_hands_its_staircase_to_the_scan_and_the_attention(
+        monkeypatch):
+    """With a staircase the fourth layer's scores are its query
+    blocks' (a [rows, 2, 2, slots, keys] product a block, each
+    rectangle one), beside the bounded scans of the first three."""
+    from code2vec_tpu.data import staircase
+
+    monkeypatch.setattr(staircase, "_BLOCK_SLOTS", 1)
+    dims = dataclasses.replace(DIMS, max_contexts=SLOTS)
+    _dims, params, _ = program_weights()
+    batch = ordered_batch(FITS, 1)
+
+    def text(stairs):
+        return jax.jit(lambda p: get_encode_fn(dims)(
+            p, *(jnp.asarray(a) for a in batch[1:5]),
+            staircase=stairs)[0]).lower(params).as_text()
+
+    whole = f"tensor<16x2x2x{SLOTS}x{SLOTS}xf32>"
+    assert whole in text(None) and whole not in text(STAIRS)
+    for rows, queries, keys in ((16, 48, 48), (10, 48, 96), (6, 48, 144),
+                                (3, 16, 160)):
+        assert f"tensor<{rows}x2x2x{queries}x{keys}xf32>" in text(STAIRS)
 
 
 # ---- configuration -------------------------------------------------------

@@ -12,7 +12,12 @@
    says the batch fits;
 5. `rows_kept` (ISSUE 33): the rows a column's rectangle keeps bound,
    in every batch that fits, the rows with a valid slot from that
-   column on.
+   column on;
+6. `query_blocks` and `attn_pairs` (ISSUE 35): the query blocks the
+   softmax mixers' core runs over hold every causal pair of valid slots
+   of a batch that fits, the pairs are a brute-force count, and the
+   producer leaves them on the batches of an encoder that scores by
+   block (over a mesh the core stays whole, and the count says so).
 """
 
 import json
@@ -582,3 +587,154 @@ def test_a_batch_that_fits_has_no_valid_slot_under_a_chunks_bound(seed):
             ids[1][bound[n], n * chunk] = 7
             assert not st.fits(stairs, ids)
     assert fitted >= 20
+
+
+# ---- 6. the query blocks of the softmax mixers' core ------------------------
+
+CELL_STAIRS = ((0, 128), (32, 120), (64, 88), (96, 64), (128, 48), (160, 40))
+
+
+@pytest.mark.parametrize("stairs,slots,width,blocks", [
+    (CELL_STAIRS, 200, 1, ((0, 32, 128), (32, 64, 120), (64, 96, 88),
+                           (96, 128, 64), (128, 160, 48), (160, 200, 40))),
+    (CELL_STAIRS, 200, 64, ((0, 64, 128), (64, 128, 88), (128, 200, 48))),
+    (CELL_STAIRS, 200, 96, ((0, 96, 128), (96, 200, 64))),
+    (CELL_STAIRS, 200, 200, ((0, 200, 128),)),
+    (((0, 8),), 20, 1, ((0, 20, 8),)),
+    (((0, 8),), 20, 64, ((0, 20, 8),)),
+    (((0, 8), (8, 5), (16, 2)), 20, 1, ((0, 8, 8), (8, 16, 5), (16, 20, 2))),
+    (((0, 8), (8, 5), (16, 2)), 20, 8, ((0, 8, 8), (8, 20, 5))),
+], ids=["cell_each", "cell_64", "cell_96", "cell_whole", "one", "one_64",
+        "narrow_last_each", "narrow_last_joins"])
+def test_query_blocks_are_rectangles_or_neighbours_under_the_firsts_rows(
+        stairs, slots, width, blocks, monkeypatch):
+    monkeypatch.setattr(st, "_BLOCK_SLOTS", width)
+    assert st.query_blocks(stairs, slots) == blocks
+    # side by side from slot 0 to the last, rows never rising
+    assert blocks[0][0] == 0 and blocks[-1][1] == slots
+    assert all(a[1] == b[0] and a[2] >= b[2]
+               for a, b in zip(blocks, blocks[1:]))
+
+
+def _scored(stairs, rows, slots) -> np.ndarray:
+    """[rows, queries, keys] bool: the pairs the blocks score, marked
+    one by one."""
+    scored = np.zeros((rows, slots, slots), bool)
+    for first, end, kept in st.query_blocks(stairs, slots):
+        for b in range(kept):
+            for q in range(first, end):
+                for c in range(end):
+                    assert not scored[b, q, c]
+                    scored[b, q, c] = True
+    return scored
+
+
+@pytest.mark.parametrize("width", [1, 5, 9])
+def test_attn_pairs_is_a_brute_force_count(width, monkeypatch):
+    monkeypatch.setattr(st, "_BLOCK_SLOTS", width)
+    for stairs, rows, slots in ((STAIRS, B, C), (((0, 8),), 8, 20),
+                                (((0, 8), (4, 7), (8, 5), (12, 3), (16, 2)),
+                                 8, 20)):
+        assert st.attn_pairs(st.query_blocks(stairs, slots)) == int(
+            _scored(stairs, rows, slots).sum())
+    monkeypatch.setattr(st, "_BLOCK_SLOTS", 1)
+    assert st.attn_pairs(st.query_blocks(CELL_STAIRS, 200)) == 1_475_072
+    assert st.attn_pairs(st.query_blocks(((0, 128),), 200)) \
+        == 128 * 200 * 200
+
+
+@pytest.mark.parametrize("width", [1, 9])
+@pytest.mark.parametrize("name", FITTING)
+def test_the_blocks_hold_every_causal_pair_of_a_batch_that_fits(
+        name, width, monkeypatch):
+    """What a valid query may see (the valid slots up to its own) lies
+    inside its block's keys, in every batch the check lets through."""
+    monkeypatch.setattr(st, "_BLOCK_SLOTS", width)
+    ids, stairs, _ = case(name)
+    assert st.fits(stairs, ids)
+    valid = np.asarray(ids[1]) != 0
+    slot = np.arange(C)
+    causal = (slot[None, :] <= slot[:, None])[None] \
+        & valid[:, :, None] & valid[:, None, :]
+    assert not (causal & ~_scored(stairs, len(valid), C)).any()
+
+
+@pytest.fixture(scope="module")
+def block_model_config(tmp_path_factory):
+    from tests.test_lfm2_moe import BLOCK
+    path = tmp_path_factory.mktemp("block") / "block.json"
+    path.write_text(json.dumps(BLOCK))
+    return str(path)
+
+
+@pytest.mark.parametrize("data_axis", [1, 2])
+def test_model_counts_the_pairs_its_softmax_layers_score(
+        dataset, block_model_config, data_axis, monkeypatch):
+    """An encoder whose mixers score by query block (its spec says so):
+    every training batch carries the pairs a head of one softmax layer
+    of its step scores, the blocks' where it fits on one device and
+    rows x contexts^2 where it does not or a mesh keeps the core whole,
+    and the span holds them; the steps' losses are those of a twin
+    that runs today's step. The bag's batches carry none."""
+    from code2vec_tpu.obs import memory_tracer
+
+    monkeypatch.setattr(st, "_BLOCK_SLOTS", 1)      # each rectangle a block
+    kw = dict(ENCODER_TYPE="lfm2_moe", BLOCK_CONFIG=block_model_config,
+              LR_SCHEDULE="constant")
+    model = model_of(dataset, data_axis, **kw)
+    stairs = model._staircase
+    assert stairs is not None and model._stair_groups == data_axis
+    bag = model_of(dataset, 1)
+    monkeypatch.setattr(Code2VecModel, "_training_staircase",
+                        lambda self: (1, None))
+    twin = model_of(dataset, data_axis, **kw)
+    reader = open_reader(model.config.data_path("train"), model.vocabs, C,
+                         128, shuffle=True, seed=3)
+    before = len(memory_tracer().records("infeed/transfer"))
+    pairs = []
+    for i, (dev, host) in enumerate(model._train_infeed(reader)):
+        whole = host.num_valid_examples == 128
+        assert dev.fits == whole
+        assert dev.attn_pairs == (
+            st.attn_pairs(st.query_blocks(stairs, C))
+            if whole and data_axis == 1
+            else host.num_valid_examples * C * C)
+        pairs.append(dev.attn_pairs)
+        if i in (0, 1, 8):      # two that fit and the short one
+            key = jax.random.fold_in(model.rng, i)
+            model.params, model.opt_state, loss = model._train_step(
+                model.params, model.opt_state, dev, key)
+            twin.params, twin.opt_state, want = twin._train_step(
+                twin.params, twin.opt_state, tuple(dev), key)
+            np.testing.assert_allclose(float(loss), float(want), rtol=1e-3)
+    assert len(pairs) == 9
+    assert (pairs[0] < 128 * C * C) == (data_axis == 1)
+    spans = memory_tracer().records("infeed/transfer")[before:]
+    assert [s["attrs"]["attn_pairs"] for s in spans] == pairs
+    dev, _host = next(iter(bag._train_infeed(open_reader(
+        bag.config.data_path("train"), bag.vocabs, C, 128, shuffle=True,
+        seed=3))))
+    assert dev.fits and not hasattr(dev, "attn_pairs")
+    assert "attn_pairs" not in memory_tracer().records(
+        "infeed/transfer")[-1]["attrs"]
+    # the count asks the function the encoder compiles its step by
+    from code2vec_tpu.models import seq_block
+    monkeypatch.setattr(seq_block, "core_blocks", lambda *a: None)
+    dev, host = next(iter(model._train_infeed(open_reader(
+        model.config.data_path("train"), model.vocabs, C, 128, shuffle=True,
+        seed=3))))
+    assert dev.fits and dev.attn_pairs == host.num_valid_examples * C * C
+
+
+def test_core_blocks_is_none_with_no_staircase_or_rows_on_two_devices():
+    from code2vec_tpu.models.seq_block import core_blocks
+    from code2vec_tpu.parallel.mesh import make_mesh
+
+    assert core_blocks(None, None, 200) is None
+    assert core_blocks(CELL_STAIRS, None, 200) == st.query_blocks(
+        CELL_STAIRS, 200)
+    one = make_mesh(0, 1, devices=jax.devices()[:1])
+    assert core_blocks(CELL_STAIRS, one, 200) == st.query_blocks(
+        CELL_STAIRS, 200)
+    two = make_mesh(0, 1, devices=jax.devices()[:2])
+    assert core_blocks(CELL_STAIRS, two, 200) is None

@@ -346,6 +346,31 @@ def test_trace_report_prints_the_gathered_slots_beside_them(
         for s in produced])])
 
 
+def test_trace_report_prints_the_scored_pairs_beside_them(
+        traced_train_run):
+    """The bag's batches carry no `attn_pairs` and the report has no
+    such line; where every counted batch carries one (an encoder whose
+    softmax mixers score by query block) it prints their sum over rows
+    x contexts squared."""
+    from tools.trace_report import pad_slot_summary, render
+    assert "attn_pairs" not in pad_slot_summary(traced_train_run)
+    manifest = {"config": {"MAX_CONTEXTS": 16}}
+    assert "Scored pairs" not in render([(manifest, traced_train_run)])
+    produced = [s for s in traced_train_run
+                if s["name"] == "infeed/produce"]
+    counted = [dict(s, attrs=dict(s["attrs"],
+                                  attn_pairs=s["attrs"]["rows"] * 100))
+               for s in produced]
+    pad = pad_slot_summary(counted)
+    assert pad["attn_pairs"] == 100 * pad["rows"]
+    assert (f"Scored pairs a head and softmax layer: {pad['attn_pairs']:,} "
+            f"({100.0 * 100 / 256:.2f}% of rows x 16^2)") in render(
+        [(manifest, counted)])
+    # one batch without a count: no sum is printed for the others
+    assert "Scored pairs" not in render([(manifest,
+                                          counted[:-1] + produced[-1:])])
+
+
 def test_breakdown_primary_and_linked_requests_agree():
     """Regression: the flush's encode/device children share the
     PRIMARY request's trace id — they must be attributed through the
