@@ -14,6 +14,7 @@ import sys
 
 from code2vec_tpu import device
 from code2vec_tpu.config import Config
+from code2vec_tpu.obs import memory_tracer
 from code2vec_tpu.parallel.distributed import maybe_initialize
 from code2vec_tpu.vocab.vocabularies import VocabType
 
@@ -24,29 +25,36 @@ def main(argv=None) -> int:
     except ValueError as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
-    device.select_backend(config.BACKEND)
-    cache_dir = device.enable_compile_cache()
-    # Deterministic fault injection (ISSUE 10): arm the registry BEFORE
-    # anything builds — sites fetch their handles at setup time, and
-    # dist/init below is itself a site.
-    if config.FAULTS:
-        from code2vec_tpu.resilience import faults
+    # `setup/backend` (obs/setup_trace.py): the import of jax and the
+    # first touch of the device, select_backend to require_backend
+    with memory_tracer().start_span("setup/backend") as span:
+        device.select_backend(config.BACKEND)
+        cache_dir = device.enable_compile_cache()
+        # Deterministic fault injection (ISSUE 10): arm the registry
+        # BEFORE anything builds — sites fetch their handles at setup
+        # time, and dist/init below is itself a site.
+        if config.FAULTS:
+            from code2vec_tpu.resilience import faults
+            try:
+                faults.install(config.FAULTS, log=config.log)
+            except ValueError as e:
+                print(f"error: --faults: {e}", file=sys.stderr)
+                return 2
+        # Multi-host jobs must initialize the distributed runtime
+        # before the first backend touch; single-host runs detect
+        # nothing and continue.
+        maybe_initialize(config.DIST_COORDINATOR,
+                         config.DIST_NUM_PROCESSES,
+                         config.DIST_PROCESS_ID, log=config.log)
+        # --backend is a demand, not a hint: a run that asked for a TPU
+        # and found none stops here, before any model is built.
         try:
-            faults.install(config.FAULTS, log=config.log)
-        except ValueError as e:
-            print(f"error: --faults: {e}", file=sys.stderr)
+            devices = device.require_backend(config.BACKEND)
+        except device.BackendUnavailable as e:
+            print(f"error: {e}", file=sys.stderr)
             return 2
-    # Multi-host jobs must initialize the distributed runtime before the
-    # first backend touch; single-host runs detect nothing and continue.
-    maybe_initialize(config.DIST_COORDINATOR, config.DIST_NUM_PROCESSES,
-                     config.DIST_PROCESS_ID, log=config.log)
-    # --backend is a demand, not a hint: a run that asked for a TPU and
-    # found none stops here, before any model is built.
-    try:
-        devices = device.require_backend(config.BACKEND)
-    except device.BackendUnavailable as e:
-        print(f"error: {e}", file=sys.stderr)
-        return 2
+        span.attrs.update(platform=devices[0].platform,
+                          devices=len(devices))
     # Preemption recovery: with --auto_resume, an existing checkpoint in
     # --save turns this run into a resume of itself — the SAME command
     # line continues after a pod restart instead of training from
@@ -99,13 +107,18 @@ def main(argv=None) -> int:
         print(f"error: {e}", file=sys.stderr)
         return 2
 
-    from code2vec_tpu.serving.interactive_predict import InteractivePredictor
-    if config.HEAD == "varmisuse":
-        from code2vec_tpu.models.vm_model import VarMisuseModel
-        model = VarMisuseModel(config)
-    else:
-        from code2vec_tpu.models.jax_model import Code2VecModel
-        model = Code2VecModel(config)
+    # `setup/imports`: the model's modules bring orbax, optax and the
+    # encoders along, which is seconds on a TPU host (PERF.md section 5)
+    with memory_tracer().start_span("setup/imports"):
+        from code2vec_tpu.serving.interactive_predict import \
+            InteractivePredictor
+        if config.HEAD == "varmisuse":
+            from code2vec_tpu.models.vm_model import \
+                VarMisuseModel as Model
+        else:
+            from code2vec_tpu.models.jax_model import \
+                Code2VecModel as Model
+    model = Model(config)
     config.log(f"model loaded: framework=jax platform="
                f"{devices[0].platform} device_kind="
                f"{devices[0].device_kind!r} devices={len(devices)} "

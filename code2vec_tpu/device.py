@@ -77,12 +77,18 @@ def enable_compile_cache() -> str:
     is set JAX already reads it and nothing is set in code; otherwise
     the cache is `<checkout>/.jax_cache` — derived from this file's
     location, because the directory is part of the cache key and one
-    that moves never hits."""
+    that moves never hits. Every entry point that compiles comes
+    through here first, so this is also where the program starts to
+    keep its record of what JAX compiles or reads from that cache
+    (obs/setup_trace.py: one listener a process, however often this
+    is called)."""
+    import jax
+
+    from code2vec_tpu.obs import setup_trace
+    setup_trace.install(jax.monitoring)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir
-    import jax
-
     cache_dir = os.path.join(_REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     return cache_dir

@@ -29,6 +29,7 @@ from code2vec_tpu.data.reader import (BatchTensors, BinaryShardReader,
 from code2vec_tpu.models.encoder import PAD_ID, ModelDims, init_params
 from code2vec_tpu.models.registry import spec as encoder_spec
 from code2vec_tpu.models.model_base import Code2VecModelBase, MetricAccumulator
+from code2vec_tpu.obs import memory_tracer, setup_trace
 from code2vec_tpu.parallel.distributed import fetch_global
 from code2vec_tpu.parallel.mesh import (CONTEXT_AXIS, DATA_AXIS, DCN_AXIS,
                                         MODEL_AXIS)
@@ -88,10 +89,28 @@ class PreparedRows:
             context_strings=[c for p in items for c in p.context_strings])
 
 
+def _leaves_and_bytes(tree) -> dict:
+    """How many arrays a pytree holds and their bytes, from their
+    shapes (no read of the device)."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    return {"leaves": len(leaves),
+            "bytes": int(sum(getattr(x, "nbytes", 0) for x in leaves))}
+
+
 class Code2VecModel(Code2VecModelBase):
     def __init__(self, config: Config):
-        super().__init__(config)
-        cfg = config
+        # set-up goes to the program's own record (obs/setup_trace.py):
+        # `setup/model` around all of it, the base class's vocabularies
+        # included, one child a phase. No span waits for the device:
+        # one that ends with device work in flight is the host's time.
+        with memory_tracer().start_span(
+                "setup/model", loading=config.is_loading) as span:
+            super().__init__(config)
+            self._build(config)
+            span.attrs["encoder"] = self.dims.encoder_type
+
+    def _build(self, cfg: Config) -> None:
+        span = memory_tracer().start_span
         self.log = cfg.log
         self.compute_dtype = jnp.bfloat16 if cfg.USE_BF16 else jnp.float32
         # The fused pool is a Mosaic kernel, so it exists on a TPU
@@ -106,7 +125,10 @@ class Code2VecModel(Code2VecModelBase):
         # ---- mesh (SURVEY.md §3.3): data axis for DP, model axis for
         # sharded vocab tables; single-device runs use no mesh. ----
         from code2vec_tpu.models.setup import build_mesh, build_optimizer
-        self.mesh = build_mesh(cfg)
+        with span("setup/mesh") as sp:
+            self.mesh = build_mesh(cfg)
+            sp.attrs["devices"] = (1 if self.mesh is None
+                                   else self.mesh.devices.size)
         model_axis = max(1, cfg.MESH_MODEL_AXIS)
         self.shard_contexts = max(1, cfg.MESH_CONTEXT_AXIS) > 1
 
@@ -114,9 +136,10 @@ class Code2VecModel(Code2VecModelBase):
             # Dims come from the checkpoint manifest, not the CLI: a model
             # trained with different max_contexts / pad multiple must
             # restore bit-exactly regardless of current flags.
-            self.dims = ckpt.load_dims(cfg.load_path)
+            with span("setup/restore"):
+                self.dims = ckpt.load_dims(cfg.load_path)
+                manifest = ckpt.load_manifest(cfg.load_path)
             cfg.MAX_CONTEXTS = self.dims.max_contexts
-            manifest = ckpt.load_manifest(cfg.load_path)
             cfg.USE_SAMPLED_SOFTMAX = manifest.get(
                 "use_sampled_softmax", cfg.USE_SAMPLED_SOFTMAX)
             cfg.NUM_SAMPLED_CLASSES = manifest.get(
@@ -193,95 +216,112 @@ class Code2VecModel(Code2VecModelBase):
             return n
 
         self._n_train_examples = n_train_examples
-        self.optimizer = build_optimizer(
-            cfg, n_train_examples,
-            manifest if cfg.is_loading else None)
-        self.rng = jax.random.PRNGKey(cfg.SEED)
+        with span("setup/optimizer"):
+            self.optimizer = build_optimizer(
+                cfg, n_train_examples,
+                manifest if cfg.is_loading else None)
 
         # ---- params: load (--load) or init ----
         self.step_num = 0
-        self.rng, init_rng = jax.random.split(self.rng)
-        params = init_params(init_rng, self.dims)
-        if cfg.SPARSE_EMBEDDING_UPDATES:
-            # Config.verify() enforces this for CLI runs; assert here so
-            # programmatic Config users get a clear error instead of an
-            # optax chain-state mismatch (adafactor became the default
-            # table optimizer in round 3, sparse_steps is adam-only).
-            assert cfg.EMBEDDING_OPTIMIZER == "adam", (
-                "SPARSE_EMBEDDING_UPDATES requires "
-                "EMBEDDING_OPTIMIZER='adam'")
-            assert cfg.LR_SCHEDULE == "constant", (
-                "SPARSE_EMBEDDING_UPDATES requires "
-                "LR_SCHEDULE='constant' (the row-update kernel applies "
-                "a fixed per-row learning rate)")
-            from code2vec_tpu.training.sparse_steps import (
-                init_sparse_opt_state)
-            opt_state = init_sparse_opt_state(params, self.optimizer,
-                                              cfg.USE_SAMPLED_SOFTMAX)
-        else:
-            opt_state = self.optimizer.init(self._opt_param_view(params))
-        if cfg.is_loading:
-            if manifest.get("released"):
-                loaded = ckpt.load_checkpoint(cfg.load_path,
-                                              {"params": params})
-                params = loaded["params"]
-                # A released checkpoint carries no optimizer state; keep
-                # the freshly-initialized opt_state built above — it
-                # already matches the train step's expected structure
-                # (sparse dict vs optax Adam, per the manifest override).
-                self.step_num = int(manifest.get("step", 0))
+        with span("setup/init_params") as sp:
+            self.rng = jax.random.PRNGKey(cfg.SEED)
+            self.rng, init_rng = jax.random.split(self.rng)
+            params = init_params(init_rng, self.dims)
+            sp.attrs.update(_leaves_and_bytes(params))
+        with span("setup/opt_init") as sp:
+            if cfg.SPARSE_EMBEDDING_UPDATES:
+                # Config.verify() enforces this for CLI runs; assert
+                # here so programmatic Config users get a clear error
+                # instead of an optax chain-state mismatch (adafactor
+                # became the default table optimizer in round 3,
+                # sparse_steps is adam-only).
+                assert cfg.EMBEDDING_OPTIMIZER == "adam", (
+                    "SPARSE_EMBEDDING_UPDATES requires "
+                    "EMBEDDING_OPTIMIZER='adam'")
+                assert cfg.LR_SCHEDULE == "constant", (
+                    "SPARSE_EMBEDDING_UPDATES requires "
+                    "LR_SCHEDULE='constant' (the row-update kernel "
+                    "applies a fixed per-row learning rate)")
+                from code2vec_tpu.training.sparse_steps import (
+                    init_sparse_opt_state)
+                opt_state = init_sparse_opt_state(
+                    params, self.optimizer, cfg.USE_SAMPLED_SOFTMAX)
             else:
-                full = ckpt.load_checkpoint(
-                    cfg.load_path, {"params": params,
-                                    "opt_state": opt_state,
-                                    "step": 0})
-                params, opt_state = full["params"], full["opt_state"]
-                self.step_num = int(full.get("step", 0))
+                opt_state = self.optimizer.init(
+                    self._opt_param_view(params))
+            sp.attrs["bytes"] = _leaves_and_bytes(opt_state)["bytes"]
+        if cfg.is_loading:
+            with span("setup/restore"):
+                if manifest.get("released"):
+                    loaded = ckpt.load_checkpoint(cfg.load_path,
+                                                  {"params": params})
+                    params = loaded["params"]
+                    # A released checkpoint carries no optimizer state;
+                    # keep the freshly-initialized opt_state built
+                    # above — it already matches the train step's
+                    # expected structure (sparse dict vs optax Adam,
+                    # per the manifest override).
+                    self.step_num = int(manifest.get("step", 0))
+                else:
+                    full = ckpt.load_checkpoint(
+                        cfg.load_path, {"params": params,
+                                        "opt_state": opt_state,
+                                        "step": 0})
+                    params, opt_state = full["params"], full["opt_state"]
+                    self.step_num = int(full.get("step", 0))
         if self.mesh is not None:
-            params = shard_params(self.mesh, params)
-            opt_state = shard_opt_state(self.mesh, opt_state, params)
+            with span("setup/shard"):
+                params = shard_params(self.mesh, params)
+                opt_state = shard_opt_state(self.mesh, opt_state, params)
         self.params, self.opt_state = params, opt_state
 
-        # ---- jitted steps (make_train_step owns the sparse-vs-dense
-        # dispatch; Config.verify gates the combinations) ----
-        augment_fn = None
-        if cfg.ADV_RENAME_PROB > 0:
-            # adversarial-training defense (attacks/defense.py)
-            from code2vec_tpu.attacks.defense import (
-                legal_token_mask, make_rename_augment)
-            augment_fn = make_rename_augment(
-                legal_token_mask(self.vocabs.token_vocab, self.dims),
-                cfg.ADV_RENAME_PROB, mode=cfg.ADV_RENAME_MODE)
-        from code2vec_tpu.ops.quant import resolve_requant_mode
-        from code2vec_tpu.training.sparse_update import \
-            resolve_sparse_update_mode
-        self._stair_groups, self._staircase = self._training_staircase()
+        with span("setup/staircase") as sp:
+            self._stair_groups, self._staircase = \
+                self._training_staircase()
+            stairs = self._staircase or ()
+            sp.attrs.update(
+                rows=cfg.TRAIN_BATCH_SIZE // self._stair_groups
+                if stairs else 0, rectangles=len(stairs))
         self._full_step_logged = False
-        self._train_step = make_train_step(
-            self.dims, self.optimizer,
-            use_sampled_softmax=cfg.USE_SAMPLED_SOFTMAX,
-            num_sampled=cfg.NUM_SAMPLED_CLASSES,
-            compute_dtype=self.compute_dtype,
-            use_pallas=self.use_pallas, mesh=self.mesh,
-            augment_fn=augment_fn,
-            requant_fused=resolve_requant_mode(cfg.REQUANT_PALLAS),
-            sparse_updates=cfg.SPARSE_EMBEDDING_UPDATES,
-            learning_rate=cfg.LEARNING_RATE,
-            sparse_update_fused=resolve_sparse_update_mode(
-                cfg.SPARSE_UPDATE_PALLAS),
-            staircase=self._staircase)
         # background checkpoint writer (--async_checkpoint, default on):
         # created lazily at the first save so load/predict-only model
         # instances never start the thread
         self._ckpt_writer: Optional[ckpt.AsyncCheckpointWriter] = None
-        top_k = cfg.TOP_K_WORDS_CONSIDERED_DURING_PREDICTION
-        self._eval_step = make_eval_step(self.dims, top_k=top_k,
-                                         compute_dtype=self.compute_dtype,
-                                         use_pallas=self.use_pallas,
-                                         mesh=self.mesh)
-        self._predict_step = make_predict_step(
-            self.dims, top_k=top_k, compute_dtype=self.compute_dtype,
-            use_pallas=self.use_pallas, mesh=self.mesh)
+
+        # ---- jitted steps (make_train_step owns the sparse-vs-dense
+        # dispatch; Config.verify gates the combinations) ----
+        with span("setup/steps"):
+            augment_fn = None
+            if cfg.ADV_RENAME_PROB > 0:
+                # adversarial-training defense (attacks/defense.py)
+                from code2vec_tpu.attacks.defense import (
+                    legal_token_mask, make_rename_augment)
+                augment_fn = make_rename_augment(
+                    legal_token_mask(self.vocabs.token_vocab, self.dims),
+                    cfg.ADV_RENAME_PROB, mode=cfg.ADV_RENAME_MODE)
+            from code2vec_tpu.ops.quant import resolve_requant_mode
+            from code2vec_tpu.training.sparse_update import \
+                resolve_sparse_update_mode
+            self._train_step = make_train_step(
+                self.dims, self.optimizer,
+                use_sampled_softmax=cfg.USE_SAMPLED_SOFTMAX,
+                num_sampled=cfg.NUM_SAMPLED_CLASSES,
+                compute_dtype=self.compute_dtype,
+                use_pallas=self.use_pallas, mesh=self.mesh,
+                augment_fn=augment_fn,
+                requant_fused=resolve_requant_mode(cfg.REQUANT_PALLAS),
+                sparse_updates=cfg.SPARSE_EMBEDDING_UPDATES,
+                learning_rate=cfg.LEARNING_RATE,
+                sparse_update_fused=resolve_sparse_update_mode(
+                    cfg.SPARSE_UPDATE_PALLAS),
+                staircase=self._staircase)
+            top_k = cfg.TOP_K_WORDS_CONSIDERED_DURING_PREDICTION
+            self._eval_step = make_eval_step(
+                self.dims, top_k=top_k, compute_dtype=self.compute_dtype,
+                use_pallas=self.use_pallas, mesh=self.mesh)
+            self._predict_step = make_predict_step(
+                self.dims, top_k=top_k, compute_dtype=self.compute_dtype,
+                use_pallas=self.use_pallas, mesh=self.mesh)
 
     # ---- vocabs: dataset dict when training, checkpoint sidecar when
     # loading (SURVEY.md §3.2 "Model checkpoint") ----
@@ -612,6 +652,10 @@ class Code2VecModel(Code2VecModelBase):
                         kill_fp.fire(step=self.step_num + 1)
                     self.step_num += 1
                     steps_into_training += 1
+                    if steps_into_training == 1:
+                        # where set-up went, once: the step's compile
+                        # is in the record by now
+                        setup_trace.report(self.log, tracer)
                     window_examples += batch.num_valid_examples
                     loss_f = (recorder.end_step(self.step_num, loss,
                                                 batch.num_valid_examples,

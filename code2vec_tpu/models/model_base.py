@@ -89,10 +89,14 @@ class Code2VecModelBase(abc.ABC):
         # singleton keeps predict()'s span calls branch-free. Same deal
         # for the request-scoped tracer (--trace): train() and the
         # PredictionServer install a recording one.
-        from code2vec_tpu.obs import Telemetry, Tracer
+        from code2vec_tpu.obs import Telemetry, Tracer, memory_tracer
         self.telemetry = Telemetry.disabled()
         self.tracer = Tracer.disabled()
-        self.vocabs: Code2VecVocabs = self._load_or_create_vocabs()
+        with memory_tracer().start_span("setup/vocabs") as span:
+            self.vocabs: Code2VecVocabs = self._load_or_create_vocabs()
+            span.attrs.update(tokens=self.vocabs.token_vocab.size,
+                              paths=self.vocabs.path_vocab.size,
+                              targets=self.vocabs.target_vocab.size)
 
     # ---- lifecycle ----
     @abc.abstractmethod

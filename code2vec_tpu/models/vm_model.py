@@ -19,6 +19,7 @@ from code2vec_tpu.config import Config
 from code2vec_tpu.data.vm_reader import (VMTextReader, build_vm_vocabs)
 from code2vec_tpu.models.encoder import ModelDims
 from code2vec_tpu.models.varmisuse import init_vm_params
+from code2vec_tpu.obs import memory_tracer, setup_trace
 from code2vec_tpu.parallel.distributed import fetch_global
 from code2vec_tpu.parallel.mesh import DATA_AXIS, DCN_AXIS
 from code2vec_tpu.parallel.sharding import (shard_batch, shard_opt_state,
@@ -41,7 +42,16 @@ class VMEvalResults(NamedTuple):
 
 class VarMisuseModel:
     def __init__(self, config: Config):
-        cfg = self.config = config
+        # the same set-up spans as Code2VecModel, where the same calls
+        # run (obs/setup_trace.py); none waits for the device
+        with memory_tracer().start_span(
+                "setup/model", loading=config.is_loading,
+                encoder="varmisuse"):
+            self._build(config)
+
+    def _build(self, cfg: Config) -> None:
+        span = memory_tracer().start_span
+        self.config = cfg
         self.log = cfg.log
         from code2vec_tpu.obs import Telemetry, Tracer
         self.telemetry = Telemetry.disabled()  # train() swaps it in
@@ -49,7 +59,10 @@ class VarMisuseModel:
         self.compute_dtype = jnp.bfloat16 if cfg.USE_BF16 else jnp.float32
         from code2vec_tpu.models.setup import build_mesh, build_optimizer
         # no context axis: the vm head is bag-encoder-only (Config.verify)
-        self.mesh = build_mesh(cfg, with_context_axis=False)
+        with span("setup/mesh") as sp:
+            self.mesh = build_mesh(cfg, with_context_axis=False)
+            sp.attrs["devices"] = (1 if self.mesh is None
+                                   else self.mesh.devices.size)
         # The fused pool is a Mosaic kernel, so it exists on a TPU only
         # (code2vec.py has already held the run to --backend: the
         # platform read here is the one the user named). Under a mesh
@@ -66,9 +79,10 @@ class VarMisuseModel:
         model_axis = max(1, cfg.MESH_MODEL_AXIS)
 
         if cfg.is_loading:
-            self.dims = ckpt.load_dims(cfg.load_path)
+            with span("setup/restore"):
+                self.dims = ckpt.load_dims(cfg.load_path)
+                manifest = ckpt.load_manifest(cfg.load_path)
             cfg.MAX_CONTEXTS = self.dims.max_contexts
-            manifest = ckpt.load_manifest(cfg.load_path)
             cfg.MAX_CANDIDATES = manifest.get("max_candidates",
                                               cfg.MAX_CANDIDATES)
             cfg.TABLES_DTYPE = self.dims.tables_dtype
@@ -87,12 +101,14 @@ class VarMisuseModel:
                 cfg.LR_SCHEDULE, manifest, cfg.log)
             cfg.LR_WARMUP_STEPS = resolve_checkpoint_warmup(
                 cfg.LR_SCHEDULE, cfg.LR_WARMUP_STEPS, manifest, cfg.log)
-            self.vocabs = ckpt.load_vocabs(cfg.load_path)
+            with span("setup/vocabs"):
+                self.vocabs = ckpt.load_vocabs(cfg.load_path)
         else:
             assert cfg.train_data_path, "varmisuse needs --data or --load"
-            self.vocabs = build_vm_vocabs(self._vm_path("train"),
-                                          cfg.MAX_TOKEN_VOCAB_SIZE,
-                                          cfg.MAX_PATH_VOCAB_SIZE)
+            with span("setup/vocabs"):
+                self.vocabs = build_vm_vocabs(self._vm_path("train"),
+                                              cfg.MAX_TOKEN_VOCAB_SIZE,
+                                              cfg.MAX_PATH_VOCAB_SIZE)
             self.dims = ModelDims(
                 token_vocab_size=self.vocabs.token_vocab.size,
                 path_vocab_size=self.vocabs.path_vocab.size,
@@ -108,36 +124,42 @@ class VarMisuseModel:
             return count_examples(self._vm_path("train"))
 
         self._n_train_examples = n_train_examples
-        self.optimizer = build_optimizer(
-            cfg, n_train_examples,
-            manifest if cfg.is_loading else None)
-        self.rng = jax.random.PRNGKey(cfg.SEED)
-        self.rng, init_rng = jax.random.split(self.rng)
-        params = init_vm_params(init_rng, self.dims)
-        if cfg.SPARSE_EMBEDDING_UPDATES:
-            # verify() enforces these for CLI runs; assert for
-            # programmatic Config users (same contract as jax_model)
-            assert cfg.EMBEDDING_OPTIMIZER == "adam", (
-                "SPARSE_EMBEDDING_UPDATES requires "
-                "EMBEDDING_OPTIMIZER='adam'")
-            assert cfg.LR_SCHEDULE == "constant", (
-                "SPARSE_EMBEDDING_UPDATES requires "
-                "LR_SCHEDULE='constant'")
-            from code2vec_tpu.training.vm_steps import \
-                init_vm_sparse_opt_state
-            opt_state = init_vm_sparse_opt_state(params, self.optimizer)
-        else:
-            opt_state = self.optimizer.init(params)
+        with span("setup/optimizer"):
+            self.optimizer = build_optimizer(
+                cfg, n_train_examples,
+                manifest if cfg.is_loading else None)
+        with span("setup/init_params"):
+            self.rng = jax.random.PRNGKey(cfg.SEED)
+            self.rng, init_rng = jax.random.split(self.rng)
+            params = init_vm_params(init_rng, self.dims)
+        with span("setup/opt_init"):
+            if cfg.SPARSE_EMBEDDING_UPDATES:
+                # verify() enforces these for CLI runs; assert for
+                # programmatic Config users (same contract as jax_model)
+                assert cfg.EMBEDDING_OPTIMIZER == "adam", (
+                    "SPARSE_EMBEDDING_UPDATES requires "
+                    "EMBEDDING_OPTIMIZER='adam'")
+                assert cfg.LR_SCHEDULE == "constant", (
+                    "SPARSE_EMBEDDING_UPDATES requires "
+                    "LR_SCHEDULE='constant'")
+                from code2vec_tpu.training.vm_steps import \
+                    init_vm_sparse_opt_state
+                opt_state = init_vm_sparse_opt_state(params,
+                                                     self.optimizer)
+            else:
+                opt_state = self.optimizer.init(params)
         self.step_num = 0
         if cfg.is_loading:
-            full = ckpt.load_checkpoint(
-                cfg.load_path,
-                {"params": params, "opt_state": opt_state, "step": 0})
-            params, opt_state = full["params"], full["opt_state"]
-            self.step_num = int(full.get("step", 0))
+            with span("setup/restore"):
+                full = ckpt.load_checkpoint(
+                    cfg.load_path,
+                    {"params": params, "opt_state": opt_state, "step": 0})
+                params, opt_state = full["params"], full["opt_state"]
+                self.step_num = int(full.get("step", 0))
         if self.mesh is not None:
-            params = shard_params(self.mesh, params)
-            opt_state = shard_opt_state(self.mesh, opt_state, params)
+            with span("setup/shard"):
+                params = shard_params(self.mesh, params)
+                opt_state = shard_opt_state(self.mesh, opt_state, params)
         self.params, self.opt_state = params, opt_state
 
         # background checkpoint writer (--async_checkpoint, default on);
@@ -145,17 +167,19 @@ class VarMisuseModel:
         self._ckpt_writer = None
         from code2vec_tpu.training.sparse_update import \
             resolve_sparse_update_mode
-        self._train_step = make_vm_train_step(
-            self.dims, self.optimizer, compute_dtype=self.compute_dtype,
-            use_pallas=self.use_pallas,
-            sparse_updates=cfg.SPARSE_EMBEDDING_UPDATES,
-            learning_rate=cfg.LEARNING_RATE,
-            sparse_update_fused=resolve_sparse_update_mode(
-                cfg.SPARSE_UPDATE_PALLAS),
-            mesh=self.mesh)
-        self._eval_step = make_vm_eval_step(
-            self.dims, compute_dtype=self.compute_dtype,
-            use_pallas=self.use_pallas)
+        with span("setup/steps"):
+            self._train_step = make_vm_train_step(
+                self.dims, self.optimizer,
+                compute_dtype=self.compute_dtype,
+                use_pallas=self.use_pallas,
+                sparse_updates=cfg.SPARSE_EMBEDDING_UPDATES,
+                learning_rate=cfg.LEARNING_RATE,
+                sparse_update_fused=resolve_sparse_update_mode(
+                    cfg.SPARSE_UPDATE_PALLAS),
+                mesh=self.mesh)
+            self._eval_step = make_vm_eval_step(
+                self.dims, compute_dtype=self.compute_dtype,
+                use_pallas=self.use_pallas)
 
     def _vm_path(self, split: str) -> str:
         p = self.config.train_data_path
@@ -298,6 +322,9 @@ class VarMisuseModel:
                         kill_fp.fire(step=self.step_num + 1)
                     self.step_num += 1
                     steps_into_training += 1
+                    if steps_into_training == 1:
+                        # where set-up went, once (as jax_model)
+                        setup_trace.report(self.log, tracer)
                     window += batch.num_valid_examples
                     loss_f = (recorder.end_step(self.step_num, loss,
                                                 batch.num_valid_examples,

@@ -19,6 +19,7 @@ from code2vec_tpu.obs.fleet import (FleetCollector,  # noqa: F401
 from code2vec_tpu.obs.health import HealthEngine  # noqa: F401
 from code2vec_tpu.obs.loop import (TrainStepRecorder,  # noqa: F401
                                    infeed_produce_instrument)
+from code2vec_tpu.obs import setup_trace  # noqa: F401
 from code2vec_tpu.obs.sinks import (JsonlSink, ScalarSink,  # noqa: F401
                                     StdoutSink)
 from code2vec_tpu.obs.telemetry import (SUMMARY_PERCENTILES,  # noqa: F401

@@ -48,7 +48,10 @@ the same name, so whenever a profiler session runs the span stands in
 the `.xplane.pb` on the device trace's own clock; with no session the
 annotation is TraceMe's no-op. The input pipeline's producer thread
 records through it (data/prefetch.py: `infeed/read`, `infeed/transfer`,
-`infeed/blocked`, and the consumer's `infeed/pop_wait`).
+`infeed/blocked`, and the consumer's `infeed/pop_wait`), and so does
+set-up (ISSUE 36, obs/setup_trace.py: the `setup/*` spans around the
+model's construction and one `compile/*` record for everything JAX
+traced, lowered, compiled or read from its cache).
 
 Disabled path (the PR 2 discipline): `Tracer.disabled()` is a shared
 singleton whose `enabled` is False and whose methods return the one
@@ -324,6 +327,11 @@ class Tracer:
         self._emit(name, trace_id, span_id, parent_id, links, tid,
                    tname, t_start, t_end, attrs)
         return SpanContext(trace_id, span_id)
+
+    def current_span(self) -> Optional[TraceSpan]:
+        """The innermost span this thread holds open as a context
+        manager, or None."""
+        return getattr(self._tls, "current", None)
 
     # ---- record plumbing ----
     def _finish(self, span: TraceSpan, t1: float) -> None:

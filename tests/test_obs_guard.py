@@ -242,6 +242,57 @@ def test_fleet_collector_runs_without_jax_or_tf(tmp_path):
     assert "FLEET-GUARD-OK" in r.stdout
 
 
+def test_setup_trace_runs_without_jax_or_tf(tmp_path):
+    """ISSUE 36: the set-up record is part of obs/: its listener takes
+    `jax.monitoring` from the caller, so the module imports, records
+    and summarises with jax and tensorflow import-blocked (the
+    monitoring module here is a stand-in that replays JAX's events)."""
+    code = textwrap.dedent("""
+        import sys, types
+        import code2vec_tpu.obs as obs
+        from code2vec_tpu.obs import setup_trace
+
+        listeners = {}
+        fake = types.SimpleNamespace(
+            register_event_time_span_listener=
+                lambda f: listeners.setdefault("span", f),
+            register_event_listener=
+                lambda f: listeners.setdefault("event", f),
+            register_event_duration_secs_listener=
+                lambda f: listeners.setdefault("duration", f))
+        assert setup_trace.install(fake) is setup_trace.install(fake)
+        rec = obs.memory_tracer()
+        import time
+        with rec.start_span("setup/model", loading=False, encoder="bag"):
+            with rec.start_span("setup/init_params"):
+                now = time.time()
+                listeners["span"](
+                    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                    now - 0.002, now - 0.001, fun_name="jit(f)")
+                listeners["event"]("/jax/compilation_cache/cache_hits")
+                listeners["duration"](
+                    "/jax/compilation_cache/cache_retrieval_time_sec",
+                    0.0005)
+                listeners["span"](
+                    "/jax/core/compile/backend_compile_duration",
+                    now - 0.001, now, fun_name="jit(f)")
+        s = setup_trace.summarize(rec.records())
+        assert (s["programs"], s["from_cache"]) == (1, 1), s
+        assert [n for n, _, _ in s["phases"]] == ["init_params", "(self)"]
+        (backend,) = rec.records("compile/backend")
+        assert backend["attrs"]["under"] == "setup/init_params"
+        assert "set-up: model" in setup_trace.format_line(s)
+        assert "jax" not in sys.modules
+        print("SETUP-GUARD-OK")
+    """)
+    r = subprocess.run([sys.executable, "-c", code],
+                       env=_tf_blocked_env(tmp_path, block_jax=True),
+                       cwd=REPO, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "SETUP-GUARD-OK" in r.stdout
+
+
 def test_tier1_collection_is_tf_free(tmp_path):
     """`pytest --collect-only` over the tier-1 selection with TF
     blocked: any test module importing TensorFlow at module scope

@@ -39,6 +39,11 @@ emits) and produces:
     encoder (lfm2_moe, qwen3_next, joyai_flash) the `moe/route` records: rows routed to the experts held
     here over the valid tokens' choices, the fullest expert's rows
     over the mean, and the spans' `row_bound` and `compact_layers`.
+  - "Set-up" table: the phases of the model's construction
+    (`setup/backend`, `setup/imports`, `setup/model` and its children
+    by their own time) and what JAX compiled or read from its cache on
+    the way (`compile/*`: programs, cache hits, the longest by name),
+    from the records `train()` sends out after its first step.
 
 Pure stdlib; reads only manifest + events files, so it works on a
 laptop over a run dir scp'd from a pod (same contract as
@@ -384,6 +389,19 @@ def scan_summary(spans: Sequence[Dict[str, Any]]
             "live_chunks": sum(a["live_chunks"] for a in scans)}
 
 
+def setup_summary(spans: Sequence[Dict[str, Any]]
+                  ) -> Optional[Dict[str, Any]]:
+    """Where set-up went, from the run's `setup/*` and `compile/*`
+    spans (the in-memory record's, sent out once the first train step
+    is dispatched) by the function the operator's log line comes from
+    (`obs/setup_trace.summarize`). None when the run has none."""
+    from code2vec_tpu.obs.setup_trace import summarize
+    return summarize([
+        {"name": s["name"], "t0": s["t0"],
+         "t1": s["t0"] + s["dur_ms"] / 1e3, "attrs": s.get("attrs") or {}}
+        for s in spans if s["name"].startswith(("setup/", "compile/"))])
+
+
 def save_breakdowns(spans: Sequence[Dict[str, Any]]
                     ) -> List[Dict[str, Any]]:
     rows = []
@@ -426,6 +444,10 @@ def _fmt(v, nd: int = 2) -> str:
             return "—"
         return f"{v:,.{nd}f}"
     return str(v)
+
+
+def _attrs(attrs: Dict[str, Any]) -> str:
+    return " ".join(f"{k}={v}" for k, v in attrs.items())
 
 
 def render(loaded, limit: int = 10) -> str:
@@ -522,6 +544,26 @@ def render(loaded, limit: int = 10) -> str:
                 f"{scan['live_chunks']:,} of them with a valid slot "
                 f"({100.0 * scan['live_chunks'] / max(scan['chunks'], 1):.1f}"
                 "%)")
+        setup = setup_summary(spans)
+        if setup:
+            lines.append("")
+            lines.append("| Set-up | s | |")
+            lines.append("|---|---|---|")
+            for name, seconds, attrs in setup["outside"]:
+                lines.append(f"| setup/{name} | {_fmt(seconds)} | "
+                             f"{_attrs(attrs)} |")
+            lines.append(f"| setup/model | {_fmt(setup['model_s'])} | "
+                         f"{_attrs(setup['model_attrs'])} |")
+            for name, seconds, attrs in setup["phases"]:
+                lines.append(f"| - {name} | {_fmt(seconds)} | "
+                             f"{_attrs(attrs)} |")
+            lines.append(
+                f"| compile/* | {_fmt(setup['compile_s'])} | "
+                f"{setup['programs']} programs, {setup['from_cache']} "
+                f"from the cache, {setup['compiled']} compiled |")
+            for name, seconds, n in setup["longest"][:limit]:
+                lines.append(f"| - {name} | {_fmt(seconds)} | "
+                             f"{n} program{'s' if n != 1 else ''} |")
         save_rows = save_breakdowns(spans)
         if save_rows:
             lines.append("")
