@@ -50,7 +50,11 @@ profiler's trace on the device's clock):
                               softmax layer of the chosen step scores,
                               `staircase.attn_pairs` when the batch
                               fits and rows x max_contexts^2 when
-                              not)
+                              not; `ff_slots` with it: the positions
+                              every layer's feed-forward half of the
+                              chosen step runs over, the staircase's
+                              area when the batch fits and rows x
+                              max_contexts when not)
   infeed/blocked   producer   the bounded put into the queue: the
                               producer's slack (`seq`)
   infeed/pop_wait  consumer   `q.get()` (`seq` of the batch it popped;
@@ -82,21 +86,25 @@ _EPOCH_END = object()
 _BATCH_SEQ = itertools.count()
 
 
+# what a transferred batch may say of the step chosen for it
+TRANSFER_COUNTS = ("gather_slots", "attn_pairs", "ff_slots")
+
+
 class BatchRecord:
     """One produced batch: its sequence number, rows, PAD slots,
-    gathered slots, scored pairs and bytes, and on the recorder's clock
-    where its read started and its transfer ended. Rides the queue item the
-    producer builds; `on_produced` (the `--trace` hook) gets it after
-    the transfer."""
+    gathered slots, scored pairs, fed-forward slots and bytes, and on
+    the recorder's clock where its read started and its transfer ended.
+    Rides the queue item the producer builds; `on_produced` (the
+    `--trace` hook) gets it after the transfer."""
 
     __slots__ = ("seq", "rows", "pad_slots", "gather_slots", "attn_pairs",
-                 "bytes", "read_start", "transfer_end")
+                 "ff_slots", "bytes", "read_start", "transfer_end")
 
     def __init__(self, seq: int, rows, pad_slots, read_start: float):
         self.seq = seq
         self.rows = rows
         self.pad_slots = pad_slots
-        self.gather_slots = self.attn_pairs = None
+        self.gather_slots = self.attn_pairs = self.ff_slots = None
         self.bytes = 0
         self.read_start = read_start
         self.transfer_end = None
@@ -139,12 +147,12 @@ def _read_batches(batches: Iterable, recorder
 def _transfer(fn: Callable, b, record: BatchRecord, recorder,
               on_produced: Optional[Callable]):
     """`fn(b)` under `infeed/transfer`; the bytes are those of what it
-    returns, and the gathered slots and scored pairs what it says of
-    itself."""
+    returns, and the gathered slots, scored pairs and fed-forward
+    slots what it says of itself."""
     with recorder.start_span("infeed/transfer", seq=record.seq) as span:
         out = fn(b)
         record.bytes = span.attrs["bytes"] = _nbytes(out)
-        for count in ("gather_slots", "attn_pairs"):
+        for count in TRANSFER_COUNTS:
             value = getattr(out, count, None)
             setattr(record, count, value)
             if value is not None:

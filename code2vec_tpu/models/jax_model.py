@@ -406,8 +406,10 @@ class Code2VecModel(Code2VecModelBase):
         staircase is PAD (`staircase.fits`; an ordered batch of the
         shard the staircase was sized from does, nearly always), the
         slots the step it chooses takes table rows for and, where the
-        encoder's softmax mixers run over the staircase, the pairs a
-        head of one of them scores."""
+        encoder is a block that runs over the staircase
+        (`models/seq_block.py`), the pairs a head of one of its softmax
+        mixers scores and the positions every layer's feed-forward half
+        runs over."""
         stairs, groups = self._staircase, self._stair_groups
         whole = b.num_valid_examples == b.target_index.shape[0]
         fits = stairs is not None and whole and staircase.fits(
@@ -426,14 +428,20 @@ class Code2VecModel(Code2VecModelBase):
         if encoder_spec(self.dims.encoder_type).scores_by_staircase:
             # beside `gather_slots` on the batch's `infeed/transfer`
             # span (data/prefetch.py): what the step `_by_fit` chooses
-            # for this batch was compiled to score, by the function the
-            # encoder compiled it by; the host's word, not the device's
-            from code2vec_tpu.models.seq_block import core_blocks
-            blocks = core_blocks(stairs if fits else None, self.mesh,
-                                 contexts)
+            # for this batch was compiled to score and to feed forward,
+            # by the functions the encoder compiled it by; the host's
+            # word, not the device's
+            from code2vec_tpu.models.seq_block import (core_blocks,
+                                                       ff_rectangles)
+            mine = stairs if fits else None
+            blocks = core_blocks(mine, self.mesh, contexts)
             batch.attn_pairs = (
                 b.num_valid_examples * contexts ** 2 if blocks is None
                 else staircase.attn_pairs(blocks))
+            batch.ff_slots = (
+                b.num_valid_examples * contexts
+                if ff_rectangles(mine, self.mesh, contexts) is None
+                else staircase.area(stairs, contexts))
         return batch
 
     def _train_infeed(self, reader, instrument=None, heartbeat=None):

@@ -294,7 +294,8 @@ def encode_joyai_flash(params: Dict, source_ids: jax.Array,
     grouped product is XLA's own kernel on the TPU, the attention XLA's
     on every backend. `staircase` (training only; `embed_contexts` has
     who checks it): the mixers' core also runs by the query blocks
-    `seq_block.core_blocks` makes of it."""
+    `seq_block.core_blocks` makes of it, every layer's feed-forward half
+    over the positions of `seq_block.ff_rectangles`."""
     del use_pallas
     cfg, sub = dims.joyai, params["joyai"]
     norm = functools.partial(_rms_norm, eps=cfg.rms_norm_eps)
@@ -317,6 +318,7 @@ def encode_joyai_flash(params: Dict, source_ids: jax.Array,
                                        (True, True) + (False,) * 5)
 
     blocks = seq_block.core_blocks(staircase, mesh, mask.shape[1])
+    rectangles = seq_block.ff_rectangles(staircase, mesh, mask.shape[1])
 
     def mixer(h, layer):
         return seq_block.latent_attention(
@@ -325,11 +327,11 @@ def encode_joyai_flash(params: Dict, source_ids: jax.Array,
             v_dim=cfg.v_head_dim, theta=cfg.rope_theta, norm=norm,
             blocks=blocks)
 
-    def dense(h, layer):
+    def dense(h, mask, layer):
         return seq_block.swiglu(h, layer["w1"], layer["w3"],
                                 layer["w2"]), None
 
-    def routed(h, layer):
+    def routed(h, mask, layer):
         out, counts = experts(h, mask, layer["router"],
                               layer["expert_bias"], layer["w1"],
                               layer["w3"], layer["w2"])
@@ -342,7 +344,8 @@ def encode_joyai_flash(params: Dict, source_ids: jax.Array,
         moe = _is_moe(cfg, i)
         return seq_block.residual_layer(
             i, norm=norm, mixer_scope="mla", mixer=mixer,
-            ff=routed if moe else dense, ff_scope=None if moe else "mlp")
+            ff=routed if moe else dense, mask=mask,
+            ff_scope=None if moe else "mlp", rectangles=rectangles)
 
     return seq_block.run_block(sub, emb, mask, compute_dtype,
                                layer_fn=layer_fn, norm=norm,

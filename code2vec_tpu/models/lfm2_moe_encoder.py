@@ -256,7 +256,8 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
     grouped product is XLA's own kernel on the TPU, the attention XLA's
     on every backend. `staircase` (training only; `embed_contexts` has
     who checks it): the attention layers' core also runs by the query
-    blocks `seq_block.core_blocks` makes of it."""
+    blocks `seq_block.core_blocks` makes of it, every layer's
+    feed-forward half over the positions of `seq_block.ff_rectangles`."""
     del use_pallas
     cfg, lfm = dims.lfm, params["lfm"]
     norm = functools.partial(_rms_norm, eps=cfg.norm_eps)
@@ -278,6 +279,7 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
                                        (True, True) + (False,) * 5)
 
     blocks = seq_block.core_blocks(staircase, mesh, mask.shape[1])
+    rectangles = seq_block.ff_rectangles(staircase, mesh, mask.shape[1])
 
     def mixer(h, layer):
         if "conv_k" in layer:
@@ -287,11 +289,11 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
             kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
             theta=cfg.rope_theta, norm=norm, blocks=blocks)
 
-    def dense(h, layer):
+    def dense(h, mask, layer):
         return seq_block.swiglu(h, layer["w1"], layer["w3"],
                                 layer["w2"]), None
 
-    def routed(h, layer):
+    def routed(h, mask, layer):
         return experts(h, mask, layer["router"], layer["expert_bias"],
                        layer["w1"], layer["w3"], layer["w2"])
 
@@ -300,8 +302,8 @@ def encode_lfm2_moe(params: Dict, source_ids: jax.Array,
         moe = _is_moe(cfg, i)
         return seq_block.residual_layer(
             i, norm=norm, mixer_scope="conv" if conv else "attn",
-            mixer=mixer, ff=routed if moe else dense,
-            ff_scope=None if moe else "mlp")
+            mixer=mixer, ff=routed if moe else dense, mask=mask,
+            ff_scope=None if moe else "mlp", rectangles=rectangles)
 
     return seq_block.run_block(lfm, emb, mask, compute_dtype,
                                layer_fn=layer_fn, norm=norm,

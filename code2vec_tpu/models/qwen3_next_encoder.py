@@ -345,7 +345,9 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
     rectangle keeps, on each device its own, and "the chunks its scan
     ran over" is that bound's sum over the devices; with none it is
     every method's every chunk. The attention layers' core runs by the
-    query blocks `seq_block.core_blocks` makes of it."""
+    query blocks `seq_block.core_blocks` makes of it, every layer's
+    feed-forward half over the positions of
+    `seq_block.ff_rectangles`."""
     del use_pallas
     cfg, sub = dims.qwen, params["qwen"]
 
@@ -383,6 +385,7 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
                                    else devices * sum(bound)),
                          delta_rule.live_chunks(mask)])
     blocks = seq_block.core_blocks(staircase, mesh, C)
+    rectangles = seq_block.ff_rectangles(staircase, mesh, C)
 
     def mixer(h, layer):
         if "in_qkvz" in layer:
@@ -393,7 +396,7 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
             theta=cfg.rope_theta, norm=norm, turned=cfg.rotary_dim,
             gated=True, blocks=blocks)
 
-    def ff(h, layer):
+    def ff(h, mask, layer):
         out, counts = experts(h, mask, layer["router"], layer["w1"],
                               layer["w3"], layer["w2"])
         with jax.named_scope("shared"):
@@ -403,7 +406,7 @@ def encode_qwen3_next(params: Dict, source_ids: jax.Array,
         linear = cfg.layer_types[i] == LINEAR
         run = seq_block.residual_layer(
             i, norm=norm, mixer_scope="gdn" if linear else "attn",
-            mixer=mixer, ff=ff)
+            mixer=mixer, ff=ff, mask=mask, rectangles=rectangles)
 
         def counted(x, layer):
             x, counts = run(x, layer)

@@ -53,10 +53,12 @@ class EncoderSpec:
     # the int8, sparse-update and VarMisuse steps are written for it
     # (they call the bag's `encode` and train no subtree)
     table_step_variants: bool = False
-    # its mixers are `models/seq_block.py`'s softmax mixers, whose core
-    # runs by query block over a training batch's staircase: the
-    # producer then counts the pairs the chosen step scores
-    # (`attn_pairs` on `infeed/transfer`, data/prefetch.py)
+    # it is a block of `models/seq_block.py`: its softmax mixers' core
+    # runs by query block over a training batch's staircase and its
+    # layers' feed-forward half over the staircase's positions, so the
+    # producer counts the pairs the chosen step scores and the
+    # positions it feeds forward (`attn_pairs`, `ff_slots` on
+    # `infeed/transfer`, data/prefetch.py)
     scores_by_staircase: bool = False
     # makes the recorder of `aux` (obs/route.RouteRecorder's contract:
     # `push(aux)`, `flush()`, a `tracer` attribute); None exactly where

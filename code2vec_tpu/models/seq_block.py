@@ -7,7 +7,12 @@ filling from the left. `x` is [B, C, H], `m` the context mask.
                                           zeros (`run_block`)
   layer    x = x + Mixer(norm(x)) ; x = x + FF(norm(x))
                                           `residual_layer`: rematerialised,
-                                          under `c2v/blk_<i>/...` scopes
+                                          under `c2v/blk_<i>/...` scopes;
+                                          the second half over the
+                                          staircase's positions as one
+                                          flat sequence where the step
+                                          was compiled for one (`pack`,
+                                          `lay_back`)
   output   norm ; the product's learned-query pool over valid slots at
            width H ; code = pooled W_out2          H -> 3E (`run_block`)
 
@@ -35,7 +40,15 @@ says whether it does (a staircase, and the batch's rows on one device):
 the producer's count of the pairs a step scores
 (`Code2VecModel._train_device_batch`) asks it too. Every other program
 (the full step, evaluation, prediction, serving, rows dealt to several
-devices) gets None and lowers to the one whole core.
+devices) gets None and lowers to the one whole core. The feed-forward
+half of every layer (norm, MLP, shared expert, router, routed experts)
+goes the same way: the three encoders ask `ff_rectangles`, on
+`core_blocks`' condition, for the staircase's own rectangles and hand
+them to `residual_layer`, which then runs that half over the
+rectangles' positions as one flat `[1, area, H]` sequence; the producer
+counts those positions by the same function (`ff_slots`). The mixers'
+own projections still see every slot: they feed per-block cuts and pay
+a layout an operator (PERF.md section 6, PR 35 and PR 37).
 """
 
 from __future__ import annotations
@@ -95,9 +108,10 @@ def causal_softmax(logits: jax.Array, mask: jax.Array,
     return jax.nn.softmax(logits, axis=-1).astype(dtype)
 
 
-# a query block (`core_blocks`): (first slot, end slot, rows); its
-# queries are its rows' slots first .. end, and what causality lets them
-# see is those rows' slots 0 .. end
+# (first slot, end slot, rows): a query block (`core_blocks`), whose
+# queries are its rows' slots first .. end and see, by causality, those
+# rows' slots 0 .. end; or a rectangle of the staircase
+# (`ff_rectangles`), which holds its rows' slots first .. end
 Block = Tuple[int, int, int]
 Span = Optional[Tuple[int, int]]
 
@@ -114,6 +128,11 @@ def cut(t: jax.Array, *spans: Span) -> jax.Array:
     if spans == whole:
         return t
     return jax.lax.slice(t, *zip(*spans))
+
+
+def _to_rows(t: jax.Array, rows: int) -> jax.Array:
+    """t with zero rows below it, up to `rows`."""
+    return jnp.pad(t, ((0, rows - t.shape[0]),) + ((0, 0),) * (t.ndim - 1))
 
 
 def causal_core(logits: Callable, v: Callable, mask: jax.Array, dtype, *,
@@ -146,12 +165,8 @@ def causal_core(logits: Callable, v: Callable, mask: jax.Array, dtype, *,
 
     if blocks is None:
         return core((0, C, B))
-    out = []
-    for block in blocks:
-        o = core(block)
-        out.append(jnp.pad(o, ((0, B - block[2]),)
-                           + ((0, 0),) * (o.ndim - 1)))
-    return jnp.concatenate(out, axis=slot_axis)
+    return jnp.concatenate([_to_rows(core(block), B) for block in blocks],
+                           axis=slot_axis)
 
 
 def attention(h: jax.Array, mask: jax.Array, layer: Dict, *, heads: int,
@@ -319,24 +334,65 @@ def routed_experts(h: jax.Array, mask: jax.Array, score: Callable,
     return out.reshape(B, C, H), counts[None]
 
 
+def pack(t: jax.Array, rectangles: Tuple[Block, ...]) -> jax.Array:
+    """t [B, C, ...] -> [1, area, ...]: the rectangles' positions as
+    one flat sequence, rectangle by rectangle, each row by row, each
+    taken in ONE slice of `t` (`cut`)."""
+    flat = [cut(t, (0, kept), (first, end)).reshape((-1,) + t.shape[2:])
+            for first, end, kept in rectangles]
+    return jnp.concatenate(flat)[None]
+
+
+def lay_back(flat: jax.Array, rectangles: Tuple[Block, ...],
+             rows: int) -> jax.Array:
+    """`pack`'s way back: flat [1, area, ...] -> [rows, C, ...], zeros
+    outside the rectangles (which stand side by side from slot 0 to
+    C)."""
+    out, start = [], 0
+    for first, end, kept in rectangles:
+        size = kept * (end - first)
+        out.append(_to_rows(flat[0, start:start + size].reshape(
+            (kept, end - first) + flat.shape[2:]), rows))
+        start += size
+    return jnp.concatenate(out, axis=1)
+
+
 def residual_layer(i: int, *, norm: Callable, mixer_scope: str,
-                   mixer: Callable, ff: Callable,
-                   ff_scope: Optional[str] = None) -> Callable:
+                   mixer: Callable, ff: Callable, mask: jax.Array,
+                   ff_scope: Optional[str] = None,
+                   rectangles: Optional[Tuple[Block, ...]] = None
+                   ) -> Callable:
     """Layer i as `run(x, layer) -> (x, counts)`, rematerialised in the
     backward pass (at H = 2048 a layer's activations are the memory).
-    `mixer(h, layer)` gives the operator's output; `ff(h, layer)` the
-    feed-forward's and its counts ([devices, n] int32, summed here over
-    the devices, or None); `norm(x, scale)` the block's norm, over the
-    layer's `op_norm` and `ff_norm`."""
+    `mixer(h, layer)` gives the operator's output; `ff(h, mask, layer)`
+    the feed-forward's over positions h [rows, slots, H] under their
+    `mask` [rows, slots], and its counts ([devices, n] int32, summed
+    here over the devices, or None); `norm(x, scale)` the block's norm,
+    over the layer's `op_norm` and `ff_norm`.
+
+    `rectangles` (`ff_rectangles`; None: every slot of every row, as
+    this always was) is the caller's word that the batch is ordered
+    longest bag first and PAD outside them (`embed_contexts` has who
+    checks it). The feed-forward half, norm and `ff`, is position-wise,
+    so it then runs over the rectangles' positions as ONE sequence
+    [1, area, H] (`pack`), under the mask packed the same way, and its
+    output is laid back once (`lay_back`). A slot outside the
+    rectangles keeps x: nothing valid reads it (the mixers look left
+    and valid slots fill from the left, the pool and the routers mask),
+    and no gradient comes back from it."""
     ff_scope = f"c2v/blk_{i}" + (f"/{ff_scope}" if ff_scope else "")
+    ff_mask = mask if rectangles is None else pack(mask, rectangles)
 
     def run(x, layer):
         h = norm(x, layer["op_norm"])
         with jax.named_scope(f"c2v/blk_{i}/{mixer_scope}"):
             x = x + mixer(h, layer)
-        h = norm(x, layer["ff_norm"])
+        kept = x if rectangles is None else pack(x, rectangles)
+        h = norm(kept, layer["ff_norm"])
         with jax.named_scope(ff_scope):
-            out, counts = ff(h, layer)
+            out, counts = ff(h, ff_mask, layer)
+        if rectangles is not None:
+            out = lay_back(out, rectangles, x.shape[0])
         return x + out, (None if counts is None
                          else jnp.sum(counts, axis=0))
 
@@ -391,6 +447,24 @@ def core_blocks(staircase: Optional[Stairs], mesh, max_contexts: int
     if staircase is None or batch_devices(mesh) != 1:
         return None
     return query_blocks(staircase, max_contexts)
+
+
+def ff_rectangles(staircase: Optional[Stairs], mesh, max_contexts: int
+                  ) -> Optional[Tuple[Block, ...]]:
+    """The rectangles whose positions the feed-forward half of every
+    layer runs over in a step compiled for `staircase` on `mesh`
+    (`residual_layer`): the staircase's own, from slot 0 to
+    `max_contexts`, or None where it runs over every slot, on
+    `core_blocks`' condition (no staircase, or the batch's rows dealt to
+    several devices; nor for a staircase that does not start at slot 0,
+    which no batch `fits`). Both who compiles the step (the block
+    encoders) and who counts the positions it multiplies (the producer's
+    `ff_slots`, `Code2VecModel._train_device_batch`) ask here."""
+    if staircase is None or batch_devices(mesh) != 1 or staircase[0][0]:
+        return None
+    ends = [first for first, _ in staircase[1:]] + [max_contexts]
+    return tuple((first, end, kept)
+                 for (first, kept), end in zip(staircase, ends))
 
 
 def refuse_context_parallel(cfg, name: str) -> None:

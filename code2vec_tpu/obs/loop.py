@@ -68,7 +68,8 @@ def infeed_produce_instrument(tracer: Tracer,
             seq=record.seq, rows=record.rows,
             pad_slots=record.pad_slots,
             gather_slots=record.gather_slots,
-            attn_pairs=record.attn_pairs, bytes=record.bytes))
+            attn_pairs=record.attn_pairs, ff_slots=record.ff_slots,
+            bytes=record.bytes))
     return on_produced
 
 

@@ -401,6 +401,38 @@ def test_infeed_transfer_carries_the_scored_pairs_a_batch_names():
         5000, None, 5002]
 
 
+def test_infeed_transfer_carries_the_fed_forward_slots_a_batch_names():
+    """`ff_slots` beside them (ISSUE 37): every count of
+    `prefetch.TRANSFER_COUNTS` that the transferred batch says of
+    itself stands on the transfer's span and on the `--trace` span, and
+    one it does not say stands on neither."""
+    from code2vec_tpu.data.prefetch import TRANSFER_COUNTS
+    from code2vec_tpu.obs import SpanChannel, infeed_produce_instrument
+    from code2vec_tpu.training.steps import TrainBatch
+
+    assert TRANSFER_COUNTS == ("gather_slots", "attn_pairs", "ff_slots")
+
+    def put(b):
+        out = TrainBatch((np.zeros(4),), True, 100 + b.i)
+        if b.i != 1:
+            out.ff_slots = 70 + b.i
+        return out
+
+    clock, tele = FakeClock(), _Events()
+    infeed = _SyncInfeed(FakeReader(clock, 3), put)
+    rec = infeed._recorder = MemoryTracer(clock=clock)
+    infeed._on_produced = infeed_produce_instrument(Tracer.create(tele),
+                                                    SpanChannel())
+    list(infeed)
+    transfers = rec.records("infeed/transfer")
+    assert [r["attrs"].get("ff_slots") for r in transfers] == [70, None, 72]
+    assert "ff_slots" not in transfers[1]["attrs"]
+    assert all("attn_pairs" not in r["attrs"] for r in transfers)
+    assert [s["attrs"].get("ff_slots") for s in tele.spans] == [70, None, 72]
+    assert [s["attrs"]["gather_slots"] for s in tele.spans] == [100, 101,
+                                                                102]
+
+
 # ---- the production record, on its threads ------------------------------
 
 @pytest.mark.parametrize("kind", ["per_batch", "chunked"])

@@ -691,7 +691,7 @@ def test_model_counts_the_pairs_its_softmax_layers_score(
     reader = open_reader(model.config.data_path("train"), model.vocabs, C,
                          128, shuffle=True, seed=3)
     before = len(memory_tracer().records("infeed/transfer"))
-    pairs = []
+    pairs, slots = [], []
     for i, (dev, host) in enumerate(model._train_infeed(reader)):
         whole = host.num_valid_examples == 128
         assert dev.fits == whole
@@ -700,6 +700,12 @@ def test_model_counts_the_pairs_its_softmax_layers_score(
             if whole and data_axis == 1
             else host.num_valid_examples * C * C)
         pairs.append(dev.attn_pairs)
+        # ISSUE 37: and the positions its layers' feed-forward half
+        # runs over, by `seq_block.ff_rectangles`
+        assert dev.ff_slots == (
+            st.area(stairs, C) if whole and data_axis == 1
+            else host.num_valid_examples * C)
+        slots.append(dev.ff_slots)
         if i in (0, 1, 8):      # two that fit and the short one
             key = jax.random.fold_in(model.rng, i)
             model.params, model.opt_state, loss = model._train_step(
@@ -711,19 +717,24 @@ def test_model_counts_the_pairs_its_softmax_layers_score(
     assert (pairs[0] < 128 * C * C) == (data_axis == 1)
     spans = memory_tracer().records("infeed/transfer")[before:]
     assert [s["attrs"]["attn_pairs"] for s in spans] == pairs
+    assert [s["attrs"]["ff_slots"] for s in spans] == slots
+    assert (slots[0] < 128 * C) == (data_axis == 1)
     dev, _host = next(iter(bag._train_infeed(open_reader(
         bag.config.data_path("train"), bag.vocabs, C, 128, shuffle=True,
         seed=3))))
     assert dev.fits and not hasattr(dev, "attn_pairs")
-    assert "attn_pairs" not in memory_tracer().records(
-        "infeed/transfer")[-1]["attrs"]
+    assert not hasattr(dev, "ff_slots")
+    assert not {"attn_pairs", "ff_slots"} & set(memory_tracer().records(
+        "infeed/transfer")[-1]["attrs"])
     # the count asks the function the encoder compiles its step by
     from code2vec_tpu.models import seq_block
     monkeypatch.setattr(seq_block, "core_blocks", lambda *a: None)
+    monkeypatch.setattr(seq_block, "ff_rectangles", lambda *a: None)
     dev, host = next(iter(model._train_infeed(open_reader(
         model.config.data_path("train"), model.vocabs, C, 128, shuffle=True,
         seed=3))))
     assert dev.fits and dev.attn_pairs == host.num_valid_examples * C * C
+    assert dev.ff_slots == host.num_valid_examples * C
 
 
 def test_core_blocks_is_none_with_no_staircase_or_rows_on_two_devices():

@@ -371,6 +371,28 @@ def test_trace_report_prints_the_scored_pairs_beside_them(
                                           counted[:-1] + produced[-1:])])
 
 
+def test_trace_report_prints_the_fed_forward_slots_beside_them(
+        traced_train_run):
+    """`ff_slots` (ISSUE 37) likewise: no line for the bag, the sum over
+    rows x contexts where every counted batch carries one."""
+    from tools.trace_report import pad_slot_summary, render
+    assert "ff_slots" not in pad_slot_summary(traced_train_run)
+    manifest = {"config": {"MAX_CONTEXTS": 16}}
+    assert "feed-forward half" not in render([(manifest, traced_train_run)])
+    produced = [s for s in traced_train_run
+                if s["name"] == "infeed/produce"]
+    counted = [dict(s, attrs=dict(s["attrs"],
+                                  ff_slots=s["attrs"]["rows"] * 10))
+               for s in produced]
+    pad = pad_slot_summary(counted)
+    assert pad["ff_slots"] == 10 * pad["rows"]
+    assert ("Slots a layer's feed-forward half ran over: "
+            f"{pad['ff_slots']:,} ({100.0 * 10 / 16:.2f}%)") in render(
+        [(manifest, counted)])
+    assert "feed-forward half" not in render([(manifest,
+                                               counted[:-1] + produced[-1:])])
+
+
 def test_breakdown_primary_and_linked_requests_agree():
     """Regression: the flush's encode/device children share the
     PRIMARY request's trace id — they must be attributed through the

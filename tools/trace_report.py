@@ -35,7 +35,8 @@ emits) and produces:
     (`gather_slots`: what the step chosen for each batch took table
     rows for, the staircase's area or every slot) and, for an encoder
     whose softmax mixers run over the staircase, the query-key pairs a
-    head of one softmax layer scored (`attn_pairs`), and for a routed-experts
+    head of one softmax layer scored (`attn_pairs`) and the positions a
+    layer's feed-forward half ran over (`ff_slots`), and for a routed-experts
     encoder (lfm2_moe, qwen3_next, joyai_flash) the `moe/route` records: rows routed to the experts held
     here over the valid tokens' choices, the fullest expert's rows
     over the mean, and the spans' `row_bound` and `compact_layers`.
@@ -331,7 +332,8 @@ def pad_slot_summary(spans: Sequence[Dict[str, Any]]
     gather spreads a PAD read); None when none does. `gather_slots`
     beside them where every counted batch carries one: the slots the
     steps took table rows for; `attn_pairs` likewise: the query-key
-    pairs a head of one softmax layer of the steps scored."""
+    pairs a head of one softmax layer of the steps scored; `ff_slots`:
+    the positions a layer's feed-forward half of the steps ran over."""
     counted = [a for a in ((s.get("attrs") or {}) for s in spans
                            if s["name"] == "infeed/produce")
                if a.get("pad_slots") is not None and a.get("rows")]
@@ -340,7 +342,7 @@ def pad_slot_summary(spans: Sequence[Dict[str, Any]]
     out = {"batches": len(counted),
            "rows": sum(a["rows"] for a in counted),
            "pad_slots": sum(a["pad_slots"] for a in counted)}
-    for count in ("gather_slots", "attn_pairs"):
+    for count in ("gather_slots", "attn_pairs", "ff_slots"):
         if all(a.get(count) is not None for a in counted):
             out[count] = sum(a[count] for a in counted)
     return out
@@ -523,6 +525,14 @@ def render(loaded, limit: int = 10) -> str:
                     share = (f" ({percent:.2f}% of rows x {contexts}^2)")
                 lines.append("Scored pairs a head and softmax layer: "
                              f"{pad['attn_pairs']:,}{share}")
+            if "ff_slots" in pad:
+                share = ""
+                if contexts:
+                    percent = 100.0 * pad["ff_slots"] / (
+                        pad["rows"] * contexts)
+                    share = f" ({percent:.2f}%)"
+                lines.append("Slots a layer's feed-forward half ran over: "
+                             f"{pad['ff_slots']:,}{share}")
         route = route_summary(spans)
         if route:
             lines.append("")
