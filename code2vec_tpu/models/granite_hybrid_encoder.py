@@ -221,8 +221,8 @@ def _mamba(h: jax.Array, mask: jax.Array, layer: Dict, cfg: GraniteDims,
     """`scan` is `delta_rule.ssd_scan`, whole or under a mesh's
     `shard_map`."""
     dtype, f32 = h.dtype, jnp.float32
-    B, C, _ = h.shape
-    n, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    C = h.shape[1]
+    P, N = cfg.mamba_d_head, cfg.mamba_d_state
     z, xbc, dt = jnp.split(h @ layer["ssm_in"].astype(dtype),
                            [cfg.d_inner, cfg.d_inner + cfg.conv_dim],
                            axis=-1)
@@ -233,15 +233,15 @@ def _mamba(h: jax.Array, mask: jax.Array, layer: Dict, cfg: GraniteDims,
         xbc = jax.nn.silu(layer["conv_b"] + sum(
             tap * padded[:, j:j + C, :].astype(f32)
             for j, tap in enumerate(layer["conv_w"].T)))
+    # x and the scan's o are [B, C, n P], head h in columns h P .. h P +
+    # P - 1, so D is repeated over a head's P columns
     x, b, c = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + N], axis=-1)
-    x = x.reshape(B, C, n, P)
     dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"])
     g = -jnp.exp(layer["A_log"]) * dt
     with jax.named_scope("scan"):
         o = scan(c[:, :, None].astype(dtype), b[:, :, None].astype(dtype),
                  x.astype(dtype), dt, g, mask)
-    y = (o + layer["D"][:, None] * x).reshape(B, C, cfg.d_inner) \
-        * jax.nn.silu(z.astype(f32))
+    y = (o + jnp.repeat(layer["D"], P) * x) * jax.nn.silu(z.astype(f32))
     y = _rms_norm(y, layer["ssm_norm"], cfg.rms_norm_eps)
     return y.astype(dtype) @ layer["ssm_out"].astype(dtype)
 

@@ -56,7 +56,10 @@ chunk (`_by_chunks`): the state is decayed by one scalar a head and
 written with no delta correction, S_t = exp(g_t) S_{t-1} + k_t (dt_t
 v_t)^T, o_t = S_t^T q_t, so in chunk form it is the rule's O and S with
 T = I and no W S term: no triangle to invert. One key head (B, C)
-serves every value head there.
+serves every value head there. Its x comes and its o goes flat, [B, C,
+n_v d_v], the Mamba layer's own layout: each chunk is cut and laid out
+at its own rows, and the chunks' outputs are joined once along the
+slots (`_flat_chunks`), where the rule lays its chunks side by side.
 """
 
 from __future__ import annotations
@@ -191,10 +194,11 @@ def _by_chunks(name: str, one_chunk, q, k, v, g, beta, mask, rows):
     """What the two scans share: the checks of `rows`, the masked
     slots' g and beta set to 0, the chunks laid out (cut to their rows
     where `rows` bounds them), `one_chunk` run over them in order, each
-    rematerialised, and o laid back [B, C, n_v, d_v], 0 at masked
-    slots."""
+    rematerialised, and o laid back in v's layout, 0 at masked slots:
+    [B, C, n_v, d_v] for the rule's v, [B, C, n_v d_v] for the scan's
+    flat x (`_flat_chunks`)."""
     B, C, n_k, d_k = k.shape
-    n_v, d_v = v.shape[2], v.shape[3]
+    n_v = g.shape[2]
     r = n_v // n_k
     L = chunk_len(C)
     N = -(-C // L)
@@ -208,6 +212,9 @@ def _by_chunks(name: str, one_chunk, q, k, v, g, beta, mask, rows):
     f32, dtype = jnp.float32, v.dtype
     live = mask.astype(f32)[..., None]
     g, beta = g.astype(f32) * live, beta.astype(f32) * live
+    if v.ndim == 3:
+        return _flat_chunks(one_chunk, q, k, v, g, beta, live, rows, L)
+    d_v = v.shape[3]
 
     def split(t, grouped: bool):
         """[b, c, heads, *tail] -> [b, c / L, n_k, (r,) L, *tail]; the
@@ -256,6 +263,41 @@ def _by_chunks(name: str, one_chunk, q, k, v, g, beta, mask, rows):
     return o * live[..., None]
 
 
+def _flat_chunks(one_chunk, q, k, x, g, beta, live, rows, L):
+    """`_by_chunks` over the scan's x [B, C, n_v d_v], the layout its
+    layer holds: chunk n's operands are cut at its rows and slots by one
+    slice each and laid out heads-major at those rows, its o laid back
+    slot-major [rows[n], slots, n_v d_v] by one copy, and the chunks
+    joined once along the slots, the rows below a chunk's bound zero.
+    The last chunk runs over the slots it has, so nothing is padded to
+    N L slots and nothing cut back. No array of all the chunks side by
+    side is made, forward or backward."""
+    B, C, n_k, d_k = k.shape
+    n_v = g.shape[2]
+    r, d_v = n_v // n_k, x.shape[2] // n_v
+    f32, dtype = jnp.float32, x.dtype
+    S = jnp.zeros((rows[0], n_k, r, d_k, d_v), f32)
+    out = []
+    for n, kept in enumerate(rows):
+        first, end = n * L, min((n + 1) * L, C)
+        l = end - first
+        q_n, k_n, x_n, g_n, beta_n = (t[:kept, first:end]
+                                      for t in (q, k, x, g, beta))
+        # [kept, n_k, l, d_k]; [kept, n_k, r, l, d_v]; [kept, n_k, r, l]
+        q_n, k_n = jnp.moveaxis(q_n, 1, 2), jnp.moveaxis(k_n, 1, 2)
+        x_n = jnp.moveaxis(x_n.reshape(kept, l, n_k, r, d_v), 1, 3)
+        g_n, beta_n = (jnp.moveaxis(t.reshape(kept, l, n_k, r), 1, 3)
+                       for t in (g_n, beta_n))
+        if kept < S.shape[0]:
+            S = S[:kept]        # a row that leaves never comes back
+        chunk = jax.checkpoint(functools.partial(one_chunk, i=jnp.arange(l),
+                                                 dtype=dtype))
+        S, o = chunk(S, q_n, k_n, x_n, g_n, beta_n)
+        o = jnp.moveaxis(o, 3, 1).reshape(kept, l, n_v * d_v)
+        out.append(jnp.pad(o, ((0, B - kept), (0, 0), (0, 0))))
+    return jnp.concatenate(out, axis=1) * live
+
+
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
                      g: jax.Array, beta: jax.Array, mask: jax.Array,
                      rows: Optional[Tuple[int, ...]] = None) -> jax.Array:
@@ -281,13 +323,15 @@ def ssd_scan(q: jax.Array, k: jax.Array, v: jax.Array, dt: jax.Array,
              g: jax.Array, mask: jax.Array,
              rows: Optional[Tuple[int, ...]] = None) -> jax.Array:
     """Mamba-2's state-space (SSD) scan in chunks of `chunk_len(C)`
-    slots, `gated_delta_rule`'s arguments, layout and `rows` bound: q
-    (C), k (B) [B, C, n_k, d_k], v (x) [B, C, n_v, d_v], dt and g [B, C,
-    n_v] float32, mask [B, C]. Per value head, S_0 = 0 [d_k, d_v]:
+    slots, `gated_delta_rule`'s arguments and `rows` bound with x flat
+    as the Mamba layer holds it: q (C), k (B) [B, C, n_k, d_k], v (x)
+    [B, C, n_v d_v], value head h its columns h d_v .. h d_v + d_v - 1,
+    dt and g [B, C, n_v] float32, mask [B, C]. Per value head, S_0 = 0
+    [d_k, d_v]:
 
       S_t = exp(g_t) S_{t-1} + k_t (dt_t v_t)^T     g_t <= 0
       o_t = S_t^T q_t
 
     A masked slot leaves the state as it is (dt = g = 0) and reads
-    o = 0. Returns o [B, C, n_v, d_v] float32."""
+    o = 0. Returns o [B, C, n_v d_v] float32, x's layout."""
     return _by_chunks("ssd_scan", _ssd_chunk, q, k, v, g, dt, mask, rows)

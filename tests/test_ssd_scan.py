@@ -1,12 +1,14 @@
-"""`ops/delta_rule.ssd_scan` (Mamba-2's scalar-decay state-space scan,
-ISSUE 38) in float32 on the CPU: the chunked scan against the
-token-by-token recurrence, output and every gradient, at two chunk
-lengths, with slots no multiple of the chunk, and under a staircase's
-bound on the rows; what a masked slot leaves alone; one key head serving
-every value head. And the delta rule, whose chunk machinery the scan now
-shares: its lowered text, bounded or not, is what it was (the three
-block encoders' lowered train steps, under this copy of the old rule and
-the old `seq_block` functions, are held in tests/test_granite_hybrid.py)."""
+"""`ops/delta_rule.ssd_scan` (Mamba-2's scalar-decay state-space scan)
+in float32 on the CPU, x and o flat [B, C, n_v d_v] as the Mamba layer
+holds them: the chunked scan against the token-by-token recurrence,
+output and every gradient, at two chunk lengths, with slots no multiple
+of the chunk, and under a staircase's bound on the rows; what a masked
+slot leaves alone; one key head serving every value head; and a lowered
+program that holds no array of the chunks side by side and no padding to
+whole chunks. And the delta rule, whose chunk machinery the scan shares:
+its lowered text, bounded or not, is what it was (the three block
+encoders' lowered train steps, under this copy of the old rule and the
+old `seq_block` functions, are held in tests/test_granite_hybrid.py)."""
 
 from unittest import mock
 
@@ -23,7 +25,8 @@ ARGS = "q k v dt g".split()
 
 def case(B=3, C=23, n_v=4, d_k=8, d_v=6, seed=0, lens=None):
     """(q, k, v, dt, g, mask): one key head of C and B [B, C, 1, d_k],
-    value heads of x [B, C, n_v, d_v], dt > 0 and g = -dt A <= 0."""
+    n_v value heads of d_v in x [B, C, n_v d_v], dt > 0 and
+    g = -dt A <= 0."""
     k = jax.random.split(jax.random.PRNGKey(seed), 5)
     lens = np.array([C, max(C - 7, 1), max(C // 3, 1)][:B]) \
         if lens is None else np.asarray(lens)
@@ -32,7 +35,7 @@ def case(B=3, C=23, n_v=4, d_k=8, d_v=6, seed=0, lens=None):
     A = jnp.arange(1, n_v + 1, dtype=jnp.float32)
     return (jax.random.normal(k[0], (B, C, 1, d_k)) / np.sqrt(d_k),
             jax.random.normal(k[1], (B, C, 1, d_k)) / np.sqrt(d_k),
-            jax.random.normal(k[2], (B, C, n_v, d_v)), dt, -dt * A,
+            jax.random.normal(k[2], (B, C, n_v * d_v)), dt, -dt * A,
             jnp.asarray(mask))
 
 
@@ -49,12 +52,13 @@ def _recurrence(q, k, v, dt, g, mask):
             "bk,bhv->bhkv", k_t, dt_t[..., None] * v_t, precision=_HIGHEST)
         return S, jnp.einsum("bhkv,bk->bhv", S, q_t, precision=_HIGHEST)
 
-    B, _, n_v, d_v = v.shape
+    B, C, n_v = dt.shape
+    d_v = v.shape[2] // n_v
     S0 = jnp.zeros((B, n_v, k.shape[-1], d_v), f32)
     _, o = jax.lax.scan(slot, S0, tuple(jnp.moveaxis(t, 1, 0) for t in (
-        q[:, :, 0].astype(f32), k[:, :, 0].astype(f32), v.astype(f32),
-        dt, g)))
-    return jnp.moveaxis(o, 0, 1) * live[..., None]
+        q[:, :, 0].astype(f32), k[:, :, 0].astype(f32),
+        v.reshape(B, C, n_v, d_v).astype(f32), dt, g)))
+    return jnp.moveaxis(o, 0, 1).reshape(B, C, n_v * d_v) * live
 
 
 recurrence = jax.jit(_recurrence)
@@ -154,8 +158,10 @@ def test_with_no_decay_a_head_reads_the_sum_of_its_writes():
     out = scan_in_chunks_of(4)(q, k, v, dt, jnp.zeros_like(dt), mask)
     qk = jnp.einsum("bid,bjd->bij", q[:, :, 0], k[:, :, 0],
                     precision=_HIGHEST) * np.tril(np.ones((10, 10)))
-    want = jnp.einsum("bij,bjh,bjhv->bihv", qk, dt, v, precision=_HIGHEST)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+    want = jnp.einsum("bij,bjh,bjhv->bihv", qk, dt, v.reshape(2, 10, 4, 6),
+                      precision=_HIGHEST)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(want).reshape(2, 10, 24), atol=1e-5)
 
 
 def test_bfloat16_operands_keep_a_float32_state():
@@ -166,6 +172,25 @@ def test_bfloat16_operands_keep_a_float32_state():
     assert got.dtype == jnp.float32
     gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     assert gap < 0.02, gap
+
+
+@pytest.mark.parametrize("rows", [(8, 5, 3, 2), None])
+def test_the_scan_lowers_with_no_chunks_side_by_side(rows):
+    """At 8 rows and 200 slots (four chunks of 64, the last of 8), bounded
+    or not: no array of the program, forward or backward, holds the four
+    chunks side by side at all 8 rows, and none is padded to 256 slots.
+    Each chunk is laid out and back at its own rows and slots, and the
+    chunks are joined once along the slots."""
+    args = case(B=8, C=200, n_v=3, lens=[200, 150, 120, 90, 60, 40, 20, 5])
+    fn = scan_in_chunks_of(64, rows)
+    grad = jax.grad(lambda *a: jnp.sum(fn(*a, args[5])),
+                    argnums=(0, 1, 2, 3, 4))
+    texts = [jax.jit(f).lower(*a).as_text()
+             for f, a in ((fn, args), (grad, args[:5]))]
+    for text in texts:
+        assert "tensor<8x4x" not in text
+        assert "x256x" not in text and "tensor<8x256" not in text
+        assert "tensor<8x200x18xf32>" in text
 
 
 # ---- the delta rule, whose chunk machinery the scan shares ---------------
