@@ -69,7 +69,8 @@ import jax.numpy as jnp
 from code2vec_tpu.data.staircase import Stairs, query_blocks
 from code2vec_tpu.models.transformer_encoder import (learned_query_pool,
                                                      padding_log_mask)
-from code2vec_tpu.ops.moe import counts_zeros, held_experts_ffn, ran_at_bound
+from code2vec_tpu.ops.moe import (busiest_share, counts_zeros,
+                                  held_experts_ffn, ran_at_bound)
 
 # the sigmoid routers' selection bias is a seeded buffer of this scale
 # x normal, held fixed: small beside the gaps between a token's top
@@ -346,8 +347,9 @@ def routed_experts(h: jax.Array, mask: jax.Array, score: Callable,
     more, the live inner entries that are zero and how many there are
     (`held_experts_ffn`)).
     `score(tokens [N, H])` is the block's router: (chosen [N, K],
-    p [N, K]), `ops/moe.route`; `act` and `wait_for_busiest` are
-    `held_experts_ffn`'s."""
+    p [N, K]), `ops/moe.route`; `act` is `held_experts_ffn`'s.
+    `wait_for_busiest`: the products run over the busiest share's rows
+    (`moe.busiest_share`, handed to `held_experts_ffn` as `busiest`)."""
     B, C, H = h.shape
     tokens = h.reshape(B * C, H)
     valid = mask.reshape(B * C) > 0
@@ -355,12 +357,14 @@ def routed_experts(h: jax.Array, mask: jax.Array, score: Callable,
     with jax.named_scope("router"):
         chosen, p = score(tokens)
     with jax.named_scope("experts"):
+        busiest = (busiest_share(chosen, valid, first_expert, held, routed)
+                   if wait_for_busiest and routed > held else None)
         out, counted = held_experts_ffn(tokens, valid, chosen, p, w1, w3,
                                         w2, first_expert, routed, act,
-                                        wait_for_busiest)
+                                        busiest)
     rows, inner = ((counted[:held], [counted[held:]]) if counts_zeros(act)
                    else (counted, []))
-    bound, at_bound = ran_at_bound(rows, chosen.size, routed)
+    bound, at_bound = ran_at_bound(rows, chosen.size, routed, busiest)
     counts = jnp.concatenate([rows, jnp.stack([
         jnp.sum(valid, dtype=jnp.int32), jnp.int32(bound),
         at_bound.astype(jnp.int32)])] + inner)

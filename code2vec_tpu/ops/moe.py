@@ -40,28 +40,34 @@ No capacity, no dropped row, and this is how. Were every token to
 choose one held expert, its group would be N rows long, so only arrays
 of N K rows hold every routing; but held of E experts take held / E of
 the choices when the router is even, and every array between the sort
-and the sum back is copied, selected and weighted at its full length
-whatever is live in it. So those arrays hold `row_bound` rows, R =
-twice the rows the held experts would take from an all-valid, evenly
-routed batch (2 N K held / E, up to the product's row tile): the first R
-sorted pairs, which are every live pair whenever the live pairs are at
-most R. A layer whose live pairs are more than R (the device alone
-knows; `fits`) runs the same body over all N K sorted pairs instead:
-one `lax.cond`, inside a `custom_vjp` whose backward is its own `cond`
-over the two bodies' vjps, so that no residual of either body crosses a
-branch (a plain `cond` under autodiff keeps both bodies' residuals).
-Where R would not be under N K (all experts held here) there is one
-body and no conditional. Either way each live row is multiplied by the
-same weights in the same group at the same offset.
+and the sum back is gathered, selected, weighted and added back at its
+full length whatever is live in it. So those arrays hold `row_bound`
+rows, R = the rows the held experts would take from an all-valid,
+evenly routed batch (N K held / E), up to a whole number of 256-row
+tiles: the first R sorted pairs, which are every live pair whenever
+the live pairs are at most R. A near-even router's live pairs fill
+62-76% of it in the cells, and the tile is 256 because the grouped
+product pays 19-41% a call over rows that are no multiple of 256
+(PERF.md section 6). A layer whose live pairs (or the busiest share's
+rows, below) are more than R (the device alone knows; `fits`) runs the
+same body over all N K sorted pairs instead: one `lax.cond`, inside a
+`custom_vjp` whose backward is its own `cond` over the two bodies'
+vjps, so that no residual of either body crosses a branch (a plain
+`cond` under autodiff keeps both bodies' residuals). Where R would not
+be under N K (all experts held here) there is one body and no
+conditional. Either way each live row is multiplied by the same
+weights in the same group at the same offset.
 
-A layer can be told to wait for its busiest share (`wait_for_busiest`,
+A layer can be told to wait for its busiest share (`busiest`,
 `model_type` `smallthinker`): were the E experts cut into shares of
 held, one a chip, a synchronous layer would last on every chip as long
 as the products of the share that took the most rows. So the products
 run over that many rows (`busiest_share`, from the choices of every
 valid token), the rows past the live ones dead: zeros, in the last
 held expert's group, out of every count and output. On the TPU the
-products' cost then follows the busiest share's rows, not this one's.
+products' cost then follows the busiest share's rows, not this one's,
+and a layer whose busiest share is more than R runs every pair, so
+that the wait is never cut short.
 """
 
 from __future__ import annotations
@@ -115,12 +121,10 @@ def choose(logits: jax.Array, bias: Optional[jax.Array], top_k: int,
     return chosen, (p if scale == 1.0 else scale * p)
 
 
-# the arrays between the sort and the sum back hold this many times the
-# rows an all-valid, evenly routed batch would send to the held experts
-# (PERF.md section 6, PR 31: the cell's live rows are a fifth of it, a
-# collapsing router's 40% of the valid choices two thirds)
-BOUND_OVER_EVEN = 2
-ROW_TILE = 128
+# the arrays between the sort and the sum back hold the rows an
+# all-valid, evenly routed batch would send to the held experts, up to
+# whole tiles of the grouped product (PERF.md section 6)
+ROW_TILE = 256
 
 
 def row_bound(pairs: int, held: int, routed: int) -> int:
@@ -128,24 +132,32 @@ def row_bound(pairs: int, held: int, routed: int) -> int:
     (token, choice) pairs its arrays hold when `held` of `routed`
     experts are here. `pairs` itself where that is no less (nothing to
     leave out: one body, no conditional)."""
-    tiles = -(-BOUND_OVER_EVEN * pairs * held // (routed * ROW_TILE))
+    tiles = -(-pairs * held // (routed * ROW_TILE))
     return min(tiles * ROW_TILE, pairs)
 
 
-def fits(rows: jax.Array, bound: int) -> jax.Array:
+def fits(rows: jax.Array, bound: int,
+         busiest: Optional[jax.Array] = None) -> jax.Array:
     """Whether the layer's live pairs (`rows` [held], the rows each held
-    expert took) are all among the first `bound` sorted pairs: the
-    device's own choice between the two bodies."""
-    return jnp.sum(rows) <= bound
+    expert took) are all among the first `bound` sorted pairs, and the
+    `busiest` share's rows too where the layer waits for them: the
+    device's own choice between the two bodies. Fewer rows than the
+    busiest share's would cut the wait short."""
+    need = jnp.sum(rows)
+    if busiest is not None:
+        need = jnp.maximum(need, busiest)
+    return need <= bound
 
 
-def ran_at_bound(rows: jax.Array, pairs: int,
-                 routed: int) -> Tuple[int, jax.Array]:
+def ran_at_bound(rows: jax.Array, pairs: int, routed: int,
+                 busiest: Optional[jax.Array] = None
+                 ) -> Tuple[int, jax.Array]:
     """(R, whether a layer of `pairs` pairs whose held experts took
-    `rows` ran at R): what `held_experts_ffn` decided, for the counts
-    that leave the step. Never where R is the full length."""
+    `rows`, waiting for `busiest`, ran at R): what `held_experts_ffn`
+    decided, for the counts that leave the step. Never where R is the
+    full length."""
     bound = row_bound(pairs, rows.shape[0], routed)
-    return bound, fits(rows, bound) & (bound < pairs)
+    return bound, fits(rows, bound, busiest) & (bound < pairs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -278,10 +290,20 @@ def _bodies(bound: int, scatter: bool = True, act: str = "silu"):
             functools.partial(_every_pair, act=act))
 
 
+def _fenced(*operands):
+    """The operands as they are, behind an `optimization_barrier`: XLA
+    otherwise sinks their producers (the staircase's packing of the
+    layer's input, the router's weights) into the conditional's
+    branches, where the body over R rows ran 8 ms a step slower on the
+    v5e in the lfm2moe cell (PERF.md section 5)."""
+    return jax.lax.optimization_barrier(operands)
+
+
 def _differentiated(h, p, w1, w3, w2, order, rows, busiest, bound: int,
                     act: str):
-    return jax.lax.cond(fits(rows, bound), *_bodies(bound, act=act),
-                        h, p, w1, w3, w2, order, rows, busiest)
+    operands = _fenced(h, p, w1, w3, w2, order, rows, busiest)
+    return jax.lax.cond(fits(rows, bound, busiest),
+                        *_bodies(bound, act=act), *operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
@@ -296,9 +318,9 @@ def _bounded(h, p, w1, w3, w2, order, rows, busiest, bound: int, act: str):
     [1600, 2048], as it stands inside the qwen3_next predict step of 8
     methods, does not return (PERF.md section 6, PR 32), so the
     programs that have to answer add nothing by index."""
-    return jax.lax.cond(fits(rows, bound),
-                        *_bodies(bound, scatter=False, act=act),
-                        h, p, w1, w3, w2, order, rows, busiest)
+    operands = _fenced(h, p, w1, w3, w2, order, rows, busiest)
+    return jax.lax.cond(fits(rows, bound, busiest),
+                        *_bodies(bound, scatter=False, act=act), *operands)
 
 
 def _bounded_fwd(h, p, w1, w3, w2, order, rows, busiest, bound, act):
@@ -321,7 +343,8 @@ def _bounded_bwd(bound, act, res, g):
             return jax.vjp(output, *floats)[1](g)
         return run
 
-    grads = jax.lax.cond(fits(rows, bound),
+    floats, g = _fenced(floats, g)
+    grads = jax.lax.cond(fits(rows, bound, busiest),
                          *map(pull, _bodies(bound, act=act)), floats, g)
     return (*grads, None, None, None)
 
@@ -348,7 +371,7 @@ def held_experts_ffn(h: jax.Array, valid: jax.Array, chosen: jax.Array,
                      p: jax.Array, w1: jax.Array, w3: jax.Array,
                      w2: jax.Array, first_expert: int,
                      routed: Optional[int] = None, act: str = "silu",
-                     wait_for_busiest: bool = False
+                     busiest: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the layer's output for tokens h [N, H]
     (`valid` [N] bool: a masked token is routed nowhere), and its counts,
@@ -359,11 +382,12 @@ def held_experts_ffn(h: jax.Array, valid: jax.Array, chosen: jax.Array,
     skip). `w1`, `w3` [held, H, F] and `w2` [held, F, H]
     are experts `first_expert ..` of the `routed` the router scores
     (None: all of them are held here); `act` names the activation
-    (`ACTIVATIONS`). `wait_for_busiest`: the products run over as many
-    rows as the busiest share of the layer took (`busiest_share`), the
-    rows past the live ones dead, as a synchronous expert-parallel layer
-    lasts on every chip as long as on the busiest; the output and the
-    counts are those of the live rows."""
+    (`ACTIVATIONS`). `busiest` (None: the live rows), the rows the
+    busiest share of the layer took (`busiest_share`): the products run
+    over that many rows, the rows past the live ones dead, as a
+    synchronous expert-parallel layer lasts on every chip as long as on
+    the busiest; the output and the counts are those of the live
+    rows."""
     n, k = chosen.shape
     held = w1.shape[0]
     local = chosen - first_expert
@@ -373,11 +397,9 @@ def held_experts_ffn(h: jax.Array, valid: jax.Array, chosen: jax.Array,
     order = jnp.argsort(key)                       # stable
     rows = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
     bound = row_bound(n * k, held, routed or held)
-    busiest = None
-    if wait_for_busiest and (routed or held) > held:
-        busiest = busiest_share(chosen, valid, first_expert, held, routed)
     if bound == n * k:
-        out = _every_pair(h, p, w1, w3, w2, order, rows, busiest, act=act)
+        out = _every_pair(*_fenced(h, p, w1, w3, w2, order, rows, busiest),
+                          act=act)
     else:
         out = _bounded(h, p, w1, w3, w2, order, rows, busiest, bound, act)
     if not counts_zeros(act):

@@ -676,7 +676,7 @@ def test_encoder_counts_its_expert_layers():
     assert aux[:, 5:].tolist() == [[256, 1]] * 2
     assert 0 < aux[:, :4].sum() <= 2 * 3 * int(mask.sum())
     # the cell: 128 methods x 200 slots, 8 choices, 16 of 256 held
-    assert moe.row_bound(25600 * 8, 16, 256) == 25600
+    assert moe.row_bound(25600 * 8, 16, 256) == 12800
 
 
 def test_under_a_mesh_of_two_every_device_routes_its_own_rows():

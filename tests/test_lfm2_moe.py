@@ -9,6 +9,7 @@ the step's named scopes; the model class end to end under the tests'
 8-device mesh."""
 
 import dataclasses
+import importlib.util
 import json
 import os
 import sys
@@ -487,7 +488,7 @@ def _bound_case(live, n=96, E=64, first=16, held=8, K=4, H=32, F=24):
     """A layer of `held` of E experts over n all-valid tokens whose
     choices are set by hand: `live` of the n K pairs fall on held
     experts (a token's on different ones), the others on experts held
-    elsewhere. At these sizes the bound is 128 of 384 pairs."""
+    elsewhere. At these sizes the bound is 256 of 384 pairs."""
     k = jax.random.split(jax.random.PRNGKey(5), 5)
     chosen = np.zeros((n, K), np.int32)
     for t in range(n):
@@ -529,9 +530,18 @@ def _layer_and_grads(case, routed, wrap=lambda f: f):
 
 
 def test_row_bound_is_twice_the_even_share_up_to_a_tile():
-    # the cell: 128 methods x 200 slots, 4 choices, 8 of 64 held
-    assert moe.row_bound(25600 * 4, 8, 64) == 25600
-    assert moe.row_bound(384, 8, 64) == 128          # 96, up to the tile
+    """R is the even share, pairs x held / routed, up to whole tiles of
+    256 rows (the name is older: R was twice the even share)."""
+    # the cell: 128 methods x 200 slots, 4 choices, 8 of 64 held; its
+    # staircase steps hold 15,936 positions
+    assert moe.row_bound(25600 * 4, 8, 64) == 12800
+    assert moe.row_bound(15936 * 4, 8, 64) == 8192      # 7,968 up
+    # the other expert cells' staircase steps
+    assert moe.row_bound(32544 * 6, 16, 64) == 48896    # smallthinker
+    assert moe.row_bound(15936 * 8, 16, 256) == 8192    # joyai
+    assert moe.row_bound(15936 * 10, 32, 512) == 9984   # qwen3next
+    assert moe.row_bound(384, 8, 64) == 256          # 48, up to the tile
+    assert moe.ROW_TILE == 256
     assert moe.row_bound(1000 * 128, 8, 64) % moe.ROW_TILE == 0
     # half or all of the experts here: nothing to leave out
     assert moe.row_bound(192, 4, 8) == 192
@@ -539,12 +549,12 @@ def test_row_bound_is_twice_the_even_share_up_to_a_tile():
 
 
 # 60 live pairs of 384, the bound itself, one more, and every pair
-@pytest.mark.parametrize("live,at_bound", [(60, True), (128, True),
-                                           (129, False), (384, False)])
+@pytest.mark.parametrize("live,at_bound", [(60, True), (256, True),
+                                           (257, False), (384, False)])
 def test_the_bounded_layer_is_the_full_length_layer(live, at_bound,
                                                     monkeypatch):
     """Output, rows and the gradients for h, p, w1, w3, w2 with 8 of 64
-    experts held (arrays of 128 rows, or the overflow's 384) against the
+    experts held (arrays of 256 rows, or the overflow's 384) against the
     one full-length body (`routed` None). The overflow runs that same
     body: bit for bit. At the bound the products see the same rows at
     the same offsets, so p's and the weights' gradients are the full
@@ -605,13 +615,14 @@ def test_all_experts_held_lowers_to_one_body_and_no_conditional():
                 case["w3"], case["w2"], case["first"], routed)[0]
         ).lower(case["h"]).as_text()
 
-    assert "stablehlo.case" in text(64)
-    for one_body in (text(None), text(8), text(16)):
+    # a half held here: R = 256 of the 384 pairs, and a conditional
+    assert "stablehlo.case" in text(64) and "stablehlo.case" in text(16)
+    for one_body in (text(None), text(8)):
         assert "stablehlo.case" not in one_body
         assert "stablehlo.if" not in one_body
 
 
-@pytest.mark.parametrize("live", [60, 128, 129])
+@pytest.mark.parametrize("live", [60, 256, 257])
 def test_a_program_that_takes_no_gradient_adds_no_rows_by_index(live):
     """Evaluation, prediction and serving run `_bounded` itself, whose
     rows go back through a gather (`_take_back_head`): the same output
@@ -635,7 +646,7 @@ def test_a_program_that_takes_no_gradient_adds_no_rows_by_index(live):
     assert float_scatters(trained.lower(case["h"]).as_text())
     want = _layer_and_grads(case, case["E"])[0]
     got = forward(case["h"])
-    if live <= 128:
+    if live <= 256:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-6)
     else:
@@ -663,7 +674,7 @@ def _eight_of_64():
 @pytest.mark.parametrize("all_here", [False, True])
 def test_encoder_counts_the_bound_and_the_layers_that_ran_at_it(all_here):
     """aux, per expert layer: the held experts' rows, the valid tokens,
-    the row bound (8 methods x 12 slots x 4 choices = 384 pairs, 128
+    the row bound (8 methods x 12 slots x 4 choices = 384 pairs, 256
     held) and whether the layer ran at it; not when every token's
     choices fall on held experts (the overflow)."""
     dims = _eight_of_64()
@@ -679,24 +690,24 @@ def test_encoder_counts_the_bound_and_the_layers_that_ran_at_it(all_here):
     aux = np.asarray(aux)
     assert aux.shape == (2, 8 + 3)
     assert aux[:, -3].tolist() == [int(mask.sum())] * 2
-    assert aux[:, -2].tolist() == [128, 128]
+    assert aux[:, -2].tolist() == [256, 256]
     if all_here:
         assert aux[:, :8].sum(axis=1).tolist() == [384, 384]
         assert aux[:, -1].tolist() == [0, 0]
     else:
-        assert np.all(aux[:, :8].sum(axis=1) <= 128)
+        assert np.all(aux[:, :8].sum(axis=1) <= 256)
         assert aux[:, -1].tolist() == [1, 1]
 
 
 def test_under_a_mesh_every_device_decides_for_its_own_rows():
-    """Four devices, 4 methods x 12 slots x 4 choices = 192 pairs and a
-    bound of 128 each: aux sums the devices' bounds and decisions, the
+    """Four devices, 8 methods x 12 slots x 4 choices = 384 pairs and a
+    bound of 256 each: aux sums the devices' bounds and decisions, the
     code vector and its gradient are the one-device encoder's."""
     from code2vec_tpu.parallel.mesh import make_mesh
 
     dims = _eight_of_64()
     params = init_params(jax.random.PRNGKey(3), dims)
-    _labels, src, pth, dst, mask, _w = batches(n=16)[0]
+    _labels, src, pth, dst, mask, _w = batches(n=32)[0]
     mesh = make_mesh(0, 1, devices=jax.devices()[:4])
 
     def run(mesh):
@@ -710,7 +721,7 @@ def test_under_a_mesh_every_device_decides_for_its_own_rows():
     grads, (code, aux) = on_mesh(params)
     want_grads, (want_code, want_aux) = on_one(params)
     aux, want_aux = np.asarray(aux), np.asarray(want_aux)
-    assert aux[:, -2:].tolist() == [[4 * 128, 4]] * 2
+    assert aux[:, -2:].tolist() == [[4 * 256, 4]] * 2
     assert want_aux[:, -2:].tolist() == [[256, 1]] * 2
     np.testing.assert_array_equal(aux[:, :-2], want_aux[:, :-2])
     np.testing.assert_allclose(np.asarray(code), np.asarray(want_code),
@@ -731,14 +742,41 @@ def test_encoder_with_half_the_experts_here_has_one_body():
     assert np.asarray(aux)[:, -2:].tolist() == [[192, 0]] * 2
 
 
+def _batch_of_lengths(lengths, seed):
+    """A batch whose i-th method has `lengths[i]` valid slots."""
+    r = np.random.default_rng(seed)
+    C = SIZES["max_contexts"]
+    mask = (np.arange(C)[None, :] < np.asarray(lengths)[:, None]).astype(
+        np.float32)
+
+    def ids(v):
+        return (r.integers(2, v + 2, mask.shape) * mask).astype(np.int32)
+
+    n = len(lengths)
+    return (r.integers(2, SIZES["targets"] + 2, n).astype(np.int32),
+            ids(SIZES["tokens"]), ids(SIZES["paths"]), ids(SIZES["tokens"]),
+            mask, np.ones(n, np.float32))
+
+
 def test_compiles_stay_zero_across_batches_of_different_routing():
+    """One compiled step over five batches that route differently and
+    run different bodies: 48 methods x 12 slots x 4 choices = 2,304
+    pairs, 8 of 64 experts held, R = 512. The first expert layer routes
+    evenly (its live pairs, about half the valid tokens, fit R); the
+    second sends every choice to four held experts (its bias), so its
+    live pairs are 4 x the valid tokens: 384 and 400 fit R, 560, 600
+    and 2,304 run every pair."""
     import optax
 
     from code2vec_tpu.training.steps import make_train_step
 
-    _dims, params, rng = program_weights()
+    dims = _eight_of_64()
+    params = init_params(jax.random.PRNGKey(3), dims)
+    layer = params["lfm"]["layers"][2]
+    layer["expert_bias"] = layer["expert_bias"].at[16:20].set(10.0)
+    assert moe.row_bound(48 * 12 * 4, 8, 64) == 512
     opt = optax.adam(1e-3)
-    step = make_train_step(DIMS, opt, use_sampled_softmax=True,
+    step = make_train_step(dims, opt, use_sampled_softmax=True,
                            num_sampled=16)
     state = opt.init(params)
     compiles = [0]
@@ -748,19 +786,26 @@ def test_compiles_stay_zero_across_batches_of_different_routing():
             compiles[0] += 1
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
-    rows = []
-    for i, b in enumerate(batches(steps=5, seed=11)):
-        params, state, _loss = step(params, state,
-                                    tuple(jnp.asarray(a) for a in b),
-                                    jax.random.fold_in(rng, i))
+    valid = (96, 140, 576, 100, 150)
+    for i, total in enumerate(valid):
+        lengths = [total // 48 + (m < total % 48) for m in range(48)]
+        params, state, _loss = step(
+            params, state,
+            tuple(jnp.asarray(a) for a in _batch_of_lengths(lengths, 11 + i)),
+            jax.random.fold_in(jax.random.PRNGKey(0), i))
         if i == 0:
             jax.block_until_ready(params)
             compiles[0] = 0
     step.route_recorder.flush()
     from code2vec_tpu.obs import memory_tracer
-    rows = [tuple(map(tuple, r["attrs"]["layers"]))
-            for r in memory_tracer().records("moe/route")[-5:]]
+    records = [r["attrs"] for r in memory_tracer().records("moe/route")[-5:]]
+    rows = [tuple(map(tuple, r["layers"])) for r in records]
     assert len(set(rows)) == 5                  # five different routings
+    assert [r["valid_tokens"] for r in records] == list(valid)
+    assert [sum(r["layers"][1]) for r in records] == [4 * v for v in valid]
+    assert all(sum(r["layers"][0]) <= 512 for r in records)
+    assert [r["row_bound"] for r in records] == [512] * 5
+    assert [r["compact_layers"] for r in records] == [2, 1, 1, 2, 1]
     assert compiles[0] == 0
 
 
@@ -850,6 +895,50 @@ def test_route_records_are_written_a_step_behind_and_never_waited_for(
     assert len(records()) == 1                   # the unready one is not awaited
     rec.flush()
     assert [r["attrs"]["seq"] for r in records()] == [0, 1, 2]
+
+
+def _carried_row_share_reader():
+    spec = importlib.util.spec_from_file_location(
+        "moe_carried_row_share",
+        os.path.join(REPO, "benchmark", "readers", "moe_carried_row_share.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_the_carried_row_share_reads_the_layers_that_ran_at_the_bound(
+        monkeypatch):
+    """Records the recorder writes from counts whose layers ran at R or
+    over every pair, through the accepted reader's `reduce`: the exact
+    share of the pairs the arrays carried. The lfm2moe cell's staircase
+    steps hold 15,936 positions, so R = 8,192; over its 128 x 200 x 4 =
+    102,400 pairs a layer, a step whose layers all ran at R reads 8.0.
+    Under a mesh the columns are sums over the devices."""
+    from code2vec_tpu.obs import route, trace
+
+    monkeypatch.setattr(trace, "_MEMORY_TRACER", trace.MemoryTracer())
+    reduce = _carried_row_share_reader().reduce
+    pairs, bound = 102400, moe.row_bound(15936 * 4, 8, 64)
+    assert bound == 8192
+    steps = [[1, 1], [1, 0], [0, 0]]       # per layer: ran at R
+    rec = route.RouteRecorder()
+    for at in steps:
+        rec.push(_FakeCounts([[5, 7, 900, bound, a] for a in at]))
+    rec.flush()
+    records = trace.memory_tracer().records("moe/route")
+    assert [r["attrs"]["compact_layers"] for r in records] == [2, 1, 0]
+    assert reduce(records[:1], 1, pairs) == 8.0
+    assert reduce(records[2:], 1, pairs) == 100.0
+    assert reduce(records, 3, pairs) == pytest.approx(
+        100.0 * (3 * bound + 3 * pairs) / (6 * pairs), rel=1e-12)
+    # two devices of 51,200 pairs each: both at R in the first layer,
+    # one of them in the second
+    rec.push(_FakeCounts([[10, 14, 1800, 2 * bound, 2],
+                          [10, 14, 1800, 2 * bound, 1]]))
+    rec.flush()
+    last = trace.memory_tracer().records("moe/route")[-1:]
+    assert reduce(last, 1, pairs, devices=2) == pytest.approx(
+        100.0 * (3 * bound + pairs // 2) / (2 * pairs), rel=1e-12)
 
 
 def test_named_scopes_stand_in_every_step_that_runs_the_encoder():

@@ -473,8 +473,11 @@ def test_no_row_is_dropped_when_all_tokens_choose_one_held_expert():
 
 
 def test_row_bound_is_an_eighth_of_the_cells_pairs():
+    """R is a sixteenth of the cell's pairs (32 of 512 experts held), up
+    to whole tiles of 256 rows; the name is from when it was twice
+    that."""
     # the cell: 128 methods x 200 slots, 10 choices, 32 of 512 held
-    assert moe.row_bound(25600 * 10, 32, 512) == 32000
+    assert moe.row_bound(25600 * 10, 32, 512) == 16128
     assert moe.row_bound(288, 4, 16) == 256
 
 
@@ -551,7 +554,7 @@ def test_under_a_mesh_every_device_routes_and_scans_its_own_rows():
     from code2vec_tpu.parallel.mesh import make_mesh
 
     _dims, params, _ = program_weights()
-    _labels, src, pth, dst, mask, _w = batches(n=16)[0]
+    _labels, src, pth, dst, mask, _w = batches(n=32)[0]
     mesh = make_mesh(0, 1, devices=jax.devices()[:4])
 
     def run(mesh):
@@ -565,10 +568,11 @@ def test_under_a_mesh_every_device_routes_and_scans_its_own_rows():
     want_grads, (want_code, want_aux) = run(None)(params)
     aux, want_aux = np.asarray(aux), np.asarray(want_aux)
     # rows, valid tokens and the scans' counts are the whole batch's;
-    # the bound and the decisions are sums over the four devices
+    # the bound and the decisions are sums over the four devices (8
+    # methods x 12 slots x 3 choices = 288 pairs and a bound of 256 each)
     np.testing.assert_array_equal(aux[:, :5], want_aux[:, :5])
     np.testing.assert_array_equal(aux[:, 7:], want_aux[:, 7:])
-    assert aux[:, 5:7].tolist() == [[4 * 128, 4]] * 4
+    assert aux[:, 5:7].tolist() == [[4 * 256, 4]] * 4
     np.testing.assert_allclose(np.asarray(code), np.asarray(want_code),
                                rtol=1e-4, atol=5e-5)
     got, want = flat(grads["qwen"]), flat(want_grads["qwen"])

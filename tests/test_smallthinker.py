@@ -598,9 +598,11 @@ def test_waiting_for_the_busiest_share_changes_no_output_gradient_or_count(
     def program(wait):
         def run(h, w1, w3, w2):
             chosen, p = moe.route(h, case["router"], None, 3, "softmax")
+            busiest = (moe.busiest_share(chosen, case["valid"], first, held,
+                                         E) if wait else None)
             return moe.held_experts_ffn(h, case["valid"], chosen, p, w1, w3,
                                         w2, first, E, act="relu",
-                                        wait_for_busiest=wait)
+                                        busiest=busiest)
         return run
 
     runs = {False: jax.jit(program(False)), True: jax.jit(program(True))}
@@ -627,6 +629,79 @@ def test_waiting_for_the_busiest_share_changes_no_output_gradient_or_count(
     for a, b in zip(results[True][2], results[False][2]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
                                    atol=1e-6)
+
+
+def _choices_of_two_shares(moved, n=96):
+    """(chosen [n, 3], p [n, 3]): 4 of 16 experts held from 2, every
+    token valid. A third of the tokens take one held expert and two of
+    share 2 (experts 10-13), the rest three of share 2, and the first
+    `moved` tokens of that third three of share 2 too: the held share
+    takes 32 - moved rows, share 2 256 + moved, of 288 pairs."""
+    chosen = np.zeros((n, 3), np.int32)
+    for t in range(n):
+        chosen[t] = [10 + (t + j) % 4 for j in range(3)]
+        if t % 3 == 0 and t // 3 >= moved:
+            chosen[t, 0] = 2 + t % 4
+    p = jax.random.uniform(jax.random.PRNGKey(7), (n, 3), minval=0.1)
+    return jnp.asarray(chosen), p / p.sum(-1, keepdims=True)
+
+
+@pytest.mark.parametrize("moved,body", [(0, "first_pairs"),
+                                        (1, "every_pair")])
+def test_a_busiest_share_past_the_bound_runs_every_pair(moved, body,
+                                                        monkeypatch):
+    """The layer's own rows fit R (256 of 288 pairs) either way; the
+    busiest share's 256 rows do too, its 257 do not, and then the layer
+    runs every pair rather than cut the wait short at R. The counts'
+    column (`ran_at_bound`) says which ran; the output, the gradients
+    and the rows are those of the layer that does not wait; with the
+    other body poisoned nothing changes."""
+    case = _layer_case()
+    held, first, E = 4, 2, 16
+    chosen, p = _choices_of_two_shares(moved)
+    valid = jnp.ones(chosen.shape[0], bool)
+    busiest = moe.busiest_share(chosen, valid, first, held, E)
+    assert int(busiest) == 256 + moved
+    sl = slice(first, first + held)
+    weights = (case["w1"][sl], case["w3"][sl], case["w2"][sl])
+
+    def program(wait):
+        def run(h, w1, w3, w2):
+            return moe.held_experts_ffn(h, valid, chosen, p, w1, w3, w2,
+                                        first, E, act="relu",
+                                        busiest=busiest if wait else None)
+        return run
+
+    def results(wait):
+        run = jax.jit(program(wait))       # traced anew: see the poison
+        out, counts = run(case["h"], *weights)
+        g = jax.grad(lambda *a: jnp.sum(jnp.sin(program(wait)(*a)[0])),
+                     argnums=(0, 1, 2, 3))(case["h"], *weights)
+        return out, counts, g
+
+    with jax.default_matmul_precision("highest"):
+        out, counts, g = results(True)
+        want_out, want_counts, want_g = results(False)
+    rows = counts[:held]
+    assert int(rows.sum()) == 32 - moved
+    assert moe.ran_at_bound(rows, 288, E) == (256, True)
+    bound, at_bound = moe.ran_at_bound(rows, 288, E, busiest)
+    assert (bound, bool(at_bound)) == (256, body == "first_pairs")
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.asarray(want_counts))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               atol=1e-6)
+    for a, b in zip(g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)
+    other = "_every_pair" if body == "first_pairs" else "_first_pairs"
+    monkeypatch.setattr(moe, other, lambda h, *a, **kw: (
+        jnp.full_like(h, jnp.nan), jnp.int32(0)))
+    with jax.default_matmul_precision("highest"):
+        out2, _counts, g2 = results(True)
+    np.testing.assert_array_equal(np.asarray(out2), np.asarray(out))
+    for a, b in zip(g2, g):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_the_products_run_over_the_busiest_shares_rows(monkeypatch):
@@ -669,7 +744,7 @@ def test_the_encoder_waits_for_the_busiest_share(monkeypatch):
         asked.append((first, held, routed))
         return busiest_share(chosen, valid, first, held, routed)
 
-    monkeypatch.setattr(moe, "busiest_share", spy)
+    monkeypatch.setattr(seq_block, "busiest_share", spy)
     params, _ = program_weights()
     _labels, src, pth, dst, mask, _w = batches()[0]
     jax.jit(lambda p: get_encode_fn(DIMS)(
@@ -709,7 +784,7 @@ def test_encoder_counts_its_layers_and_the_recorder_writes_the_zeros():
     assert record["inner_zero_share"] == pytest.approx(
         100.0 * aux[:, 7].sum() / aux[:, 8].sum())
     # the cell: 128 methods x 200 slots, 6 choices, 16 of 64 held
-    assert moe.row_bound(25600 * 6, 16, 64) == 76800
+    assert moe.row_bound(25600 * 6, 16, 64) == 38400
 
 
 # ---- configuration -------------------------------------------------------
@@ -974,14 +1049,15 @@ def _bodies_as_it_was(bound, scatter=True):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _bounded_as_it_was(h, p, w1, w3, w2, order, rows, bound):
+    operands = jax.lax.optimization_barrier((h, p, w1, w3, w2, order, rows))
     return jax.lax.cond(moe.fits(rows, bound),
-                        *_bodies_as_it_was(bound, scatter=False),
-                        h, p, w1, w3, w2, order, rows)
+                        *_bodies_as_it_was(bound, scatter=False), *operands)
 
 
 def _bounded_fwd_as_it_was(h, p, w1, w3, w2, order, rows, bound):
+    operands = jax.lax.optimization_barrier((h, p, w1, w3, w2, order, rows))
     return (jax.lax.cond(moe.fits(rows, bound), *_bodies_as_it_was(bound),
-                         h, p, w1, w3, w2, order, rows),
+                         *operands),
             (h, p, w1, w3, w2, order, rows))
 
 
@@ -993,6 +1069,7 @@ def _bounded_bwd_as_it_was(bound, res, g):
             return jax.vjp(lambda *f: body(*f, order, rows), *floats)[1](g)
         return run
 
+    floats, g = jax.lax.optimization_barrier((floats, g))
     grads = jax.lax.cond(moe.fits(rows, bound),
                          *map(pull, _bodies_as_it_was(bound)), floats, g)
     return (*grads, None, None)
@@ -1012,7 +1089,8 @@ def _held_experts_ffn_as_it_was(h, valid, chosen, p, w1, w3, w2,
     rows = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
     bound = moe.row_bound(n * k, held, routed or held)
     if bound == n * k:
-        return _every_pair_as_it_was(h, p, w1, w3, w2, order, rows), rows
+        return _every_pair_as_it_was(*jax.lax.optimization_barrier(
+            (h, p, w1, w3, w2, order, rows))), rows
     return _bounded_as_it_was(h, p, w1, w3, w2, order, rows, bound), rows
 
 
@@ -1024,7 +1102,12 @@ def test_the_four_blocks_train_steps_lower_to_what_they_did(
     """The full step and the staircase step of each accepted block: with
     `seq_block`'s masked softmax, core, attention, residual layer and
     routed experts and `ops/moe`'s grouped product as they were and as
-    they are, the same text."""
+    they are, the same text. The copies hold the barriers before the
+    expert conditional and call today's `moe.row_bound`,
+    `moe.fits` and `moe.ran_at_bound`, so for the three expert blocks
+    this pins the layer around them at today's bound; the bound and the
+    choice of body are held by tests/test_lfm2_moe.py and the busiest
+    share's part in it by this file's tests above."""
     dims = dims_of(name)
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), dims))
     optimizer = optax.sgd(0.1)
