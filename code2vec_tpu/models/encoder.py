@@ -29,6 +29,7 @@ import numpy as np
 
 from code2vec_tpu.models.registry import EncoderSpec, spec
 from code2vec_tpu.ops.attention import attention_pool
+from code2vec_tpu.ops.dropout import dropout
 
 Params = Dict[str, jax.Array]
 
@@ -297,11 +298,7 @@ def embed_contexts(params: Params, source_ids: jax.Array,
 
     with jax.named_scope("c2v/encode"):
         contexts = jnp.concatenate(rows, axis=-1).astype(compute_dtype)
-        if dropout_rng is not None and dropout_keep_rate < 1.0:
-            keep = jax.random.bernoulli(dropout_rng, dropout_keep_rate,
-                                        contexts.shape)
-            contexts = jnp.where(keep, contexts / dropout_keep_rate, 0.0)
-    return contexts
+        return dropout(contexts, dropout_rng, dropout_keep_rate)
 
 
 def encode(params: Params, source_ids: jax.Array, path_ids: jax.Array,

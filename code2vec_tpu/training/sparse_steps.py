@@ -39,6 +39,7 @@ import optax
 
 from code2vec_tpu.models.encoder import ModelDims
 from code2vec_tpu.ops.attention import attention_pool
+from code2vec_tpu.ops.dropout import dropout
 from code2vec_tpu.ops.quant import is_quantized
 from code2vec_tpu.ops.sampled_softmax import (
     _log_expected_count, log_uniform_sample)
@@ -135,13 +136,8 @@ def make_gathered_loss(dims: ModelDims, ctx, *, use_sampled_softmax:
             contexts = jnp.concatenate(
                 [gathered["src_e"], gathered["pth_e"],
                  gathered["dst_e"]], axis=-1).astype(compute_dtype)
-            if dims.dropout_keep_rate < 1.0:
-                keep = jax.random.bernoulli(
-                    ctx["drop_rng"], dims.dropout_keep_rate,
-                    contexts.shape)
-                contexts = jnp.where(keep,
-                                     contexts / dims.dropout_keep_rate,
-                                     0.0)
+            contexts = dropout(contexts, ctx["drop_rng"],
+                               dims.dropout_keep_rate)
         with jax.named_scope("c2v/pool"):
             code, _ = attention_pool(contexts, dense["transform"],
                                      dense["attention"], mask)
